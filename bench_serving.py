@@ -50,6 +50,7 @@ import numpy as np
 def build_booster(args):
     import lightgbm_tpu as lgb
 
+    lgb.use_compile_cache()
     if args.model:
         return lgb.Booster(model_file=args.model)
     rng = np.random.RandomState(11)

@@ -33,7 +33,28 @@ except ImportError:  # pragma: no cover
 
 __version__ = "2.2.4.tpu0"
 
+
+def use_compile_cache() -> str:
+    """Give JAX's persistent compilation cache its ONE place and return it.
+
+    ``JAX_COMPILATION_CACHE_DIR`` decides when it is set (JAX reads the
+    variable itself; nothing is set in code).  Otherwise the cache is
+    ``<checkout>/.jax_tpu_cache`` — a fixed path, never a temporary name:
+    the path is part of the cache key, so a directory that moves never
+    hits.  Every entry point that compiles (``train``, the CLI, ``serve``,
+    the benchmarks, ``chip_smoke.py``) calls this first."""
+    import os
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_tpu_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 __all__ = ["Dataset", "Booster", "CVBooster", "Config",
-           "train", "cv",
+           "train", "cv", "use_compile_cache",
            "early_stopping", "print_evaluation", "record_evaluation",
            "record_telemetry", "reset_parameter"] + _SKLEARN + _PLOT

@@ -71,8 +71,9 @@ def xla_costs(closed_jaxpr) -> Tuple[int, int]:
     """(flops, bytes_accessed) from XLA's analytical cost model over the
     LOWERED program — no compilation, no execution."""
     import jax
+    from jax.extend.core import jaxpr_as_fun
 
-    fn = jax.core.jaxpr_as_fun(closed_jaxpr)
+    fn = jaxpr_as_fun(closed_jaxpr)
     args = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
             for v in closed_jaxpr.jaxpr.invars]
     lowered = jax.jit(fn).lower(*args)

@@ -266,7 +266,6 @@ def _trace_wave_sharded(kind: str, quant: bool = False, ndev: int = 2):
     from jax.sharding import PartitionSpec as P
 
     from ..config import Config
-    from ..parallel.compact_sharded import shard_map
     from ..parallel.mesh import make_mesh
     from ..parallel.feature_sharded import FeatureShardedWaveLearner
     from ..parallel.wave_sharded import ShardedVotingWaveLearner, \
@@ -299,10 +298,7 @@ def _trace_wave_sharded(kind: str, quant: bool = False, ndev: int = 2):
         assert learner._quant, learner._quant_reason
         assert learner._wire_int16(), "int16 exchange tier did not engage"
     kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        fn = shard_map(body, check_vma=False, **kw)
-    except TypeError:
-        fn = shard_map(body, check_rep=False, **kw)
+    fn = jax.shard_map(body, check_vma=False, **kw)
     z = jnp.zeros(learner.n_pad, jnp.float32)
     fmask_pad = jnp.ones(learner.f_pad, bool)
     return jax.make_jaxpr(fn)(learner.sharded_bins(), z, z, z, fmask_pad)
@@ -322,7 +318,6 @@ def _trace_wave_sharded_2d(shape: Tuple[int, int] = (2, 2),
     from jax.sharding import PartitionSpec as P
 
     from ..config import Config
-    from ..parallel.compact_sharded import shard_map
     from ..parallel.sharding import AXIS_DATA, AXIS_FEATURE, make_mesh
     from ..parallel.wave2d_sharded import ShardedWave2DLearner, \
         wave2d_ineligible_reason
@@ -338,11 +333,7 @@ def _trace_wave_sharded_2d(shape: Tuple[int, int] = (2, 2),
     kw = dict(mesh=mesh,
               in_specs=(P(fx, ax), P(ax), P(ax), P(ax), P()),
               out_specs=(P(), P(), P(), P(ax), P()))
-    try:
-        fn = shard_map(learner._train_tree_wave_sharded, check_vma=False,
-                       **kw)
-    except TypeError:
-        fn = shard_map(learner._train_tree_wave_sharded, check_rep=False,
+    fn = jax.shard_map(learner._train_tree_wave_sharded, check_vma=False,
                        **kw)
     z = jnp.zeros(learner.n_pad, jnp.float32)
     fmask_pad = jnp.ones(learner.f_pad, bool)

@@ -305,6 +305,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"[LightGBM-TPU] [Fatal] Unknown task: {cfg.task}",
               file=sys.stderr)
         return 1
+    from . import use_compile_cache
+    use_compile_cache()
     task(params, cfg)
     return 0
 

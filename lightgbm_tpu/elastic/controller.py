@@ -85,7 +85,7 @@ def _parse_base(params: Dict[str, Any], host_id: int) -> "tuple":
 def run_host(params: Dict[str, Any], data: str, num_boost_round: int,
              host_id: int, num_hosts: int, workdir: str,
              worker_env: Optional[Dict[str, str]] = None,
-             enable_x64: bool = False, cache_dir: Optional[str] = None,
+             enable_x64: bool = False,
              negotiate_deadline_s: float = 20.0,
              worker_timeout_s: float = 600.0) -> ElasticResult:
     """Supervise this host through every membership epoch until training
@@ -129,7 +129,7 @@ def run_host(params: Dict[str, Any], data: str, num_boost_round: int,
             "verdict_path": os.path.join(edir, "verdict.json"),
             "result_path": os.path.join(edir, "result.json"),
             "negotiate_deadline_s": float(negotiate_deadline_s),
-            "enable_x64": bool(enable_x64), "cache_dir": cache_dir,
+            "enable_x64": bool(enable_x64),
         }
         spec_path = os.path.join(edir, "spec.json")
         write_json(spec_path, spec)

@@ -111,9 +111,6 @@ def main(argv) -> None:
     import jax
     if spec.get("enable_x64"):
         jax.config.update("jax_enable_x64", True)
-    if spec.get("cache_dir"):
-        jax.config.update("jax_compilation_cache_dir", spec["cache_dir"])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     import lightgbm_tpu as lgb
     from ..parallel.multihost import RankDeathError

@@ -1,11 +1,11 @@
 """Frontier-wave TPU tree learner: batched speculative leaf-wise growth.
 
 The sequential compact learner (`learner_compact.py`) builds a tree as 254
-dependent split steps inside one XLA program; at 1M rows the program floors
-at ~90 ms/tree of per-step bookkeeping and per-window sort latency before
-any real data work (profiling/PROFILE.md).  This learner restructures the
-growth into ~13 *frontier waves* while preserving exact best-first
-(leaf-wise) semantics:
+dependent split steps inside one XLA program, which at 1M rows floors on
+per-step bookkeeping and per-window sort latency before any real data work
+(round-5 chip record, deleted in PR 21; not re-measured).  This learner
+restructures the growth into ~13 *frontier waves* while preserving exact
+best-first (leaf-wise) semantics:
 
   1. **Grow.**  Each wave splits the top-W positive-gain frontier leaves at
      once: one full-array stable sort re-compacts every split window
@@ -201,6 +201,9 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             # buffer_donor pass.  Bitcasting leaf_id (int32[n_pad]) out
             # as its f32 bit-pattern gives the donated grad buffer a
             # landing slot; train_async casts it back at the call seam.
+            # That is the program's ONLY n_pad-sized output, so only grad
+            # is donated: a donated hess has nowhere to land (the v5e
+            # compile reports it "not usable" and leaves it allocated).
             # The analysis gate asserts input_output_alias in this
             # program's compiled HLO (analysis/donation.py).
             def _tree_w_donating(bins_p, grad, hess, bag, fmask):
@@ -211,7 +214,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 return out[:3] + (leaf_f32,) + out[4:]
 
             self._jit_tree_w = jax.jit(_tree_w_donating,
-                                       donate_argnums=(1, 2))
+                                       donate_argnums=(1,))
             self._tree_w_bitcast = True
         else:
             self._jit_tree_w = jax.jit(self._train_tree_wave)
@@ -332,10 +335,8 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         if qg == "on":
             self._quant = q_reason is None
         else:
-            # auto stays OFF until the on-hardware win is recorded
-            # (BENCH_r08 carries the CPU evidence; ROADMAP item 1 tracks
-            # the TPU leg) — same posture scan/partition auto took before
-            # their device sweeps landed
+            # auto stays OFF until an on-hardware win is recorded
+            # (ROADMAP queue 1 item 3 tracks the TPU leg)
             self._quant = False
             if q_reason is None:
                 q_reason = "tpu_quantized_grad=%s (quantization is " \

@@ -1,6 +1,6 @@
 """Structured training telemetry.
 
-The perf trajectory so far (BENCH_r01-r05) was driven by one-off scripts
+The early perf rounds were driven by one-off scripts
 under ``profiling/`` and hand-done ablation arithmetic; the library itself
 measured nothing.  This package is the first-class observability layer the
 boosting loop and tree learners report through:

@@ -57,8 +57,8 @@ TEL_NAMES = {
 # state: health, in-flight, dispatched, ejections, latency histogram —
 # `lightgbm_tpu/serving/fleet/replicas.py`)
 # v7: required "provenance" block (platform / jax version / device & host
-# counts / emulated-vs-real flag — no more BENCH_r06-style ambiguity about
-# what hardware a number came from) and optional "distributed" section
+# counts / emulated-vs-real flag — no ambiguity about what hardware a
+# number came from) and optional "distributed" section
 # (per-rank step timings + skew, sampled-sync attribution table, memory
 # watermarks, clock-offset handshake — `observability/attribution.py` /
 # `observability/podtrace.py`)
@@ -102,25 +102,18 @@ def provenance_section(extra: Optional[Dict[str, Any]] = None
     the accelerator platform is NOT a real TPU (CPU runs, forced-host
     virtual device pods) — the flag the BENCH/MULTICHIP writers assert on
     so a CPU-parity number can never masquerade as a device result."""
+    import jax
+    dev = jax.devices()[0]
     out: Dict[str, Any] = {
-        "platform": "unknown", "device_kind": "unknown",
-        "jax_version": "unknown", "num_devices": 0, "num_hosts": 1,
-        "process_index": 0, "emulated": True, "mesh_shape": None,
+        "platform": str(dev.platform),
+        "device_kind": str(dev.device_kind),
+        "jax_version": str(jax.__version__),
+        "num_devices": int(jax.device_count()),
+        "num_hosts": int(jax.process_count()),
+        "process_index": int(jax.process_index()),
+        "emulated": dev.platform != "tpu", "mesh_shape": None,
         "cost_ledger_sha256": _cost_ledger_sha256(),
     }
-    try:
-        import jax
-        out["jax_version"] = str(jax.__version__)
-        devs = jax.devices()
-        out["platform"] = str(devs[0].platform)
-        out["device_kind"] = str(getattr(devs[0], "device_kind",
-                                         devs[0].platform))
-        out["num_devices"] = int(jax.device_count())
-        out["num_hosts"] = int(jax.process_count())
-        out["process_index"] = int(jax.process_index())
-        out["emulated"] = out["platform"] != "tpu"
-    except Exception:
-        pass
     if extra:
         out.update({k: v for k, v in extra.items() if v is not None})
     return out
@@ -130,24 +123,18 @@ def memory_watermarks() -> Dict[str, Any]:
     """Device HBM peaks (``memory_stats()``; absent on backends that
     don't expose them — CPU) and the process tracemalloc snapshot when
     the caller has tracing on.  Host-only, never forces a device sync."""
+    import jax
     devices = []
-    try:
-        import jax
-        for d in jax.local_devices():
-            try:
-                st = d.memory_stats()
-            except Exception:
-                st = None
-            if not st:
-                continue
-            devices.append({
-                "device": str(d),
-                "peak_bytes_in_use": int(st.get("peak_bytes_in_use", 0)),
-                "bytes_in_use": int(st.get("bytes_in_use", 0)),
-                "bytes_limit": int(st.get("bytes_limit", 0)),
-            })
-    except Exception:
-        pass
+    for d in jax.local_devices():
+        st = d.memory_stats()       # None where the backend has none (CPU)
+        if not st:
+            continue
+        devices.append({
+            "device": str(d),
+            "peak_bytes_in_use": int(st.get("peak_bytes_in_use", 0)),
+            "bytes_in_use": int(st.get("bytes_in_use", 0)),
+            "bytes_limit": int(st.get("bytes_limit", 0)),
+        })
     host = None
     if tracemalloc.is_tracing():
         cur, peak = tracemalloc.get_traced_memory()

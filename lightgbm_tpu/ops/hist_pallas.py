@@ -36,12 +36,6 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both so
-# the interpret-mode parity tests run on either toolchain
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-
 def _tile_params(fw: int, n: int, word_tile: int, row_block: int,
                  num_bins: int):
     """Shared Mosaic tiling normalization for the packed-word kernels:
@@ -118,7 +112,7 @@ def build_histogram_pallas(bins: jax.Array, w: jax.Array, *, num_bins: int,
         ],
         out_specs=pl.BlockSpec((feature_tile, 3, b_pad), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((f, 3, b_pad), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(bins, w)
     return out[:, :, :num_bins].transpose(0, 2, 1)
@@ -361,7 +355,7 @@ def build_histogram_packed(bins_words: jax.Array, w: jax.Array, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bins_words, w)
@@ -501,7 +495,7 @@ def build_histogram_segments(bins_words: jax.Array, w: jax.Array,
                           n_slots=n_slots, radix=radix, quant=quant),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(chunk_slot, chunk_block, chunk_leaf, bins_words, w, lid)
@@ -613,7 +607,7 @@ def build_histogram_multislot(bins_words: jax.Array, w: jax.Array,
                                lambda i, j: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((fw, n_slots, 3, 4 * b_pad),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bins_words, w, slot)

@@ -3,8 +3,8 @@
 The OpenCL reference ships histogram, split-scan AND a data-partition
 kernel; only the histogram family had been ported.  The wave learner
 re-compacts every split window with a full-array 13-lane ``lax.sort``
-(~6.1 ms per 1M rows on v5e, the learner's single largest per-wave cost
-— profiling/PROFILE.md round 5).  ``lax.sort`` cost is operand-count- and
+(the learner's single largest per-wave cost in the round-5 chip record,
+deleted in PR 21; not re-measured).  ``lax.sort`` cost is operand-count- and
 key-entropy-insensitive (pure bitonic stage latency), but the wave's
 permutation is *not* a general sort: every row's destination is known in
 closed form before any row moves —
@@ -55,11 +55,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
-
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 
 # Row-count ceiling: destinations/ranks ride f32-exact integer planes and
@@ -284,34 +279,41 @@ def _permute_kernel(ot_ref, it_ref, kind_ref, bins_ref, wbits_ref, rid_ref,
 
     @pl.when(kind < 2)
     def _compute():
-        dest = dest_ref[...]                    # (rb,) int32, global dests
-        mvd = mvd_ref[...] != 0                 # (rb,) row moved this wave
+        # rows ride the lane axis throughout, as (1, rb) operands: Mosaic
+        # refuses the 1-D form (XLA tiles a 1-D s32[N] by 1024, this
+        # block is rb), and the transposed one-hot below needs no
+        # lane->sublane relayout of the destinations
+        dest = dest_ref[...]                    # (1, rb) int32 global dests
+        mvd = mvd_ref[...] != 0                 # (1, rb) row moved this wave
         base = ot * rb
         sel = (dest >= base) & (dest < base + rb)
-        sel &= jnp.where(kind == 0, ~mvd, mvd)
+        # identity chunks (kind 0) carry the unmoved rows, moving chunks
+        # (kind 1) the moved ones; compared as integers because Mosaic
+        # cannot legalize arith.select on i1 vectors
+        sel &= mvd.astype(jnp.int32) == kind
         d_local = jnp.where(sel, dest - base, -1)
-        iota_d = lax.broadcasted_iota(jnp.int32, (rb, rb), 1)
-        oh = (d_local[:, None] == iota_d).astype(jnp.bfloat16)  # (rb, rb)
+        iota_d = lax.broadcasted_iota(jnp.int32, (rb, rb), 0)
+        oh_t = (iota_d == d_local).astype(jnp.bfloat16)  # (rb dst, rb src)
         planes = []
         for wd in range(fw):
-            word = bins_ref[wd, :]
+            word = bins_ref[wd:wd + 1, :]
             for s in range(4):
-                planes.append(((word >> (8 * s)) & 0xFF)[None, :])
-        wbits = wbits_ref[...]        # (3, rb) int32 (f32 bit patterns,
-        for c in range(3):            # bitcast by the caller)
+                planes.append((word >> (8 * s)) & 0xFF)
+        for c in range(3):            # f32 bit patterns, bitcast by the
+            word = wbits_ref[c:c + 1, :]                     # caller
             for s in range(4):
-                planes.append(((wbits[c, :] >> (8 * s)) & 0xFF)[None, :])
+                planes.append((word >> (8 * s)) & 0xFF)
         rid = rid_ref[...]
         for s in range(3):
-            planes.append(((rid >> (8 * s)) & 0xFF)[None, :])
+            planes.append((rid >> (8 * s)) & 0xFF)
         lid = lid_ref[...]
         for s in range(2):
-            planes.append(((lid >> (8 * s)) & 0xFF)[None, :])
+            planes.append((lid >> (8 * s)) & 0xFF)
         a = jnp.concatenate(planes, axis=0) \
             .astype(jnp.bfloat16)                      # (P, rb), 0..255
         # one nonzero product per output element: bf16 transports each
         # byte exactly; accumulation stays in integer-exact range
-        part = lax.dot_general(a, oh, (((1,), (0,)), ((), ())),
+        part = lax.dot_general(a, oh_t, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
         out_ref[0, :, :] += part.astype(out_ref.dtype)
 
@@ -323,16 +325,14 @@ def _apply_partition_call(ot, it, kind, bins_p, w_bits, rid_p, lid_p, dest,
     t_blocks = n // rb
     p = _byte_planes(fw)
     grid = (ot.shape[0],)
+    row_spec = pl.BlockSpec((1, rb), lambda t, o, i, k: (0, i[t]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             pl.BlockSpec((fw, rb), lambda t, o, i, k: (0, i[t])),
             pl.BlockSpec((3, rb), lambda t, o, i, k: (0, i[t])),
-            pl.BlockSpec((rb,), lambda t, o, i, k: (i[t],)),
-            pl.BlockSpec((rb,), lambda t, o, i, k: (i[t],)),
-            pl.BlockSpec((rb,), lambda t, o, i, k: (i[t],)),
-            pl.BlockSpec((rb,), lambda t, o, i, k: (i[t],)),
+            row_spec, row_spec, row_spec, row_spec,
         ],
         out_specs=pl.BlockSpec((1, p, rb), lambda t, o, i, k: (o[t], 0, 0)),
     )
@@ -340,10 +340,11 @@ def _apply_partition_call(ot, it, kind, bins_p, w_bits, rid_p, lid_p, dest,
         functools.partial(_permute_kernel, rb=rb, fw=fw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_blocks, p, rb), jnp.bfloat16),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(ot, it, kind, bins_p, w_bits, rid_p, lid_p, dest, mvd)
+    )(ot, it, kind, bins_p, w_bits,
+      *(v[None, :] for v in (rid_p, lid_p, dest, mvd)))
     return out
 
 

@@ -45,10 +45,6 @@ from ..learner_compact import (CF_GAIN, CF_LCNT, CF_LOUT, CF_LSG, CF_LSH,
 from ..ops.split import find_best_splits
 from ..tree import Tree
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 class ShardedCompactLearner(CompactTPUTreeLearner):
@@ -492,11 +488,7 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
             kw = dict(mesh=self.mesh,
                       in_specs=(P(None, ax), P(ax), P(ax), P(ax), P()),
                       out_specs=(P(), P(), P(), P(ax), P()))
-            try:  # replication checking kwarg was renamed in jax 0.8
-                fn = shard_map(self._train_tree_sharded, check_vma=False,
-                               **kw)
-            except TypeError:
-                fn = shard_map(self._train_tree_sharded, check_rep=False,
+            fn = jax.shard_map(self._train_tree_sharded, check_vma=False,
                                **kw)
             self._jit_tree_c = jax.jit(fn)
         return self._jit_tree_c
@@ -528,10 +520,7 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         outside the gate's traced-program set."""
         ledger = self._ledger
         kw = dict(mesh=self.mesh, in_specs=in_specs, out_specs=out_specs)
-        try:
-            fn = shard_map(body, check_vma=False, **kw)
-        except TypeError:
-            fn = shard_map(body, check_rep=False, **kw)
+        fn = jax.shard_map(body, check_vma=False, **kw)
         jfn = jax.jit(fn)
 
         def run(*a):

@@ -29,7 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_wave import WaveTPUTreeLearner
-from .compact_sharded import ShardedCompactLearner, shard_map
+from .compact_sharded import ShardedCompactLearner
 
 
 class FeatureShardedCompactLearner(ShardedCompactLearner):
@@ -139,12 +139,8 @@ class FeatureShardedCompactLearner(ShardedCompactLearner):
             kw = dict(mesh=self.mesh,
                       in_specs=(P(None, None), P(), P(), P(), P()),
                       out_specs=(P(), P(), P(), P(), P()))
-            try:
-                fn = shard_map(self._train_tree_feature_sharded,
+            fn = jax.shard_map(self._train_tree_feature_sharded,
                                check_vma=False, **kw)
-            except TypeError:
-                fn = shard_map(self._train_tree_feature_sharded,
-                               check_rep=False, **kw)
             self._jit_tree_c = jax.jit(fn)
         return self._jit_tree_c
 
@@ -242,12 +238,8 @@ class FeatureShardedWaveLearner(FeatureShardedCompactLearner,
             kw = dict(mesh=self.mesh,
                       in_specs=(P(None, None), P(), P(), P(), P()),
                       out_specs=out_specs)
-            try:
-                fn = shard_map(self._train_tree_feature_wave,
+            fn = jax.shard_map(self._train_tree_feature_wave,
                                check_vma=False, **kw)
-            except TypeError:
-                fn = shard_map(self._train_tree_feature_wave,
-                               check_rep=False, **kw)
             self._jit_tree_w = jax.jit(fn, donate_argnums=(1, 2)) \
                 if self._donate else jax.jit(fn)
         return self._pop_telem(self._jit_tree_w(

@@ -52,7 +52,6 @@ from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_compact import CF_GAIN, CI_FEAT, CompactTPUTreeLearner
 from ..learner_wave import WaveState, wave_budget_reason
-from .compact_sharded import shard_map
 from .sharding import AXIS_DATA, AXIS_FEATURE
 from .wave_sharded import ShardedWaveLearner
 
@@ -269,12 +268,8 @@ class ShardedWave2DLearner(ShardedWaveLearner):
             kw = dict(mesh=self.mesh,
                       in_specs=(P(fx, ax), P(ax), P(ax), P(ax), P()),
                       out_specs=out_specs)
-            try:
-                fn = shard_map(self._train_tree_wave_sharded,
+            fn = jax.shard_map(self._train_tree_wave_sharded,
                                check_vma=False, **kw)
-            except TypeError:
-                fn = shard_map(self._train_tree_wave_sharded,
-                               check_rep=False, **kw)
             self._jit_tree_w = jax.jit(fn, donate_argnums=(1, 2)) \
                 if self._donate else jax.jit(fn)
         return self._pop_telem(self._jit_tree_w(

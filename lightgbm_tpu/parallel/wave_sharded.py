@@ -47,7 +47,7 @@ from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_wave import WaveState, WaveTPUTreeLearner, \
     wave_budget_reason
-from .compact_sharded import ShardedCompactLearner, shard_map
+from .compact_sharded import ShardedCompactLearner
 
 
 class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
@@ -168,12 +168,8 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
             kw = dict(mesh=self.mesh,
                       in_specs=(P(None, ax), P(ax), P(ax), P(ax), P()),
                       out_specs=out_specs)
-            try:
-                fn = shard_map(self._train_tree_wave_sharded,
+            fn = jax.shard_map(self._train_tree_wave_sharded,
                                check_vma=False, **kw)
-            except TypeError:
-                fn = shard_map(self._train_tree_wave_sharded,
-                               check_rep=False, **kw)
             self._jit_tree_w = jax.jit(fn, donate_argnums=(1, 2)) \
                 if self._donate else jax.jit(fn)
         return self._pop_telem(self._jit_tree_w(

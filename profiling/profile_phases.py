@@ -22,9 +22,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# ONE timing implementation repo-wide (PROFILE.md round-10 note): best-of
-# with a real device->host fetch per call, shared with the runtime
-# attribution probes — no hand-rolled block_until_ready loops here
+# ONE timing implementation repo-wide: best-of, synced per call, shared
+# with the runtime attribution probes — no hand-rolled timing loops here
 from lightgbm_tpu.observability.attribution import (  # noqa: E402
     force_sync, timeit)
 

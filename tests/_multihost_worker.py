@@ -41,13 +41,15 @@ def _setup(spec):
                                f"{spec['local_devices']}")
     if spec.get("faults"):
         os.environ["LGBT_FAULTS"] = spec["faults"]
+    # share the suite's persistent compile cache (tests/conftest.py): the
+    # pod processes compile the same programs as the in-process tests.
+    # Inherited through the environment under pytest; the fixed default
+    # applies only when the variable is unset (a worker run by hand)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
     import jax
     jax.config.update("jax_enable_x64", True)
-    # share the suite's persistent compile cache (tests/conftest.py): the
-    # pod processes compile the same programs as the in-process tests
-    cache = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -189,14 +191,12 @@ def _job_elastic(spec):
         params["telemetry_out"] = spec["telemetry_out"]
     if spec.get("trace_out"):
         params["trace_out"] = spec["trace_out"]
-    cache = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         ".jax_cache")
     out = {"rank": spec["rank"], "ok": False}
     try:
         res = run_host(
             params, spec["data"], int(spec.get("iters", 6)),
             host_id=spec["rank"], num_hosts=spec["num_hosts"],
-            workdir=spec["workdir"], enable_x64=True, cache_dir=cache,
+            workdir=spec["workdir"], enable_x64=True,
             negotiate_deadline_s=float(spec.get("negotiate_deadline_s", 20)),
             worker_timeout_s=float(spec.get("worker_timeout_s", 420)))
         with open(res.model_path) as fh:

@@ -85,16 +85,12 @@ def _shard_psum_program():
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from lightgbm_tpu.parallel.compact_sharded import shard_map
     from lightgbm_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(2)
     kw = dict(mesh=mesh, in_specs=(P("data"),), out_specs=P())
     body = lambda x: lax.psum(x, "data")  # noqa: E731
-    try:
-        fn = shard_map(body, check_vma=False, **kw)
-    except TypeError:
-        fn = shard_map(body, check_rep=False, **kw)
+    fn = jax.shard_map(body, check_vma=False, **kw)
     return jax.make_jaxpr(fn)(jnp.ones(8, jnp.float32))
 
 
@@ -312,7 +308,7 @@ def test_gate_cli_end_to_end(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_ENABLE_X64", None)
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(_HERE, ".jax_cache")
+    # JAX_COMPILATION_CACHE_DIR is inherited (conftest.py places it)
     t0 = time.monotonic()
     proc = subprocess.run(
         [sys.executable, "-m", "lightgbm_tpu.analysis", "--json", str(out)],

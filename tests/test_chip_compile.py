@@ -1,0 +1,147 @@
+"""Ahead-of-time compiles of the main path's Pallas kernels for a TPU v5e.
+
+Interpret mode proves a kernel's arithmetic, not that Mosaic (the chip's
+kernel compiler) accepts it: PR 6's partition and split-scan kernels and
+PR 12's fused child-scan passed every interpret-mode parity test and were
+refused by the compiler the first time it saw them (PR 21).  libtpu is
+installed here, and it compiles for a chip that is DESCRIBED, not attached,
+so each kernel the default wave path reaches on a TPU is compiled below at
+Higgs width — 28 features = 8 packed words, 256 padded bins, 2^20 rows, the
+wave learner's real W=64 / 2W=128 batch sizes — at no chip time.
+
+Nothing runs: a compile says nothing about results (the interpret-mode
+parity tests pin those) or about speed.
+
+Rules this file keeps (see the on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped, non-autouse fixture — never
+at import time, in a ``skipif`` or in ``parametrize`` — because only one
+process may load libtpu and every xdist worker imports every test file;
+all these tests live in this ONE file so one worker owns the library; the
+compiles happen in this process (no child); the persistent compilation
+cache is off around them (a described-device executable can be written to
+it but not read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+ROWS = 1 << 20          # Higgs-scale row axis (10.5M rows shard to ~this)
+FW = 8                  # 28 features -> 32 padded columns -> 8 int32 words
+F = 28
+B = 256                 # max_bin=255 -> 256 padded bins
+W = 64                  # Config.tpu_wave_width: wave batch W, child scans 2W
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one described v5e device, compile cache off."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower+compile ``fn`` for the described chip; the HLO text.  x64 is
+    off as in production (conftest turns it on for the f64 parity tests)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+_BINS = ((FW, ROWS), jnp.int32)
+_W3 = ((3, ROWS), jnp.float32)
+_ROW_I = ((ROWS,), jnp.int32)
+
+
+@pytest.mark.parametrize("mode", ["bf16x3", "highest", "quant"])
+def test_packed_histogram_compiles(one_chip, mode):
+    """Root/window pass (`learner_compact._make_hist_branch`)."""
+    from lightgbm_tpu.ops.hist_pallas import build_histogram_packed
+    kw = {"bf16x3": dict(nterms=3), "highest": dict(nterms=0),
+          "quant": dict(quant=True)}[mode]
+    _compile(lambda b, w: build_histogram_packed(b, w, num_bins=B, **kw),
+             one_chip, _BINS, _W3)
+
+
+def test_segment_histogram_compiles(one_chip):
+    """Wave member histograms (`learner_wave._segment_hists`): W slots,
+    the learner's own chunk-capacity formula at the default cutoffs."""
+    from lightgbm_tpu.ops.hist_pallas import build_histogram_segments
+    rb = 2048
+    t = ROWS // rb + W + W * (8192 // rb + 2) + 1
+    chunk = ((t,), jnp.int32)
+    _compile(lambda b, w, lid, cs, cb, cl: build_histogram_segments(
+        b, w, lid, cs, cb, cl, num_bins=B, n_slots=W, row_block=rb,
+        nterms=3), one_chip, _BINS, _W3, _ROW_I, chunk, chunk, chunk)
+
+
+def test_multislot_histogram_compiles(one_chip):
+    """Level-wise opening pass (`learner_wave._opening_hists`, off by
+    default: tpu_wave_open_levels auto = 0) at a level-3 width."""
+    from lightgbm_tpu.ops.hist_pallas import build_histogram_multislot
+    _compile(lambda b, w, s: build_histogram_multislot(
+        b, w, s, num_bins=B, n_slots=8, row_block=2048, nterms=3),
+        one_chip, _BINS, _W3, _ROW_I)
+
+
+def test_masked_learner_histogram_compiles(one_chip):
+    """The masked learner's feature-major kernel (tpu_learner=masked, the
+    plain reference chip_smoke.py compares against) at 32 padded features."""
+    from lightgbm_tpu.ops.hist_pallas import build_histogram_pallas
+    _compile(lambda b, w: build_histogram_pallas(b, w, num_bins=B),
+             one_chip, ((32, ROWS), jnp.uint8), _W3)
+
+
+@pytest.mark.parametrize("k", [1, 2 * W])
+def test_batched_split_scan_compiles(one_chip, k):
+    """`find_best_splits_batched` at the root (K=1) and child (K=2W)
+    batch sizes (`learner_wave._cand_rows_batch`)."""
+    from lightgbm_tpu.ops.scan_pallas import find_best_splits_batched
+    leaf = ((k,), jnp.float32)
+    meta = ((F,), jnp.int32)
+    _compile(find_best_splits_batched, one_chip,
+             ((k, F, B, 3), jnp.float32), leaf, leaf, leaf, meta, meta,
+             meta, ((F,), jnp.bool_))
+
+
+def test_fused_child_scan_compiles(one_chip):
+    """`fused_child_scans` for one full wave (K=W members, 2W children) —
+    the quantized wave step's chain (tpu_quantized_grad=on)."""
+    from lightgbm_tpu.ops.scan_pallas import fused_child_scans
+    hist = ((W, F, B, 3), jnp.float32)
+    child = ((2 * W,), jnp.float32)
+    meta = ((F,), jnp.int32)
+    _compile(fused_child_scans, one_chip, hist, hist, ((W,), jnp.bool_),
+             child, child, child, meta, meta, meta, ((F,), jnp.bool_))
+
+
+def test_partition_permute_compiles(one_chip):
+    """`apply_partition` — chunk list, every grid-size bucket of the
+    permute kernel, and the byte-plane recombine — for one W-member wave."""
+    from lightgbm_tpu.ops.partition_pallas import apply_partition
+    member = ((W,), jnp.int32)
+    text = _compile(
+        apply_partition, one_chip, _BINS, _W3, _ROW_I, _ROW_I, _ROW_I,
+        _ROW_I, member, member, member, ((W,), jnp.bool_), _ROW_I, _ROW_I,
+        member, member)
+    assert text.count("tpu_custom_call") > 1   # one kernel per bucket

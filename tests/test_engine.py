@@ -353,3 +353,24 @@ def test_cv_early_stopping_aggregated(rng):
     # truncated AT the aggregated best (last entry is the minimum)
     assert means[-1] == min(means)
     assert len(res["l2-stdv"]) == len(means)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """`use_compile_cache`: JAX_COMPILATION_CACHE_DIR decides when set (no
+    directory is set in code); otherwise the fixed <checkout>/.jax_tpu_cache
+    — never a temporary name."""
+    import os
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert lgb.use_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(lgb.__file__))), ".jax_tpu_cache")
+        assert lgb.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert lgb.use_compile_cache() == want          # fixed, idempotent
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -485,11 +485,17 @@ def test_back_to_back_promotions_cancel_stale_watchdog(rng):
     X, y = _data(rng)
     server = _serve(_train(X, y, 4))
     try:
-        # a long watch interval: the stale watchdog would sit armed for
-        # the whole drill unless promote() explicitly cancels it
+        # a watch interval LONGER than this test may run (the lifecycle
+        # marker's SIGALRM ends it at 120 s): the stale watchdog sits
+        # armed for the whole drill unless promote() explicitly cancels
+        # it, and it can never wake mid-drill.  At the old 10 s interval
+        # a loaded machine (6 xdist workers, cold compile cache) took
+        # longer than that between the two promotions, the stale
+        # watchdog woke on the injected fallbacks and rolled back — the
+        # test then failed on the clock, not on the code
         ctl = LifecycleController(server, divergence_max=0.75,
-                                  rollback_deadline_s=30.0,
-                                  watch_interval_s=10.0,
+                                  rollback_deadline_s=1200.0,
+                                  watch_interval_s=600.0,
                                   error_rate_max=0.05)
         _traffic(server, X)
         X2, y2 = _data(rng)

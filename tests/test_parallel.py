@@ -414,3 +414,17 @@ def test_router_logs_chosen_learner(rng, capsys):
     out = capsys.readouterr().out
     assert "using ShardedWaveLearner" in out or \
         "using ShardedCompactLearner" in out
+
+
+def test_parallel_mode_on_one_device_warns(rng, monkeypatch):
+    """A job that asks for a mesh and sees ONE device trains serial — that
+    is legal (these CPU tests rely on it) but must never be silent."""
+    X, y = _problem(rng, n=512)
+    params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+              "verbosity": -1, "tree_learner": "data"}
+    ds = lgb.Dataset(X, label=y, params=params)
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    with pytest.warns(UserWarning, match="only ONE device"):
+        bst = lgb.Booster(params, ds)
+    assert bst.gbdt._mesh is None

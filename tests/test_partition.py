@@ -225,7 +225,13 @@ def test_split_scan_parity(dyadic):
         gw = np.asarray(want.gain)
         gg = np.asarray(got.gain)[i]
         if dyadic:
-            assert np.array_equal(gw, gg), i
+            # dyadic inputs make every SUM exact in any order, so the
+            # thresholds, child aggregates and leaf outputs below are
+            # bitwise.  The gain is not a sum: g^2/(h+l2) terms round,
+            # and XLA:CPU contracts mul+add into FMA differently in the
+            # two separately compiled programs (kernel body vs
+            # find_best_splits) — 1 ulp either way on this JAX
+            np.testing.assert_array_max_ulp(gw, gg, maxulp=1)
             assert np.array_equal(np.asarray(want.threshold),
                                   np.asarray(got.threshold)[i]), i
             assert np.array_equal(np.asarray(want.default_left),
