@@ -30,6 +30,7 @@ from ..dataset import Dataset, _ConstructedDataset
 from ..learner import TPUTreeLearner
 from ..metrics import Metric, create_metric
 from ..objectives import ObjectiveFunction, create_objective
+from ..observability.phases import scope
 from ..ops.histogram import _on_tpu
 from ..ops.lookup import lookup_f32 as _lookup_small
 from ..tree import Tree
@@ -69,7 +70,7 @@ class ScoreUpdater:
 
         On TPU the per-row lookup is a one-hot matmul, not an XLA gather — a
         1M-row gather from a small table costs ~8 ms there while the MXU
-        one-hot contraction is ~0.5 ms (profiling/profile_gather_alts.py);
+        one-hot contraction is ~0.5 ms (round-5 chip reading);
         on CPU/GPU backends a plain gather is cheaper and the results are
         bit-identical either way (lookup_f32 is exact)."""
         lv = jnp.asarray(leaf_values.astype(np.float32))
@@ -331,54 +332,53 @@ class GBDT:
         else:
             self._pending = []
         tel = self.telemetry
-        _flush_t0 = time.perf_counter() if tel.enabled else 0.0
-        # the record arrays were copy_to_host_async'd at dispatch time, so
-        # these np.asarray calls find host-resident data;
-        # only records of still-executing queued trees block, on execution
-        # itself
-        first_idx = len(self._models)
-        for entry in pend:
-            first_idx = min(first_idx, self._assemble_entry(entry))
-        # deferred stop detection over the flushed iterations only: the first
-        # iteration in which NO class grew a tree ends training; later
-        # iterations repeated the draw and are dropped (`gbdt.cpp:379-387`),
-        # including rolling their contributions back out of the training
-        # score (under bagging a later draw may have split)
-        k = max(self.num_tree_per_iteration, 1)
-        for it in range(first_idx // k, len(self._models) // k):
-            trees = self._models[it * k:(it + 1) * k]
-            if trees and all(t is not None and t.num_leaves <= 1
-                             for t in trees):
-                # a rolling flush may still hold queued post-stop
-                # iterations whose device score updates already applied —
-                # drain them so the rollback below covers every tree
-                if self._pending:
-                    tail, self._pending = self._pending, []
-                    for entry in tail:
-                        self._assemble_entry(entry)
-                # keep iteration 0's constant trees (the sync path's
-                # first-iteration case keeps them too); everything after the
-                # stop iteration is rolled back and dropped
-                drop_from = max(it, 1) * k
-                for di in range(drop_from, len(self._models)):
-                    t = self._models[di]
-                    if t is not None and t.num_leaves > 1:
-                        t.apply_shrinkage(-1.0)
-                        delta = _traverse_tree_binned(self.train_data, t)
-                        self.train_score.score = \
-                            self.train_score.score.at[di % k].add(delta)
-                del self._models[drop_from:]
-                self.iter_ = it
-                self._stopped = True
-                import warnings
-                warnings.warn("Stopped training because there are no more "
-                              "leaves that meet the split requirements")
-                break
+        with tel.phase("flush", it=self.iter_ - 1, trees=len(pend)):
+            # the record arrays were copy_to_host_async'd at dispatch time,
+            # so _assemble_entry's np.asarray calls find host-resident
+            # data; only records of still-executing queued trees block, on
+            # execution itself
+            first_idx = len(self._models)
+            for entry in pend:
+                first_idx = min(first_idx, self._assemble_entry(entry))
+            # deferred stop detection over the flushed iterations only: the
+            # first iteration in which NO class grew a tree ends training;
+            # later iterations repeated the draw and are dropped
+            # (`gbdt.cpp:379-387`), including rolling their contributions
+            # back out of the training score (under bagging a later draw
+            # may have split)
+            k = max(self.num_tree_per_iteration, 1)
+            for it in range(first_idx // k, len(self._models) // k):
+                trees = self._models[it * k:(it + 1) * k]
+                if trees and all(t is not None and t.num_leaves <= 1
+                                 for t in trees):
+                    # a rolling flush may still hold queued post-stop
+                    # iterations whose device score updates already applied
+                    # — drain them so the rollback below covers every tree
+                    if self._pending:
+                        tail, self._pending = self._pending, []
+                        for entry in tail:
+                            self._assemble_entry(entry)
+                    # keep iteration 0's constant trees (the sync path's
+                    # first-iteration case keeps them too); everything after
+                    # the stop iteration is rolled back and dropped
+                    drop_from = max(it, 1) * k
+                    for di in range(drop_from, len(self._models)):
+                        t = self._models[di]
+                        if t is not None and t.num_leaves > 1:
+                            t.apply_shrinkage(-1.0)
+                            delta = _traverse_tree_binned(
+                                self.train_data, t)
+                            self.train_score.score = self.train_score \
+                                .score.at[di % k].add(delta)
+                    del self._models[drop_from:]
+                    self.iter_ = it
+                    self._stopped = True
+                    import warnings
+                    warnings.warn(
+                        "Stopped training because there are no more "
+                        "leaves that meet the split requirements")
+                    break
         if tel.enabled:
-            # t0 makes the flush land as a trace span too (trace_out)
-            tel.add_phase_time("pipeline_flush",
-                               time.perf_counter() - _flush_t0,
-                               t0=_flush_t0)
             tel.inc("pipeline_flushes")
             tel.inc("trees_assembled", len(pend))
             if keep == 0:
@@ -392,32 +392,28 @@ class GBDT:
         """Materialize one queued pipelined tree into ``self._models``;
         returns its model index."""
         idx, rf, ri, rc, init_sc = entry
-        # span only when telemetry is on: an attached-but-idle recorder on
-        # a telemetry-off booster must record nothing (same invariant the
-        # phase timers keep)
-        tr = self.telemetry.tracer if self.telemetry.enabled else None
-        _t0 = time.perf_counter() if tr is not None else 0.0
-        tree = self.learner.assemble_host(np.asarray(rf), np.asarray(ri),
-                                          np.asarray(rc))
-        if tr is not None:
-            # per-tree host-assembly span: which tree a long flush spent
-            # its time on (the aggregate lands in phase pipeline_flush)
-            tr.add_complete("tree_assemble", _t0,
-                            time.perf_counter() - _t0, cat="train",
-                            args={"model_index": int(idx)})
-        if tree.num_leaves > 1:
-            tree.apply_shrinkage(self.shrinkage_rate)
-            if abs(init_sc) > kEpsilon:
-                tree.leaf_value[:tree.num_leaves] += init_sc
-                tree.shrinkage = 1.0
-        elif idx < self.num_tree_per_iteration:
-            # nothing splittable on the very first iteration: keep the
-            # boost-from-average constant model and add its output to the
-            # training score, matching the sync path (`gbdt.cpp:395-404`)
-            tree.leaf_value[0] = init_sc
-            if abs(init_sc) > kEpsilon:
-                self.train_score.add_constant(
-                    init_sc, idx % self.num_tree_per_iteration)
+        tel = self.telemetry
+        # where the host blocks on the device: the records of a tree whose
+        # program has retired are host-resident (copy_to_host_async at
+        # dispatch); those of a still-queued tree wait for its execution
+        with tel.phase("d2h_wait", tree=idx):
+            rf, ri, rc = np.asarray(rf), np.asarray(ri), np.asarray(rc)
+        with tel.phase("assemble_tree", tree=idx):
+            tree = self.learner.assemble_host(rf, ri, rc)
+            if tree.num_leaves > 1:
+                tree.apply_shrinkage(self.shrinkage_rate)
+                if abs(init_sc) > kEpsilon:
+                    tree.leaf_value[:tree.num_leaves] += init_sc
+                    tree.shrinkage = 1.0
+            elif idx < self.num_tree_per_iteration:
+                # nothing splittable on the very first iteration: keep the
+                # boost-from-average constant model and add its output to
+                # the training score, matching the sync path
+                # (`gbdt.cpp:395-404`)
+                tree.leaf_value[0] = init_sc
+                if abs(init_sc) > kEpsilon:
+                    self.train_score.add_constant(
+                        init_sc, idx % self.num_tree_per_iteration)
         self._models[idx] = tree
         return idx
 
@@ -517,7 +513,7 @@ class GBDT:
         cfg = self.cfg
         if cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0 \
                 and iter_ % cfg.bagging_freq == 0:
-            with self.telemetry.phase("bagging"):
+            with self.telemetry.phase("bagging", it=iter_):
                 n = self.num_data
                 bag_cnt = int(cfg.bagging_fraction * n)
                 idx = self._bag_rng.choice(n, bag_cnt, replace=False)
@@ -537,18 +533,19 @@ class GBDT:
 
     def _feature_sample(self) -> jax.Array:
         """Per-tree feature_fraction sampling (`serial_tree_learner.cpp:255-283`)."""
-        f = self.train_data.num_used_features
-        frac = self.cfg.feature_fraction
-        if frac >= 1.0:
-            if getattr(self, "_full_fmask", None) is None \
-                    or self._full_fmask.shape[0] != f:
-                self._full_fmask = jnp.ones(f, dtype=bool)
-            return self._full_fmask
-        used = max(1, int(round(f * frac)))
-        idx = self._feat_rng.choice(f, used, replace=False)
-        mask = np.zeros(f, dtype=bool)
-        mask[idx] = True
-        return jnp.asarray(mask)
+        with self.telemetry.phase("feature_sample", it=self.iter_):
+            f = self.train_data.num_used_features
+            frac = self.cfg.feature_fraction
+            if frac >= 1.0:
+                if getattr(self, "_full_fmask", None) is None \
+                        or self._full_fmask.shape[0] != f:
+                    self._full_fmask = jnp.ones(f, dtype=bool)
+                return self._full_fmask
+            used = max(1, int(round(f * frac)))
+            idx = self._feat_rng.choice(f, used, replace=False)
+            mask = np.zeros(f, dtype=bool)
+            mask[idx] = True
+            return jnp.asarray(mask)
 
     # -- gradients -----------------------------------------------------------
 
@@ -592,7 +589,7 @@ class GBDT:
                 if getattr(obj, n, None) is not None
                 and hasattr(getattr(obj, n), "shape")}
         t0 = time.perf_counter()
-        with self.telemetry.phase("gradients"):
+        with self.telemetry.phase("gradients", it=self.iter_):
             g, h = self._jit_grad_fn(self.train_score.score, arrs)
         self._sync_sampler.leg("gradients", t0, (g, h))
         return g, h
@@ -613,8 +610,6 @@ class GBDT:
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None) -> bool:
         """Returns True when training cannot continue (no splittable leaves)."""
-        if not self.telemetry.enabled:
-            return self._train_one_iter_inner(gradients, hessians)
         ss = self._sync_sampler
         if ss.sampled(self.iter_):
             # sampled-sync bracket: drain the queued pipeline so the
@@ -628,7 +623,7 @@ class GBDT:
             ss.active = True
             t0 = time.perf_counter()
             try:
-                with self.telemetry.phase("iteration"):
+                with self.telemetry.phase("iteration", it=self.iter_):
                     ret = self._train_one_iter_inner(gradients, hessians)
                     force_sync(self.train_score.score)
             finally:
@@ -637,7 +632,7 @@ class GBDT:
                 "sync.iteration", time.perf_counter() - t0, t0=t0)
             ss.probe_exchange(self.learner)
             return ret
-        with self.telemetry.phase("iteration"):
+        with self.telemetry.phase("iteration", it=self.iter_):
             return self._train_one_iter_inner(gradients, hessians)
 
     def _train_one_iter_inner(self, gradients=None, hessians=None) -> bool:
@@ -681,10 +676,15 @@ class GBDT:
                 else learner._train_tree_compact
 
             def step(score, bins_p, bag, fmask, lr):
-                g, h = obj.get_gradients(score[0], 0)
+                # device phase scopes (observability/phases.py): names in
+                # every enclosed operation's op_name, no equation
+                with scope("gradients"):
+                    g, h = obj.get_gradients(score[0], 0)
                 out = tree_fn(bins_p, g, h, bag, fmask)
                 rec_f, rec_i, rec_cat, leaf_id, leaf_out = out[:5]
-                score = score.at[0].add(lr * jnp.take(leaf_out, leaf_id))
+                with scope("score_update"):
+                    score = score.at[0].add(
+                        lr * jnp.take(leaf_out, leaf_id))
                 # out[5:] is the telemetry counter lane (present only when
                 # cfg.telemetry — the program is unchanged otherwise)
                 return (score, rec_f, rec_i, rec_cat) + tuple(out[5:])
@@ -699,7 +699,8 @@ class GBDT:
             self._lr_dev_val = self.shrinkage_rate
         fmask = self._feature_sample()
         _t0 = time.perf_counter()
-        with tel.phase("tree_dispatch"):
+        with tel.phase("dispatch", it=self.iter_,
+                       queued=len(self._pending)):
             out = self._fused_iter_fn()(
                 self.train_score.score, self.learner.bins_packed(),
                 self._bag_mask, fmask, self._lr_dev)
@@ -750,7 +751,8 @@ class GBDT:
         for k in range(self.num_tree_per_iteration):
             fmask = self._feature_sample()
             _t0 = time.perf_counter()
-            with tel.phase("tree_dispatch"):
+            with tel.phase("tree_dispatch", it=self.iter_,
+                           queued=len(self._pending)):
                 rec_f, rec_i, rec_cat, leaf_id, leaf_out = \
                     self.learner.train_async(grad[k], hess[k],
                                              self._bag_mask, fmask)
@@ -758,7 +760,7 @@ class GBDT:
                 "tree_build", _t0, (rec_f, rec_i, rec_cat, leaf_id,
                                     leaf_out))
             _t0 = time.perf_counter()
-            with tel.phase("score_update"):
+            with tel.phase("score_update", it=self.iter_):
                 self.train_score.score = _score_add_leaf(
                     self.train_score.score, leaf_out, leaf_id,
                     self._lr_dev, k)
@@ -798,7 +800,7 @@ class GBDT:
             if self.class_need_train[k] and self.train_data.num_used_features > 0:
                 fmask = self._feature_sample()
                 _t0 = time.perf_counter()
-                with tel.phase("tree_train"):
+                with tel.phase("tree_train", it=self.iter_):
                     new_tree, leaf_id = self.learner.train(
                         grad[k], hess[k], self._bag_mask, fmask)
                 # on sampled iterations record tree_train as a sync leg:
@@ -817,7 +819,7 @@ class GBDT:
                 # attribution table's leg sum tracks the iteration wall on
                 # the non-pipelined path too
                 _t0 = time.perf_counter()
-                with tel.phase("score_update"):
+                with tel.phase("score_update", it=self.iter_):
                     if self.objective is not None:
                         score_np = np.asarray(self.train_score.score[k])
                         self.objective.renew_tree_output(

@@ -77,9 +77,11 @@ def run_train(params: Dict[str, str], cfg: Config) -> None:
     from . import engine
     from .dataset import Dataset
 
-    # --telemetry-out / --trace-out imply telemetry: asking for the
-    # report (or for spans, which ride the phase timers) IS opting in
-    if (cfg.telemetry_out or cfg.trace_out) and not cfg.telemetry:
+    # --telemetry-out implies telemetry: asking for the report IS opting
+    # in.  --trace-out does not: spans are kept whenever a recorder
+    # listens, and telemetry=true compiles another device program (the
+    # counter lane)
+    if cfg.telemetry_out and not cfg.telemetry:
         cfg.telemetry = True
         params = dict(params, telemetry="true")
     if cfg.resume:

@@ -467,8 +467,9 @@ class Config:
     profile_trace_dir: str = ""
     # write a Chrome trace-event JSON of the host-side structured spans
     # (observability/trace.py — open in Perfetto / chrome://tracing).
-    # Training: spans ride the existing phase timers, so trace_out implies
-    # telemetry=True; written when engine.train returns.  Serving
+    # Training: the spans of observability/phases.py (iteration, dispatch,
+    # flush, d2h_wait, assemble_tree, ...), recorded whether or not
+    # telemetry is on; written when engine.train returns.  Serving
     # (task=serve): per-request/batch/stage spans linked by trace_id,
     # written at server stop.  Host-only + monotonic clocks: the traced
     # XLA programs are untouched (jaxprs byte-identical with tracing off)
@@ -616,7 +617,7 @@ class Config:
     # (num_leaves - 1) correction splits, so a guard stops batching near
     # that reserve.  1 = the round-4 one-miss-per-pass behavior;
     # -1 = auto (currently 4 at every scale — the round-5 sweep winner;
-    # re-sweep {2,3,4,6} rides profiling/profile_stall_batch.py)
+    # re-sweep {2,3,4,6} was never run on the chip)
     tpu_wave_stall_batch: int = -1
     # fuse the batched replay correction's TOP member into the
     # span-vectorized partition stage whenever its covering span fits the

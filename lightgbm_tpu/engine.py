@@ -200,9 +200,11 @@ class Booster:
 
     def eval_valid(self, feval=None) -> List[Tuple]:
         out = []
-        for i, name in enumerate(self.gbdt.valid_names):
-            out.extend(self._eval_set(name, self.gbdt.valid_scores[i],
-                                      self.gbdt.valid_metrics[i], feval, None))
+        with self.gbdt.telemetry.phase("eval_valid", it=self.gbdt.iter_):
+            for i, name in enumerate(self.gbdt.valid_names):
+                out.extend(self._eval_set(
+                    name, self.gbdt.valid_scores[i],
+                    self.gbdt.valid_metrics[i], feval, None))
         return out
 
     def _eval_set(self, name, updater, metrics, feval, dataset) -> List[Tuple]:
@@ -394,11 +396,6 @@ def train(params: Dict, train_set: Dataset, num_boost_round: int = 100,
     use_compile_cache()
     params = dict(params or {})
     cfg_probe = Config.from_params(params)
-    if cfg_probe.trace_out and not cfg_probe.telemetry:
-        # spans ride the phase timers, so asking for a trace opts into
-        # telemetry (same implication the CLI applies for --telemetry-out)
-        params["telemetry"] = True
-        cfg_probe = Config.from_params(params)
     if "num_iterations" not in params and num_boost_round is not None:
         params["num_iterations"] = num_boost_round
     num_boost_round = Config.from_params(params).num_iterations
@@ -443,8 +440,9 @@ def train(params: Dict, train_set: Dataset, num_boost_round: int = 100,
         train_set.categorical_feature = categorical_feature
 
     # structured span recorder (observability/trace.py): host-side only —
-    # attaching it cannot change a traced program, and with trace_out
-    # unset nothing is allocated.  Created BEFORE the Booster (and
+    # attaching it cannot change a traced program (and needs no
+    # ``telemetry``: a span is kept when a recorder listens), and with
+    # trace_out unset nothing is allocated.  Created BEFORE the Booster (and
     # registered process-wide) so the streaming loader's ingestion-chunk
     # spans — recorded during dataset construction, before the GBDT's
     # Telemetry exists — land in the same flight recorder
@@ -566,19 +564,9 @@ def train(params: Dict, train_set: Dataset, num_boost_round: int = 100,
     if booster.best_iteration <= 0:
         for name, mname, val, _ in (evaluation_result_list or []):
             booster.best_score.setdefault(name, {})[mname] = val
-    if _tracing and cfg_probe.telemetry:
-        # automated capture-and-parse: map the profiler's device events
-        # back to the named legs and the ledger's collective sites; lands
-        # in the report's distributed.profile (None when the backend
-        # wrote no Chrome-format trace — xplane-only captures)
-        from .observability.attribution import attribute_profile
-        prof = attribute_profile(
-            cfg_probe.profile_trace_dir,
-            getattr(booster.gbdt.learner, "_ledger", None))
-        if prof is not None:
-            booster.gbdt.telemetry.set_distributed(profile=prof)
-    if booster._mh_net is not None and cfg_probe.telemetry \
-            and (cfg_probe.telemetry_out or cfg_probe.trace_out):
+    if booster._mh_net is not None and (
+            (cfg_probe.telemetry and cfg_probe.telemetry_out)
+            or cfg_probe.trace_out):
         # one clock-offset handshake serves both the report's
         # distributed.clock and the per-rank trace metadata below
         from .observability import podtrace as _podtrace

@@ -44,6 +44,7 @@ from .binning import MISSING_NAN, MISSING_ZERO
 from .config import Config
 from .dataset import _ConstructedDataset
 from .learner import (NUM_REC_FIELDS, REC_VALID, TPUTreeLearner, _FeatCand)
+from .observability.phases import scope
 from .ops.hist_pallas import (build_histogram_packed, pack_bin_words,
                               unpack_bin_words)
 from .ops.histogram import _on_tpu, build_histogram_onehot
@@ -700,9 +701,10 @@ class CompactTPUTreeLearner(TPUTreeLearner):
         self._partition_branches = [
             self._make_partition_branch(S, sort_mode=S > self._sort_cutoff)
             for S in self._win_sizes]
-        state = self._init_root_compact(bins_p, grad, hess, bag,
-                                        feature_mask)
-        state = self._forced_phase_compact(state, feature_mask)
+        with scope("root"):
+            state = self._init_root_compact(bins_p, grad, hess, bag,
+                                            feature_mask)
+            state = self._forced_phase_compact(state, feature_mask)
 
         # records are written at cursor ``num_leaves - 1`` (number of
         # successful splits so far), so an aborted forced phase can't leave
@@ -711,16 +713,18 @@ class CompactTPUTreeLearner(TPUTreeLearner):
             return (st.num_leaves < self.num_leaves) & \
                 (jnp.max(st.cand_f[:, CF_GAIN]) > 0.0)
 
-        state = jax.lax.while_loop(
-            cond,
-            lambda st: self._split_step_compact(st, feature_mask,
-                                                st.num_leaves - 1),
-            state)
+        with scope("grow"):
+            state = jax.lax.while_loop(
+                cond,
+                lambda st: self._split_step_compact(st, feature_mask,
+                                                    st.num_leaves - 1),
+                state)
         # leaf partition in ORIGINAL row order for the score updater
         # descatter to original row order via a 2-lane sort (~3x cheaper
         # than the equivalent scatter on TPU)
-        leaf_id = lax.sort([state.rid_p, state.lid_p], num_keys=1)[1]
-        leaf_output = state.leaf_f[:, LF_OUT].astype(jnp.float32)
+        with scope("emit"):
+            leaf_id = lax.sort([state.rid_p, state.lid_p], num_keys=1)[1]
+            leaf_output = state.leaf_f[:, LF_OUT].astype(jnp.float32)
         return (state.rec_f, state.rec_i, state.rec_cat, leaf_id,
                 leaf_output)
 

@@ -10,7 +10,7 @@ best-first (leaf-wise) semantics:
   1. **Grow.**  Each wave splits the top-W positive-gain frontier leaves at
      once: one full-array stable sort re-compacts every split window
      simultaneously (per-row split parameters come from an MXU mask-matmul,
-     never an XLA gather — `profiling/profile_gather_alts.py`), then the
+     never an XLA gather: ~10x slower on the chip, round 5), then the
      smaller-child histograms run per member (subtraction for siblings) and
      all 2W children are scanned in one batched split finder.  Replayed
      against real split sequences, top-W selection reproduces the true
@@ -57,6 +57,7 @@ from .learner_compact import (CF_GAIN, CF_LCNT, CF_LOUT, CF_LSG, CF_LSH,
                               CI_FLAGS, CI_THR, LF_CNT, LF_DEPTH, LF_MAX_C,
                               LF_MIN_C, LF_OUT, LF_SUM_G, LF_SUM_H, NUM_CF,
                               NUM_CI, NUM_LF, CompactTPUTreeLearner)
+from .observability.phases import scope
 from .observability.telemetry import (TEL_FROZEN_MEMBERS, TEL_GROW_SPLITS,
                                       TEL_NSLOTS, TEL_POPS,
                                       TEL_STALL_EXTRAS, TEL_STALL_SORT_MODE,
@@ -265,7 +266,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             self.budget + int(np.ceil(self.budget * ov)),
             2 * self.budget)
         # level-wise opening depth (see Config.tpu_wave_open_levels).
-        # MEASURED on the v5e (round 5, profiling/profile_opening.py + a
+        # MEASURED on the v5e (round 5, an opening sweep since deleted + a
         # device trace): a full-array multi-slot hist pass floors at ~6 ms
         # of one-hot VPU work regardless of K, so an opening level costs
         # ~8-18 ms against the ~10.6 ms wave it replaces, plus a ~6 ms
@@ -358,7 +359,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             # iteration (rf.py keeps _rf_grad across iters); donating
             # those buffers would invalidate them after the first tree
             self._donate = False
-        # dev-only phase ablation for profiling (profile_wave_phases.py):
+        # dev-only phase ablation (its profiling script is deleted):
         # comma-set of {nohist, noscan, nosort} — NOT a user knob; a leaked
         # env var would silently train WRONG trees, so warn loudly
         import os
@@ -465,9 +466,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         else:
             w = jnp.stack([grad * bag, hess * bag, bag], axis=0)
         lid0 = jnp.zeros(n, jnp.int32)
-        root_hist = self._reduce_hist(
-            self._hist_branches[-1](bins_p, w, lid0, jnp.int32(0),
-                                    jnp.int32(n), jnp.int32(0)))
+        with scope("hist"):
+            root_hist = self._reduce_hist(
+                self._hist_branches[-1](bins_p, w, lid0, jnp.int32(0),
+                                        jnp.int32(n), jnp.int32(0)))
         if self._quant:
             # root totals from the DEQUANTIZED lanes so FixHistogram's
             # totals-minus-others algebra matches the histogram contents;
@@ -481,9 +483,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             cnt = self._global_scalar(jnp.sum(bag.astype(acc)))
         md = int(self.cfg.max_depth)
         depth_ok = jnp.asarray([True if md <= 0 else md > 0])
-        cf, ci, cb = self._cand_rows_batch(
-            root_hist[None], sum_g[None], sum_h[None], cnt[None],
-            feature_mask, depth_ok, None)
+        with scope("scan"):
+            cf, ci, cb = self._cand_rows_batch(
+                root_hist[None], sum_g[None], sum_h[None], cnt[None],
+                feature_mask, depth_ok, None)
         root_lf = jnp.asarray([0.0, 0.0, 0.0, 0.0, 0.0, -jnp.inf, jnp.inf],
                               acc)
         root_lf = root_lf.at[LF_SUM_G].set(sum_g).at[LF_SUM_H].set(sum_h) \
@@ -591,11 +594,12 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             h_par = st.hist_pool[ph_k]
             kw = {k: v for k, v in self._split_kwargs.items()
                   if k != "skip_missing_scan"}
-            num, hl, hr = fused_child_scans(
-                h_small, h_par, left_small, sg2, sh2, cn2,
-                self.f_num_bin, self.f_missing, self.f_default_bin,
-                feature_mask & self._cat_mask,
-                interpret=self._scan_interpret, **kw)
+            with scope("scan"):
+                num, hl, hr = fused_child_scans(
+                    h_small, h_par, left_small, sg2, sh2, cn2,
+                    self.f_num_bin, self.f_missing, self.f_default_bin,
+                    feature_mask & self._cat_mask,
+                    interpret=self._scan_interpret, **kw)
             st = st._replace(
                 hist_pool=st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr))
             f = self.num_features
@@ -611,8 +615,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 right_output=num.right_output)
             cf2, ci2, cb2 = self._pack_cand_rows(cands, depth_ok)
         else:
-            cf2, ci2, cb2 = self._cand_rows_batch(
-                hists2, sg2, sh2, cn2, feature_mask, depth_ok, constraints)
+            with scope("scan"):
+                cf2, ci2, cb2 = self._cand_rows_batch(
+                    hists2, sg2, sh2, cn2, feature_mask, depth_ok,
+                    constraints)
         # per-child leaf rows
         lf_l = jnp.stack([pcf[:, CF_LSG], pcf[:, CF_LSH], pcf[:, CF_LCNT],
                           pcf[:, CF_LOUT], cd, lmin, lmax], 1)
@@ -657,342 +663,349 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         M, n = self.M, self._rows_len()
         fw = self.fw
         self._coll_ctx = ("grow_wave", "wave")
-        # ---- select the wave: top-W positive-gain frontier leaves
-        g = self._pool_gains(st)
-        gv, wi = lax.top_k(g, W)
-        rem = self.grow_budget - st.num_splits
-        valid = (gv > 0.0) & (jnp.arange(W) < rem)
-        pos = jnp.cumsum(valid.astype(jnp.int32)) - valid.astype(jnp.int32)
-        lslot = st.num_nodes + 2 * pos
-        rslot = lslot + 1
-        # ---- per-member split params (small gathers over node tables)
-        feat = st.cand_i[wi, CI_FEAT]
-        thr = st.cand_i[wi, CI_THR]
-        flags = st.cand_i[wi, CI_FLAGS]
-        dleft = (flags & 1).astype(jnp.float32)
-        iscat = ((flags & 2) >> 1).astype(jnp.float32)
-        ps = st.node_i[wi, 0]
-        cw = st.node_i[wi, 1]
-        col = self.fw_col[feat]
-        widx = col // 4
-        shift = (col % 4) * 8
-        mt = self.f_missing[feat]
-        db = self.f_default_bin[feat]
-        nb = self.f_num_bin[feat]
-        boff = self.fw_goff[feat]
-        bnd = self.fw_bnd[feat]
-        # members at or below the wave cutoff split in place (lid rewrite,
-        # children share the parent span); only keyed members' rows get new
-        # window keys.  Opening mode keys EVERY valid member (children get
-        # logical windows now, physical compaction happens at the deferred
-        # materialization sort); normal mode keys the members it sorts
-        if opening:
-            sortable = valid
-        else:
-            sortable = valid & (cw > self._wave_cutoff)
-        P = jnp.stack([widx.astype(jnp.float32), shift.astype(jnp.float32),
-                       thr.astype(jnp.float32), dleft, iscat,
-                       mt.astype(jnp.float32), db.astype(jnp.float32),
-                       nb.astype(jnp.float32), boff.astype(jnp.float32),
-                       bnd.astype(jnp.float32), lslot.astype(jnp.float32),
-                       rslot.astype(jnp.float32),
-                       sortable.astype(jnp.float32)],
-                      axis=1)                                       # (W, C)
-        cat16 = None
-        if self.has_categorical:
-            cb_w = st.cand_b[wi]                                # (W, Wc)
-            cat16 = jnp.concatenate(
-                [(cb_w & jnp.uint32(0xFFFF)).astype(jnp.float32),
-                 (cb_w >> jnp.uint32(16)).astype(jnp.float32)], axis=1)
-
-        # -- pass 1 (per row chunk): wave-member mask -> split params via
-        # MXU mask-matmul (gathers are ~5 ms/M rows on TPU, the one-hot
-        # contraction ~0.5 ms), per-row decision, partial exact counts
-        def decide(bins_c, lid_c, bag_c):
-            ch_n = lid_c.shape[0]
-            mask = (lid_c[:, None] == wi[None, :]) & valid[None, :]
-            mask_f = mask.astype(jnp.float32)
-            pm = lax.dot_general(mask_f, P, (((1,), (0,)), ((), ())),
-                                 precision=_HIGH)               # (ch, C)
-            in_wave = jnp.any(mask, axis=1)
-            ri = lambda c: jnp.rint(pm[:, c]).astype(jnp.int32)
-            widx_r, shift_r, thr_r = ri(0), ri(1), ri(2)
-            dleft_r = pm[:, 3] > 0.5
-            iscat_r = pm[:, 4] > 0.5
-            mt_r, db_r, nb_r = ri(5), ri(6), ri(7)
-            boff_r, bnd_r = ri(8), ri(9)
-            lslot_r, rslot_r = ri(10), ri(11)
-            sortable_r = pm[:, 12] > 0.5
-            # per-row decision (NumericalDecisionInner `tree.h:233-249`)
-            word = self._word_select(bins_c, widx_r)
-            code = (word >> shift_r) & 0xFF
-            if self._bundle is not None:
-                r = code - boff_r
-                in_r = (r >= 0) & (r < nb_r - 1)
-                dec = r + (r >= db_r).astype(r.dtype)
-                frow = jnp.where(bnd_r == 1, jnp.where(in_r, dec, db_r),
-                                 code)
+        with scope("select"):
+            # ---- select the wave: top-W positive-gain frontier leaves
+            g = self._pool_gains(st)
+            gv, wi = lax.top_k(g, W)
+            rem = self.grow_budget - st.num_splits
+            valid = (gv > 0.0) & (jnp.arange(W) < rem)
+            pos = jnp.cumsum(valid.astype(jnp.int32)) - valid.astype(jnp.int32)
+            lslot = st.num_nodes + 2 * pos
+            rslot = lslot + 1
+            # ---- per-member split params (small gathers over node tables)
+            feat = st.cand_i[wi, CI_FEAT]
+            thr = st.cand_i[wi, CI_THR]
+            flags = st.cand_i[wi, CI_FLAGS]
+            dleft = (flags & 1).astype(jnp.float32)
+            iscat = ((flags & 2) >> 1).astype(jnp.float32)
+            ps = st.node_i[wi, 0]
+            cw = st.node_i[wi, 1]
+            col = self.fw_col[feat]
+            widx = col // 4
+            shift = (col % 4) * 8
+            mt = self.f_missing[feat]
+            db = self.f_default_bin[feat]
+            nb = self.f_num_bin[feat]
+            boff = self.fw_goff[feat]
+            bnd = self.fw_bnd[feat]
+            # members at or below the wave cutoff split in place (lid rewrite,
+            # children share the parent span); only keyed members' rows get new
+            # window keys.  Opening mode keys EVERY valid member (children get
+            # logical windows now, physical compaction happens at the deferred
+            # materialization sort); normal mode keys the members it sorts
+            if opening:
+                sortable = valid
             else:
-                frow = code
-            is_missing = ((mt_r == MISSING_ZERO) & (frow == db_r)) | \
-                         ((mt_r == MISSING_NAN) & (frow == nb_r - 1))
-            go_left = jnp.where(is_missing, dleft_r, frow <= thr_r)
+                sortable = valid & (cw > self._wave_cutoff)
+            P = jnp.stack([widx.astype(jnp.float32), shift.astype(jnp.float32),
+                           thr.astype(jnp.float32), dleft, iscat,
+                           mt.astype(jnp.float32), db.astype(jnp.float32),
+                           nb.astype(jnp.float32), boff.astype(jnp.float32),
+                           bnd.astype(jnp.float32), lslot.astype(jnp.float32),
+                           rslot.astype(jnp.float32),
+                           sortable.astype(jnp.float32)],
+                          axis=1)                                       # (W, C)
+            cat16 = None
             if self.has_categorical:
-                catpm = lax.dot_general(mask_f, cat16,
-                                        (((1,), (0,)), ((), ())),
-                                        precision=_HIGH)        # (ch, 2*Wc)
-                j = frow >> 5
-                lo = jnp.zeros(ch_n, jnp.float32)
-                hi = jnp.zeros(ch_n, jnp.float32)
-                for jj in range(self.cat_W):
-                    sel = j == jj
-                    lo = lo + jnp.where(sel, catpm[:, jj], 0.0)
-                    hi = hi + jnp.where(sel, catpm[:, self.cat_W + jj], 0.0)
-                catw = (jnp.rint(hi).astype(jnp.int32).astype(jnp.uint32)
-                        << jnp.uint32(16)) | \
-                    jnp.rint(lo).astype(jnp.int32).astype(jnp.uint32)
-                cat_left = (catw >> (frow & 31).astype(jnp.uint32)) & 1
-                go_left = jnp.where(iscat_r, cat_left == 1, go_left)
-            go_left = go_left & in_wave
-            # exact integer counts via f32-exact one-hot contractions: the
-            # chunk bound keeps per-chunk counts <= 2^20 (f32-exact); the
-            # cross-chunk sum runs in int32, so exactness holds at ANY row
-            # count (this was the old `n_pad < 2^24` eligibility gate)
-            gl_f = go_left.astype(jnp.float32)
-            bag_f = bag_c.astype(jnp.float32)
-            w3 = jnp.stack([gl_f, gl_f * bag_f, bag_f], 0)
-            cnt3 = lax.dot_general(w3, mask_f, (((1,), (0,)), ((), ())),
-                                   precision=_HIGH)             # (3, W)
-            lid_new = jnp.where(in_wave,
-                                jnp.where(go_left, lslot_r, rslot_r), lid_c)
-            return (go_left, in_wave & sortable_r, lid_new,
-                    jnp.rint(cnt3).astype(jnp.int32))
+                cb_w = st.cand_b[wi]                                # (W, Wc)
+                cat16 = jnp.concatenate(
+                    [(cb_w & jnp.uint32(0xFFFF)).astype(jnp.float32),
+                     (cb_w >> jnp.uint32(16)).astype(jnp.float32)], axis=1)
 
-        Cm = 1
-        while n // Cm > self._row_chunk and Cm < 1024 \
-                and n % (Cm * 2) == 0:
-            Cm *= 2
-        bag_b = st.w_p[2] > 0.5
-        if Cm == 1:
-            go_left, sort_r, lid_p, cnt3 = decide(st.bins_p, st.lid_p, bag_b)
-        else:
-            ch = n // Cm
-            go_left, sort_r, lid_p, cnt3c = lax.map(
-                lambda a: decide(*a),
-                (st.bins_p.reshape(fw, Cm, ch).transpose(1, 0, 2),
-                 st.lid_p.reshape(Cm, ch), bag_b.reshape(Cm, ch)))
-            go_left = go_left.reshape(-1)
-            sort_r = sort_r.reshape(-1)
-            lid_p = lid_p.reshape(-1)
-            cnt3 = jnp.sum(cnt3c, axis=0, dtype=jnp.int32)
-        cnt3 = self._sync_counts3(cnt3)
-        lc_w = cnt3[0]
-        lc_bag = cnt3[1]
-        c_bag = cnt3[2]
+        with scope("partition"):
+            # -- pass 1 (per row chunk): wave-member mask -> split params via
+            # MXU mask-matmul (gathers are ~5 ms/M rows on TPU, the one-hot
+            # contraction ~0.5 ms), per-row decision, partial exact counts
+            def decide(bins_c, lid_c, bag_c):
+                ch_n = lid_c.shape[0]
+                mask = (lid_c[:, None] == wi[None, :]) & valid[None, :]
+                mask_f = mask.astype(jnp.float32)
+                pm = lax.dot_general(mask_f, P, (((1,), (0,)), ((), ())),
+                                     precision=_HIGH)               # (ch, C)
+                in_wave = jnp.any(mask, axis=1)
+                ri = lambda c: jnp.rint(pm[:, c]).astype(jnp.int32)
+                widx_r, shift_r, thr_r = ri(0), ri(1), ri(2)
+                dleft_r = pm[:, 3] > 0.5
+                iscat_r = pm[:, 4] > 0.5
+                mt_r, db_r, nb_r = ri(5), ri(6), ri(7)
+                boff_r, bnd_r = ri(8), ri(9)
+                lslot_r, rslot_r = ri(10), ri(11)
+                sortable_r = pm[:, 12] > 0.5
+                # per-row decision (NumericalDecisionInner `tree.h:233-249`)
+                word = self._word_select(bins_c, widx_r)
+                code = (word >> shift_r) & 0xFF
+                if self._bundle is not None:
+                    r = code - boff_r
+                    in_r = (r >= 0) & (r < nb_r - 1)
+                    dec = r + (r >= db_r).astype(r.dtype)
+                    frow = jnp.where(bnd_r == 1, jnp.where(in_r, dec, db_r),
+                                     code)
+                else:
+                    frow = code
+                is_missing = ((mt_r == MISSING_ZERO) & (frow == db_r)) | \
+                             ((mt_r == MISSING_NAN) & (frow == nb_r - 1))
+                go_left = jnp.where(is_missing, dleft_r, frow <= thr_r)
+                if self.has_categorical:
+                    catpm = lax.dot_general(mask_f, cat16,
+                                            (((1,), (0,)), ((), ())),
+                                            precision=_HIGH)        # (ch, 2*Wc)
+                    j = frow >> 5
+                    lo = jnp.zeros(ch_n, jnp.float32)
+                    hi = jnp.zeros(ch_n, jnp.float32)
+                    for jj in range(self.cat_W):
+                        sel = j == jj
+                        lo = lo + jnp.where(sel, catpm[:, jj], 0.0)
+                        hi = hi + jnp.where(sel, catpm[:, self.cat_W + jj], 0.0)
+                    catw = (jnp.rint(hi).astype(jnp.int32).astype(jnp.uint32)
+                            << jnp.uint32(16)) | \
+                        jnp.rint(lo).astype(jnp.int32).astype(jnp.uint32)
+                    cat_left = (catw >> (frow & 31).astype(jnp.uint32)) & 1
+                    go_left = jnp.where(iscat_r, cat_left == 1, go_left)
+                go_left = go_left & in_wave
+                # exact integer counts via f32-exact one-hot contractions: the
+                # chunk bound keeps per-chunk counts <= 2^20 (f32-exact); the
+                # cross-chunk sum runs in int32, so exactness holds at ANY row
+                # count (this was the old `n_pad < 2^24` eligibility gate)
+                gl_f = go_left.astype(jnp.float32)
+                bag_f = bag_c.astype(jnp.float32)
+                w3 = jnp.stack([gl_f, gl_f * bag_f, bag_f], 0)
+                cnt3 = lax.dot_general(w3, mask_f, (((1,), (0,)), ((), ())),
+                                       precision=_HIGH)             # (3, W)
+                lid_new = jnp.where(in_wave,
+                                    jnp.where(go_left, lslot_r, rslot_r), lid_c)
+                return (go_left, in_wave & sortable_r, lid_new,
+                        jnp.rint(cnt3).astype(jnp.int32))
 
-        # -- pass 2: window-order keys.  INVARIANT: every leaf's rows carry
-        # key = 2 * (its window start) — strictly increasing with position,
-        # so the stable sort is the identity on untouched leaves and
-        # partitions each split window in place.  The children's starts are
-        # already known pre-sort (s and s+lc), so both get final keys here.
-        # Starts are routed through the contraction as hi/lo 12-bit planes
-        # (one nonzero per row -> each plane f32-exact at any N).
-        # Partition mode needs no carried keys (each wave materializes its
-        # own windows from wave-local destinations) — the pass is skipped.
-        if self._use_partition and not opening:
-            key_p = st.key_p
-        else:
-            starts2 = jnp.stack([ps, ps + lc_w], axis=1)        # (W, 2)
-            planes = jnp.concatenate(
-                [(starts2 >> 12).astype(jnp.float32),
-                 (starts2 & 0xFFF).astype(jnp.float32)], axis=1)  # (W, 4)
-
-            def keys(lid_old_c, go_c, sort_c, key_c):
-                mask_f = ((lid_old_c[:, None] == wi[None, :])
-                          & valid[None, :]).astype(jnp.float32)
-                ks = lax.dot_general(mask_f, planes,
-                                     (((1,), (0,)), ((), ())),
-                                     precision=_HIGH)           # (ch, 4)
-                ki = jnp.rint(ks).astype(jnp.int32)
-                kl = 2 * ((ki[:, 0] << 12) + ki[:, 2])
-                kr = 2 * ((ki[:, 1] << 12) + ki[:, 3])
-                return jnp.where(sort_c, jnp.where(go_c, kl, kr), key_c)
-
+            Cm = 1
+            while n // Cm > self._row_chunk and Cm < 1024 \
+                    and n % (Cm * 2) == 0:
+                Cm *= 2
+            bag_b = st.w_p[2] > 0.5
             if Cm == 1:
-                key_p = keys(st.lid_p, go_left, sort_r, st.key_p)
+                go_left, sort_r, lid_p, cnt3 = decide(st.bins_p, st.lid_p, bag_b)
             else:
                 ch = n // Cm
-                key_p = lax.map(
-                    lambda a: keys(*a),
-                    (st.lid_p.reshape(Cm, ch), go_left.reshape(Cm, ch),
-                     sort_r.reshape(Cm, ch),
-                     st.key_p.reshape(Cm, ch))).reshape(-1)
-        # ---- ONE stable sort re-compacts every sortable split window.
-        # Skipped when the whole wave froze (the tree's bottom waves), when
-        # opening mode defers ALL compaction to the materialization sort,
-        # and — under sort-deferral alternation — on every wave without a
-        # PENDING key set: a deferring wave only assigns logical windows +
-        # keys, and the NEXT wave's single sort materializes both levels.
-        do_sort = jnp.any(sortable)
-        if opening:
-            st = st._replace(lid_p=lid_p, key_p=key_p)
-            sorted_now = jnp.asarray(False)
-        elif self._use_partition and "nosort" not in self._ablate:
-            # ---- Pallas stable partition (ops/partition_pallas.py): the
-            # permutation the stable sort produces, computed directly —
-            # per-row destinations from two exclusive prefix sums over
-            # the left/right flags plus per-member window bases routed
-            # through the same mask-matmul as the key pass, then one
-            # chunked byte-plane permute kernel.  Record-exact vs the
-            # sort (tests/test_partition.py).
-            sort_now = do_sort
+                go_left, sort_r, lid_p, cnt3c = lax.map(
+                    lambda a: decide(*a),
+                    (st.bins_p.reshape(fw, Cm, ch).transpose(1, 0, 2),
+                     st.lid_p.reshape(Cm, ch), bag_b.reshape(Cm, ch)))
+                go_left = go_left.reshape(-1)
+                sort_r = sort_r.reshape(-1)
+                lid_p = lid_p.reshape(-1)
+                cnt3 = jnp.sum(cnt3c, axis=0, dtype=jnp.int32)
+            cnt3 = self._sync_counts3(cnt3)
+            lc_w = cnt3[0]
+            lc_bag = cnt3[1]
+            c_bag = cnt3[2]
 
-            def run_partition(args):
-                from .ops.partition_pallas import (apply_partition,
-                                                   exclusive_cumsum_i32)
-                bins_p_i, w_p_i, rid_p_i, lid_p_i = args
-                gl = sort_r & go_left
-                gr = sort_r & ~go_left
-                cum = exclusive_cumsum_i32(
-                    jnp.stack([gl, gr]).astype(jnp.int32))
-                cl, cr = cum[0], cum[1]
-                active = sortable
-                ps_s = jnp.where(active, ps, 0)
-                cl_ps = jnp.take(cl, ps_s)
-                cr_ps = jnp.take(cr, ps_s)
-                # member bases shifted by +n so the 13/12-bit plane split
-                # stays non-negative (each plane has one nonzero per row
-                # -> f32-exact at any N <= 2^24)
-                base_l = ps + n - cl_ps
-                base_r = ps + lc_w + n - cr_ps
-                dplanes = jnp.stack(
-                    [(base_l >> 12).astype(jnp.float32),
-                     (base_l & 0xFFF).astype(jnp.float32),
-                     (base_r >> 12).astype(jnp.float32),
-                     (base_r & 0xFFF).astype(jnp.float32)],
-                    axis=1)                                     # (W, 4)
+            # -- pass 2: window-order keys.  INVARIANT: every leaf's rows carry
+            # key = 2 * (its window start) — strictly increasing with position,
+            # so the stable sort is the identity on untouched leaves and
+            # partitions each split window in place.  The children's starts are
+            # already known pre-sort (s and s+lc), so both get final keys here.
+            # Starts are routed through the contraction as hi/lo 12-bit planes
+            # (one nonzero per row -> each plane f32-exact at any N).
+            # Partition mode needs no carried keys (each wave materializes its
+            # own windows from wave-local destinations) — the pass is skipped.
+            if self._use_partition and not opening:
+                key_p = st.key_p
+            else:
+                starts2 = jnp.stack([ps, ps + lc_w], axis=1)        # (W, 2)
+                planes = jnp.concatenate(
+                    [(starts2 >> 12).astype(jnp.float32),
+                     (starts2 & 0xFFF).astype(jnp.float32)], axis=1)  # (W, 4)
 
-                def dests(lid_old_c, go_c, sort_c, pos_c, cl_c, cr_c):
+                def keys(lid_old_c, go_c, sort_c, key_c):
                     mask_f = ((lid_old_c[:, None] == wi[None, :])
                               & valid[None, :]).astype(jnp.float32)
-                    ks = lax.dot_general(mask_f, dplanes,
+                    ks = lax.dot_general(mask_f, planes,
                                          (((1,), (0,)), ((), ())),
-                                         precision=_HIGH)       # (ch, 4)
+                                         precision=_HIGH)           # (ch, 4)
                     ki = jnp.rint(ks).astype(jnp.int32)
-                    bl = (ki[:, 0] << 12) + ki[:, 1] - n
-                    br = (ki[:, 2] << 12) + ki[:, 3] - n
-                    return jnp.where(
-                        sort_c, jnp.where(go_c, bl + cl_c, br + cr_c),
-                        pos_c)
+                    kl = 2 * ((ki[:, 0] << 12) + ki[:, 2])
+                    kr = 2 * ((ki[:, 1] << 12) + ki[:, 3])
+                    return jnp.where(sort_c, jnp.where(go_c, kl, kr), key_c)
 
-                pos = jnp.arange(n, dtype=jnp.int32)
                 if Cm == 1:
-                    dest = dests(st.lid_p, go_left, sort_r, pos, cl, cr)
+                    key_p = keys(st.lid_p, go_left, sort_r, st.key_p)
                 else:
                     ch = n // Cm
-                    dest = lax.map(
-                        lambda a: dests(*a),
-                        (st.lid_p.reshape(Cm, ch),
-                         go_left.reshape(Cm, ch),
-                         sort_r.reshape(Cm, ch), pos.reshape(Cm, ch),
-                         cl.reshape(Cm, ch),
-                         cr.reshape(Cm, ch))).reshape(-1)
-                return apply_partition(
-                    bins_p_i, w_p_i, rid_p_i, lid_p_i, dest,
-                    sort_r.astype(jnp.int32), ps, lc_w, cw, active,
-                    cl, cr, cl_ps, cr_ps,
-                    interpret=self._partition_interpret)
-
-            bins_p, w_p, rid_p, lid_p = lax.cond(
-                sort_now, run_partition, lambda a: a,
-                (st.bins_p, st.w_p, st.rid_p, lid_p))
-            st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
-                             lid_p=lid_p)
-            sorted_now = sort_now
-        elif "nosort" not in self._ablate:
-            if self._defer_sorts:
-                sort_now = st.pending
-            else:
+                    key_p = lax.map(
+                        lambda a: keys(*a),
+                        (st.lid_p.reshape(Cm, ch), go_left.reshape(Cm, ch),
+                         sort_r.reshape(Cm, ch),
+                         st.key_p.reshape(Cm, ch))).reshape(-1)
+            # ---- ONE stable sort re-compacts every sortable split window.
+            # Skipped when the whole wave froze (the tree's bottom waves), when
+            # opening mode defers ALL compaction to the materialization sort,
+            # and — under sort-deferral alternation — on every wave without a
+            # PENDING key set: a deferring wave only assigns logical windows +
+            # keys, and the NEXT wave's single sort materializes both levels.
+            do_sort = jnp.any(sortable)
+            if opening:
+                st = st._replace(lid_p=lid_p, key_p=key_p)
+                sorted_now = jnp.asarray(False)
+            elif self._use_partition and "nosort" not in self._ablate:
+                # ---- Pallas stable partition (ops/partition_pallas.py): the
+                # permutation the stable sort produces, computed directly —
+                # per-row destinations from two exclusive prefix sums over
+                # the left/right flags plus per-member window bases routed
+                # through the same mask-matmul as the key pass, then one
+                # chunked byte-plane permute kernel.  Record-exact vs the
+                # sort (tests/test_partition.py).
                 sort_now = do_sort
 
-            def run_sort(args):
-                key_p, bins_p, w_p, rid_p, lid_p = args
-                ops = ([key_p] + [bins_p[i] for i in range(fw)]
-                       + [w_p[0], w_p[1], w_p[2], rid_p, lid_p])
-                sd = lax.sort(ops, num_keys=1, is_stable=True)
-                return (sd[0], jnp.stack(sd[1:1 + fw]),
-                        jnp.stack(sd[1 + fw:4 + fw]), sd[4 + fw], sd[5 + fw])
+                def run_partition(args):
+                    from .ops.partition_pallas import (apply_partition,
+                                                       exclusive_cumsum_i32)
+                    bins_p_i, w_p_i, rid_p_i, lid_p_i = args
+                    gl = sort_r & go_left
+                    gr = sort_r & ~go_left
+                    cum = exclusive_cumsum_i32(
+                        jnp.stack([gl, gr]).astype(jnp.int32))
+                    cl, cr = cum[0], cum[1]
+                    active = sortable
+                    ps_s = jnp.where(active, ps, 0)
+                    cl_ps = jnp.take(cl, ps_s)
+                    cr_ps = jnp.take(cr, ps_s)
+                    # member bases shifted by +n so the 13/12-bit plane split
+                    # stays non-negative (each plane has one nonzero per row
+                    # -> f32-exact at any N <= 2^24)
+                    base_l = ps + n - cl_ps
+                    base_r = ps + lc_w + n - cr_ps
+                    dplanes = jnp.stack(
+                        [(base_l >> 12).astype(jnp.float32),
+                         (base_l & 0xFFF).astype(jnp.float32),
+                         (base_r >> 12).astype(jnp.float32),
+                         (base_r & 0xFFF).astype(jnp.float32)],
+                        axis=1)                                     # (W, 4)
 
-            key_p, bins_p, w_p, rid_p, lid_p = lax.cond(
-                sort_now, run_sort, lambda a: a,
-                (key_p, st.bins_p, st.w_p, st.rid_p, lid_p))
-            st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
-                             lid_p=lid_p, key_p=key_p)
-            sorted_now = sort_now
-        else:  # profiling skeleton: windows stay unsorted (garbage layout)
-            st = st._replace(lid_p=lid_p, key_p=key_p)
-            sorted_now = do_sort
-        st = st._replace(pending=(st.pending | do_sort) & ~sorted_now)
-        # ---- child windows: sortable members split [s,lc)/[s+lc,..);
-        # frozen members' children share the parent span
-        li = jnp.stack([ps, jnp.where(sortable, lc_w, cw)], 1)
-        ri2 = jnp.stack([jnp.where(sortable, ps + lc_w, ps),
-                         jnp.where(sortable, cw - lc_w, cw)], 1)
-        # children's materialized covering spans: the logical windows when
-        # this wave sorted (everything compacts), the MEMBER's span when
-        # the sort was deferred (rows haven't moved)
-        mphys = st.phys_i[wi]                                   # (W, 2)
-        phys_l = jnp.where(sorted_now, li, mphys)
-        phys_r = jnp.where(sorted_now, ri2, mphys)
-        # ---- smaller-child histograms (+ sibling subtraction) per member.
-        # Post-sort, every member's window is materialized — scan the
-        # logical child window (or the shared node span for frozen
-        # members); on a deferring wave scan the member's covering span
-        # with the lid mask doing the selection
-        left_small = lc_bag <= (c_bag - lc_bag)
-        sm_slot = jnp.where(left_small, lslot, rslot)
-        sm_start = jnp.where(sorted_now,
-                             jnp.where(sortable & ~left_small, ps + lc_w,
-                                       ps),
-                             mphys[:, 0])
-        sm_cnt = jnp.where(sorted_now,
-                           jnp.where(sortable,
-                                     jnp.where(left_small, lc_w,
-                                               cw - lc_w), cw),
-                           mphys[:, 1])
-        ph = st.hslot[wi]
-        rh = 1 + st.num_splits + pos
-        oobh = jnp.int32(self.H + 7)
-        lh_w = jnp.where(valid, ph, oobh)
-        rh_w = jnp.where(valid, rh, oobh)
+                    def dests(lid_old_c, go_c, sort_c, pos_c, cl_c, cr_c):
+                        mask_f = ((lid_old_c[:, None] == wi[None, :])
+                                  & valid[None, :]).astype(jnp.float32)
+                        ks = lax.dot_general(mask_f, dplanes,
+                                             (((1,), (0,)), ((), ())),
+                                             precision=_HIGH)       # (ch, 4)
+                        ki = jnp.rint(ks).astype(jnp.int32)
+                        bl = (ki[:, 0] << 12) + ki[:, 1] - n
+                        br = (ki[:, 2] << 12) + ki[:, 3] - n
+                        return jnp.where(
+                            sort_c, jnp.where(go_c, bl + cl_c, br + cr_c),
+                            pos_c)
+
+                    pos = jnp.arange(n, dtype=jnp.int32)
+                    if Cm == 1:
+                        dest = dests(st.lid_p, go_left, sort_r, pos, cl, cr)
+                    else:
+                        ch = n // Cm
+                        dest = lax.map(
+                            lambda a: dests(*a),
+                            (st.lid_p.reshape(Cm, ch),
+                             go_left.reshape(Cm, ch),
+                             sort_r.reshape(Cm, ch), pos.reshape(Cm, ch),
+                             cl.reshape(Cm, ch),
+                             cr.reshape(Cm, ch))).reshape(-1)
+                    return apply_partition(
+                        bins_p_i, w_p_i, rid_p_i, lid_p_i, dest,
+                        sort_r.astype(jnp.int32), ps, lc_w, cw, active,
+                        cl, cr, cl_ps, cr_ps,
+                        interpret=self._partition_interpret)
+
+                bins_p, w_p, rid_p, lid_p = lax.cond(
+                    sort_now, run_partition, lambda a: a,
+                    (st.bins_p, st.w_p, st.rid_p, lid_p))
+                st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
+                                 lid_p=lid_p)
+                sorted_now = sort_now
+            elif "nosort" not in self._ablate:
+                if self._defer_sorts:
+                    sort_now = st.pending
+                else:
+                    sort_now = do_sort
+
+                def run_sort(args):
+                    key_p, bins_p, w_p, rid_p, lid_p = args
+                    ops = ([key_p] + [bins_p[i] for i in range(fw)]
+                           + [w_p[0], w_p[1], w_p[2], rid_p, lid_p])
+                    sd = lax.sort(ops, num_keys=1, is_stable=True)
+                    return (sd[0], jnp.stack(sd[1:1 + fw]),
+                            jnp.stack(sd[1 + fw:4 + fw]), sd[4 + fw], sd[5 + fw])
+
+                key_p, bins_p, w_p, rid_p, lid_p = lax.cond(
+                    sort_now, run_sort, lambda a: a,
+                    (key_p, st.bins_p, st.w_p, st.rid_p, lid_p))
+                st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
+                                 lid_p=lid_p, key_p=key_p)
+                sorted_now = sort_now
+            else:  # profiling skeleton: windows stay unsorted (garbage layout)
+                st = st._replace(lid_p=lid_p, key_p=key_p)
+                sorted_now = do_sort
+            st = st._replace(pending=(st.pending | do_sort) & ~sorted_now)
+        with scope("select"):
+            # ---- child windows: sortable members split [s,lc)/[s+lc,..);
+            # frozen members' children share the parent span
+            li = jnp.stack([ps, jnp.where(sortable, lc_w, cw)], 1)
+            ri2 = jnp.stack([jnp.where(sortable, ps + lc_w, ps),
+                             jnp.where(sortable, cw - lc_w, cw)], 1)
+            # children's materialized covering spans: the logical windows when
+            # this wave sorted (everything compacts), the MEMBER's span when
+            # the sort was deferred (rows haven't moved)
+            mphys = st.phys_i[wi]                                   # (W, 2)
+            phys_l = jnp.where(sorted_now, li, mphys)
+            phys_r = jnp.where(sorted_now, ri2, mphys)
+            # ---- smaller-child histograms (+ sibling subtraction) per member.
+            # Post-sort, every member's window is materialized — scan the
+            # logical child window (or the shared node span for frozen
+            # members); on a deferring wave scan the member's covering span
+            # with the lid mask doing the selection
+            left_small = lc_bag <= (c_bag - lc_bag)
+            sm_slot = jnp.where(left_small, lslot, rslot)
+            sm_start = jnp.where(sorted_now,
+                                 jnp.where(sortable & ~left_small, ps + lc_w,
+                                           ps),
+                                 mphys[:, 0])
+            sm_cnt = jnp.where(sorted_now,
+                               jnp.where(sortable,
+                                         jnp.where(left_small, lc_w,
+                                                   cw - lc_w), cw),
+                               mphys[:, 1])
+            ph = st.hslot[wi]
+            rh = 1 + st.num_splits + pos
+            oobh = jnp.int32(self.H + 7)
+            lh_w = jnp.where(valid, ph, oobh)
+            rh_w = jnp.where(valid, rh, oobh)
 
         if not opening and getattr(self, "_use_fused", False):
             # fused chain: only the smaller-child histograms run here —
             # subtraction, select, FixHistogram and both child scans
             # collapse into one Pallas launch in _children_bookkeeping
-            h_small = self._member_small_hists(st, sm_slot, sm_start,
-                                               sm_cnt, valid)
-            st = self._children_bookkeeping(
-                st, wi, valid, lslot, rslot, lc_bag, c_bag, li, ri2, ph,
-                rh, None, feature_mask, phys_l, phys_r,
-                fused_parts=(h_small, ph, left_small, lh_w, rh_w))
+            with scope("hist"):
+                h_small = self._member_small_hists(st, sm_slot, sm_start,
+                                                   sm_cnt, valid)
+            with scope("select"):
+                st = self._children_bookkeeping(
+                    st, wi, valid, lslot, rslot, lc_bag, c_bag, li, ri2,
+                    ph, rh, None, feature_mask, phys_l, phys_r,
+                    fused_parts=(h_small, ph, left_small, lh_w, rh_w))
         else:
-            if opening:
-                # sm_start/sm_cnt reference LOGICAL windows (nothing has
-                # been compacted yet) — opening hists mask by lid over
-                # the full array
-                pool, hl, hr = self._opening_hists(
-                    st, sm_slot, valid, ph, lh_w, rh_w, left_small)
-            else:
-                pool, hl, hr = self._wave_member_hists(
-                    st, sm_slot, sm_start, sm_cnt, valid, ph, lh_w, rh_w,
-                    left_small)
-            st = st._replace(hist_pool=pool)
-            hists2 = jnp.stack([hl, hr], 1).reshape((2 * W,)
-                                                   + hl.shape[1:])
-            st = self._children_bookkeeping(
-                st, wi, valid, lslot, rslot, lc_bag, c_bag, li, ri2, ph,
-                rh, hists2, feature_mask, phys_l, phys_r)
+            with scope("hist"):
+                if opening:
+                    # sm_start/sm_cnt reference LOGICAL windows (nothing
+                    # has been compacted yet) — opening hists mask by lid
+                    # over the full array
+                    pool, hl, hr = self._opening_hists(
+                        st, sm_slot, valid, ph, lh_w, rh_w, left_small)
+                else:
+                    pool, hl, hr = self._wave_member_hists(
+                        st, sm_slot, sm_start, sm_cnt, valid, ph, lh_w,
+                        rh_w, left_small)
+                st = st._replace(hist_pool=pool)
+                hists2 = jnp.stack([hl, hr], 1).reshape((2 * W,)
+                                                       + hl.shape[1:])
+            with scope("select"):
+                st = self._children_bookkeeping(
+                    st, wi, valid, lslot, rslot, lc_bag, c_bag, li, ri2,
+                    ph, rh, hists2, feature_mask, phys_l, phys_r)
         if st.telem is not None:
             st = st._replace(telem=st.telem
                              .at[TEL_WAVES].add(1)
@@ -1127,13 +1140,15 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         position — the invariant the per-wave sorts maintain), after which
         the regular wave flow's physical-window machinery applies."""
         fw = self.fw
-        ops = ([st.key_p] + [st.bins_p[i] for i in range(fw)]
-               + [st.w_p[0], st.w_p[1], st.w_p[2], st.rid_p, st.lid_p])
-        sd = lax.sort(ops, num_keys=1, is_stable=True)
-        return st._replace(key_p=sd[0], bins_p=jnp.stack(sd[1:1 + fw]),
-                           w_p=jnp.stack(sd[1 + fw:4 + fw]),
-                           rid_p=sd[4 + fw], lid_p=sd[5 + fw],
-                           phys_i=st.node_i, pending=jnp.asarray(False))
+        with scope("partition"):
+            ops = ([st.key_p] + [st.bins_p[i] for i in range(fw)]
+                   + [st.w_p[0], st.w_p[1], st.w_p[2], st.rid_p, st.lid_p])
+            sd = lax.sort(ops, num_keys=1, is_stable=True)
+            return st._replace(
+                key_p=sd[0], bins_p=jnp.stack(sd[1:1 + fw]),
+                w_p=jnp.stack(sd[1 + fw:4 + fw]), rid_p=sd[4 + fw],
+                lid_p=sd[5 + fw], phys_i=st.node_i,
+                pending=jnp.asarray(False))
 
     def _segment_hists(self, st: WaveState, sm_slot, sm_start, sm_cnt,
                        valid, t_cap: Optional[int] = None):
@@ -1737,7 +1752,8 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 def do_stall1(s):
                     sort_c = (s.node_i[top, 1]
                               > jnp.int32(self._stall_cutoff))
-                    s2 = self._stall_split(s, top, feature_mask)
+                    with scope("stall"):
+                        s2 = self._stall_split(s, top, feature_mask)
                     if s2.telem is not None:
                         s2 = s2._replace(
                             telem=s2.telem.at[TEL_STALL_SORT_MODE].add(
@@ -1781,8 +1797,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 fits = self._replicated_spans(s.phys_i[tops_k, 1]) \
                     <= jnp.int32(self._vec_cap)
                 bv = bv & ((head & fits) | (jnp.arange(Kb) == 0))
-                s2 = self._stall_split_batch(s, tops_k, bv, feature_mask,
-                                             top_fits=fits[0])
+                with scope("stall"):
+                    s2 = self._stall_split_batch(s, tops_k, bv,
+                                                 feature_mask,
+                                                 top_fits=fits[0])
                 nsp = jnp.sum(bv, dtype=jnp.int32).astype(jnp.int32)
                 return s2, nsp, nsp - bv[0].astype(jnp.int32)
 
@@ -1833,30 +1851,35 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         self._stall_branches = [
             self._make_stall_branch(S, sort_mode=S > self._stall_cutoff)
             for S in self._win_sizes]
-        st = self._init_root_wave(bins_p, grad, hess, bag, feature_mask)
+        with scope("root"):
+            st = self._init_root_wave(bins_p, grad, hess, bag, feature_mask)
         # level-wise opening: the first L levels grow unsorted (level d has
         # at most 2^d members), then ONE materialization sort compacts
         # every window; a level with nothing to split is an exact no-op
-        for d in range(self.open_levels):
-            st = self._wave_body(st, feature_mask,
-                                 width=min(1 << d, self.W), opening=True)
-        if self.open_levels > 0:
-            st = lax.cond(st.pending, self._materialize_sort,
-                          lambda s: s, st)
+        with scope("opening"):
+            for d in range(self.open_levels):
+                st = self._wave_body(st, feature_mask,
+                                     width=min(1 << d, self.W),
+                                     opening=True)
+            if self.open_levels > 0:
+                st = lax.cond(st.pending, self._materialize_sort,
+                              lambda s: s, st)
 
         def gcond(s):
             return (s.num_splits < self.grow_budget) & \
                 (jnp.max(self._pool_gains(s)) > 0.0)
 
-        st = lax.while_loop(gcond, lambda s: self._wave_step(s, feature_mask),
-                            st)
-        if self._defer_sorts and self._stall_batch == 1:
-            # the growth loop may exit on a deferring wave — the K=1
-            # replay's stall splits slice PHYSICAL windows, so materialize
-            # first.  Batched (K>1) corrections mask through phys_i
-            # covering spans instead, so they skip this sort
-            st = lax.cond(st.pending, self._materialize_sort,
-                          lambda s: s, st)
+        with scope("grow"):
+            st = lax.while_loop(
+                gcond, lambda s: self._wave_step(s, feature_mask), st)
+            if self._defer_sorts and self._stall_batch == 1:
+                # the growth loop may exit on a deferring wave — the K=1
+                # replay's stall splits slice PHYSICAL windows, so
+                # materialize first.  Batched (K>1) corrections mask
+                # through phys_i covering spans instead, so they skip
+                # this sort
+                st = lax.cond(st.pending, self._materialize_sort,
+                              lambda s: s, st)
         return self._emit_tree_wave(st, feature_mask)
 
     def _emit_tree_wave(self, st: WaveState, feature_mask):
@@ -1866,117 +1889,119 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         if st.telem is not None:
             st = st._replace(
                 telem=st.telem.at[TEL_GROW_SPLITS].set(st.num_splits))
-        st, avail, refidx, pops, pop_nodes, pop_ref, _stalls = self._replay(
-            st, feature_mask)
+        with scope("replay"):
+            st, avail, refidx, pops, pop_nodes, pop_ref, _stalls = \
+                self._replay(st, feature_mask)
         if st.telem is not None:
             st = st._replace(
                 telem=st.telem.at[TEL_TOTAL_SPLITS].set(st.num_splits))
 
-        # ---- emit host records in pop order
-        budget = self.budget
-        vp = jnp.arange(budget) < pops
-        nd = jnp.where(vp, pop_nodes, 0)
-        cf = st.cand_f[nd].astype(jnp.float32)
-        ci = st.cand_i[nd]
-        nf = st.node_f[nd].astype(jnp.float32)
-        rec_f = jnp.stack([
-            vp.astype(jnp.float32),
-            pop_ref.astype(jnp.float32),
-            ci[:, CI_FEAT].astype(jnp.float32),
-            ci[:, CI_THR].astype(jnp.float32),
-            (ci[:, CI_FLAGS] & 1).astype(jnp.float32),
-            cf[:, CF_GAIN],
-            cf[:, CF_LOUT], cf[:, CF_ROUT],
-            cf[:, CF_LCNT], cf[:, CF_RCNT],
-            nf[:, LF_OUT], nf[:, LF_CNT],
-            cf[:, CF_LSH], cf[:, CF_RSH],
-            cf[:, CF_LSG], cf[:, CF_RSG],
-            ((ci[:, CI_FLAGS] & 2) >> 1).astype(jnp.float32)], axis=1)
-        assert rec_f.shape[1] == NUM_REC_FIELDS
-        rec_i = st.cnt_i[nd]
-        rec_cat = st.cand_b[nd]
+        with scope("emit"):
+            # ---- emit host records in pop order
+            budget = self.budget
+            vp = jnp.arange(budget) < pops
+            nd = jnp.where(vp, pop_nodes, 0)
+            cf = st.cand_f[nd].astype(jnp.float32)
+            ci = st.cand_i[nd]
+            nf = st.node_f[nd].astype(jnp.float32)
+            rec_f = jnp.stack([
+                vp.astype(jnp.float32),
+                pop_ref.astype(jnp.float32),
+                ci[:, CI_FEAT].astype(jnp.float32),
+                ci[:, CI_THR].astype(jnp.float32),
+                (ci[:, CI_FLAGS] & 1).astype(jnp.float32),
+                cf[:, CF_GAIN],
+                cf[:, CF_LOUT], cf[:, CF_ROUT],
+                cf[:, CF_LCNT], cf[:, CF_RCNT],
+                nf[:, LF_OUT], nf[:, LF_CNT],
+                cf[:, CF_LSH], cf[:, CF_RSH],
+                cf[:, CF_LSG], cf[:, CF_RSG],
+                ((ci[:, CI_FLAGS] & 2) >> 1).astype(jnp.float32)], axis=1)
+            assert rec_f.shape[1] == NUM_REC_FIELDS
+            rec_i = st.cnt_i[nd]
+            rec_cat = st.cand_b[nd]
 
-        # ---- map speculative leaves to their final ancestors
-        final = avail  # revealed and never popped
-        iota = jnp.arange(self.M, dtype=jnp.int32)
-        T = jnp.where(final, iota, st.parent)
-        # pointer-jump doubling: k iterations cover chains of 2^k; chain
-        # depth is bounded by the node count M
-        for _ in range(max(1, (self.M - 1).bit_length())):
-            T = T[T]
-        slot2ref = jnp.where(final[T], refidx[T], 0)
-        # chunked lookup: the (rows, M_pad) one-hot transient is bounded to
-        # ~2^17 rows per step regardless of N (at 10.5M rows an unchunked
-        # one-hot would be ~24 GB)
-        Cl = 1
-        while self._rows_len() // Cl > (1 << 17) and Cl < 1024 \
-                and self._rows_len() % (Cl * 2) == 0:
-            Cl *= 2
-        if Cl == 1:
-            leaf_ref = lookup_int(slot2ref, st.lid_p)
-        else:
-            leaf_ref = lax.map(
-                lambda lid_c: lookup_int(slot2ref, lid_c),
-                st.lid_p.reshape(Cl, self._rows_len() // Cl)).reshape(-1)
-        # descatter to original row order by sorting on rid (a 2-lane sort
-        # is ~3x cheaper than the equivalent scatter on TPU)
-        leaf_id = lax.sort([st.rid_p, leaf_ref], num_keys=1)[1]
-        leaf_out = jnp.zeros(self.num_leaves, jnp.float32).at[
-            jnp.where(final, refidx, self.num_leaves + 7)].set(
-                st.node_f[:, LF_OUT].astype(jnp.float32))
-        if self._quant and self._q_raw is not None:
-            # leaf-output RENEWAL (the quantized-training recipe's
-            # accuracy anchor): per-leaf sums re-accumulated from the
-            # RETAINED f32 gradients over the final leaf assignment, so
-            # leaf values carry no discretization error — only the split
-            # STRUCTURE sees quantized sums.  Patches both the score
-            # update (leaf_out) and the host records' child outputs.
-            from .ops.split import calculate_leaf_output
-            gb, hb = self._q_raw
-            self._q_raw = None
-            L = self.num_leaves
-            kw = self._split_kwargs
-            # FIXED-POINT accumulation: the renewed outputs feed the score,
-            # and the next round's stochastic rounding keys on the score's
-            # BIT PATTERN — a 1-ulp f32 summation-order difference between
-            # serial and sharded would re-roll the rounding and fork the
-            # tree stream.  Rounding each row to a pow2 grid and summing
-            # int32 makes the reduction exact at any shard order; the grid
-            # leaves k = 30 - ceil_log2(N) bits per row (>= 9 bits under
-            # the F32_EXACT_ROWS gate), noise far below the quantization
-            # the splits already tolerate.
-            sg, sh = self._q_scales
-            kb = max(30 - int(self.n_pad - 1).bit_length(), 1)
-            qg = sg * jnp.float32(2.0 ** (3 - kb))    # sg·GMAX <= sg·2^3
-            qh = sh * jnp.float32(2.0 ** (4 - kb))    # sh·HMAX <= sh·2^4
-            rg = jnp.rint(gb / qg).astype(jnp.int32)
-            rh = jnp.rint(hb / qh).astype(jnp.int32)
-            lgh = jnp.zeros((2, L), jnp.int32) \
-                .at[0, leaf_id].add(rg).at[1, leaf_id].add(rh)
-            lgh = self._global_scalar(lgh)
-            lg = lgh[0].astype(jnp.float32) * qg
-            lh = lgh[1].astype(jnp.float32) * qh
-            has_h = lh > 0.0
-            refined = jnp.where(
-                has_h,
-                calculate_leaf_output(
-                    lg, lh, kw["lambda_l1"], kw["lambda_l2"],
-                    kw["max_delta_step"]).astype(jnp.float32),
-                0.0)
-            leaf_out = jnp.where(has_h, refined, leaf_out)
-            # pop i's left child keeps ref pop_ref[i]; its right child is
-            # ref 1 + i (the replay's leaf numbering)
-            lref = jnp.clip(pop_ref, 0, L - 1)
-            rref = jnp.minimum(jnp.arange(budget, dtype=jnp.int32) + 1,
-                               L - 1)
-            from .learner import REC_LEFT_OUT, REC_RIGHT_OUT
-            rec_f = rec_f \
-                .at[:, REC_LEFT_OUT].set(
-                    jnp.where(vp & has_h[lref], refined[lref],
-                              rec_f[:, REC_LEFT_OUT])) \
-                .at[:, REC_RIGHT_OUT].set(
-                    jnp.where(vp & has_h[rref], refined[rref],
-                              rec_f[:, REC_RIGHT_OUT]))
+            # ---- map speculative leaves to their final ancestors
+            final = avail  # revealed and never popped
+            iota = jnp.arange(self.M, dtype=jnp.int32)
+            T = jnp.where(final, iota, st.parent)
+            # pointer-jump doubling: k iterations cover chains of 2^k; chain
+            # depth is bounded by the node count M
+            for _ in range(max(1, (self.M - 1).bit_length())):
+                T = T[T]
+            slot2ref = jnp.where(final[T], refidx[T], 0)
+            # chunked lookup: the (rows, M_pad) one-hot transient is bounded to
+            # ~2^17 rows per step regardless of N (at 10.5M rows an unchunked
+            # one-hot would be ~24 GB)
+            Cl = 1
+            while self._rows_len() // Cl > (1 << 17) and Cl < 1024 \
+                    and self._rows_len() % (Cl * 2) == 0:
+                Cl *= 2
+            if Cl == 1:
+                leaf_ref = lookup_int(slot2ref, st.lid_p)
+            else:
+                leaf_ref = lax.map(
+                    lambda lid_c: lookup_int(slot2ref, lid_c),
+                    st.lid_p.reshape(Cl, self._rows_len() // Cl)).reshape(-1)
+            # descatter to original row order by sorting on rid (a 2-lane sort
+            # is ~3x cheaper than the equivalent scatter on TPU)
+            leaf_id = lax.sort([st.rid_p, leaf_ref], num_keys=1)[1]
+            leaf_out = jnp.zeros(self.num_leaves, jnp.float32).at[
+                jnp.where(final, refidx, self.num_leaves + 7)].set(
+                    st.node_f[:, LF_OUT].astype(jnp.float32))
+            if self._quant and self._q_raw is not None:
+                # leaf-output RENEWAL (the quantized-training recipe's
+                # accuracy anchor): per-leaf sums re-accumulated from the
+                # RETAINED f32 gradients over the final leaf assignment, so
+                # leaf values carry no discretization error — only the split
+                # STRUCTURE sees quantized sums.  Patches both the score
+                # update (leaf_out) and the host records' child outputs.
+                from .ops.split import calculate_leaf_output
+                gb, hb = self._q_raw
+                self._q_raw = None
+                L = self.num_leaves
+                kw = self._split_kwargs
+                # FIXED-POINT accumulation: the renewed outputs feed the score,
+                # and the next round's stochastic rounding keys on the score's
+                # BIT PATTERN — a 1-ulp f32 summation-order difference between
+                # serial and sharded would re-roll the rounding and fork the
+                # tree stream.  Rounding each row to a pow2 grid and summing
+                # int32 makes the reduction exact at any shard order; the grid
+                # leaves k = 30 - ceil_log2(N) bits per row (>= 9 bits under
+                # the F32_EXACT_ROWS gate), noise far below the quantization
+                # the splits already tolerate.
+                sg, sh = self._q_scales
+                kb = max(30 - int(self.n_pad - 1).bit_length(), 1)
+                qg = sg * jnp.float32(2.0 ** (3 - kb))    # sg·GMAX <= sg·2^3
+                qh = sh * jnp.float32(2.0 ** (4 - kb))    # sh·HMAX <= sh·2^4
+                rg = jnp.rint(gb / qg).astype(jnp.int32)
+                rh = jnp.rint(hb / qh).astype(jnp.int32)
+                lgh = jnp.zeros((2, L), jnp.int32) \
+                    .at[0, leaf_id].add(rg).at[1, leaf_id].add(rh)
+                lgh = self._global_scalar(lgh)
+                lg = lgh[0].astype(jnp.float32) * qg
+                lh = lgh[1].astype(jnp.float32) * qh
+                has_h = lh > 0.0
+                refined = jnp.where(
+                    has_h,
+                    calculate_leaf_output(
+                        lg, lh, kw["lambda_l1"], kw["lambda_l2"],
+                        kw["max_delta_step"]).astype(jnp.float32),
+                    0.0)
+                leaf_out = jnp.where(has_h, refined, leaf_out)
+                # pop i's left child keeps ref pop_ref[i]; its right child is
+                # ref 1 + i (the replay's leaf numbering)
+                lref = jnp.clip(pop_ref, 0, L - 1)
+                rref = jnp.minimum(jnp.arange(budget, dtype=jnp.int32) + 1,
+                                   L - 1)
+                from .learner import REC_LEFT_OUT, REC_RIGHT_OUT
+                rec_f = rec_f \
+                    .at[:, REC_LEFT_OUT].set(
+                        jnp.where(vp & has_h[lref], refined[lref],
+                                  rec_f[:, REC_LEFT_OUT])) \
+                    .at[:, REC_RIGHT_OUT].set(
+                        jnp.where(vp & has_h[rref], refined[rref],
+                                  rec_f[:, REC_RIGHT_OUT]))
         if st.telem is not None:
             return rec_f, rec_i, rec_cat, leaf_id, leaf_out, st.telem
         return rec_f, rec_i, rec_cat, leaf_id, leaf_out

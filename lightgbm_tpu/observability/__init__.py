@@ -1,9 +1,18 @@
 """Structured training telemetry.
 
-The early perf rounds were driven by one-off scripts
-under ``profiling/`` and hand-done ablation arithmetic; the library itself
-measured nothing.  This package is the first-class observability layer the
-boosting loop and tree learners report through:
+The early perf rounds were driven by one-off profiling scripts (deleted)
+and hand-done ablation arithmetic; the library itself measured nothing.
+This package is the first-class observability layer the boosting loop and
+tree learners report through:
+
+  * ``phases`` (`phases.py`) — the names the program gives its own work:
+    the ``jax.named_scope`` phases of the fused step (gradients, root,
+    opening, grow, replay, emit, score_update, with select / partition /
+    hist / scan / stall inside), the pinned Pallas kernel names, and the
+    ``lgbt.*`` host spans.  ``trace.span`` is the one span call: an event
+    of a ``jax.profiler`` session (the device's clock) always, a
+    ``TraceRecorder`` span when one is attached, a phase-table row when
+    ``telemetry`` is on.
 
   * ``Telemetry`` — host wall timers per phase, per-iteration timing, and
     the host-side decode of the per-tree device counter vector the wave
@@ -43,10 +52,7 @@ Schema v7 adds the distributed-training layer (ROADMAP items 1 & 2):
   * ``attribution`` (`attribution.py`) — the sampled-sync timer
     (``telemetry_sync_every``: every Nth iteration brackets each leg of
     the jitted step with a forced sync), the exchange-window probe the
-    sharded learners expose, the per-leg attribution table, and the
-    best-effort ``jax.profiler`` Chrome-trace parse.  One timing
-    implementation (``timeit``/``force_sync``) shared with the
-    ``profiling/`` scripts.
+    sharded learners expose, and the per-leg attribution table.
   * ``podtrace`` (`podtrace.py`) — the pod flight recorder: per-rank
     trace export with a KV-store clock-offset handshake, and the merge
     of N per-rank traces into ONE pod-wide Chrome trace.
@@ -73,12 +79,13 @@ prerequisite):
 
 Device-side *time* attribution inside the fused tree program is out of
 scope for counters — that is what the opt-in ``profile_trace_dir``
-(`jax.profiler`) trace is for; see README "Telemetry & profiling" and
-"Tracing & service metrics".
+(`jax.profiler`) trace is for, in which every device operation carries the
+program's phase scopes in its ``op_name``; see README "Telemetry &
+profiling" and "Tracing & service metrics".
 """
 
 from .attribution import (SampledSync, attribution_table, force_sync,
-                          parse_profiler_trace, timeit)
+                          timeit)
 from .collectives import CollectiveLedger
 from .drift import DriftMonitor, ks_2samp, ks_from_counts, psi_from_counts
 from .metrics_export import (BENCH_SERVING_SCHEMA, LatencyHistogram,
@@ -95,7 +102,7 @@ __all__ = ["Telemetry", "CollectiveLedger", "TEL_NAMES",
            "TraceRecorder", "new_trace_id", "LatencyHistogram",
            "prometheus_text", "BENCH_SERVING_SCHEMA",
            "SampledSync", "attribution_table", "force_sync",
-           "parse_profiler_trace", "timeit", "training_prometheus",
+           "timeit", "training_prometheus",
            "estimate_clock_offset", "export_rank_trace",
            "merge_pod_trace", "provenance_section",
            "get_global_tracer", "set_global_tracer",

@@ -2,8 +2,9 @@
 
 The phase table (`telemetry.py`) times host-visible dispatch windows, and
 the CollectiveLedger records trace-time collective SITES — neither says
-where device time actually goes.  This module adds the three runtime
-attribution mechanisms the BENCH rounds need:
+where device time actually goes.  This module adds two runtime attribution
+mechanisms (device time by phase is read off a ``jax.profiler`` trace
+through the program's own scopes and spans, ``phases.py``):
 
   * **Sampled-sync timer** (``telemetry_sync_every=N``): every Nth
     iteration the boosting loop drains the dispatch queue, then brackets
@@ -22,12 +23,6 @@ attribution mechanisms the BENCH rounds need:
     collective leg the fused program hides.  The probe jits are outside
     the analysis gate's traced-program set and the ledger is muted while
     they trace, so budgets.json and ``collectives.sites`` are unchanged.
-  * **jax.profiler capture-and-parse** (``parse_profiler_trace``):
-    best-effort scan of a ``profile_trace_dir`` for Chrome-format
-    ``*.trace.json[.gz]`` files, mapping device op names back to the
-    named legs the ledger knows (hist / exchange / scan / partition /
-    flush).  Returns None when only ``*.xplane.pb`` exists (no protobuf
-    dependency is added for it).
 
 Everything here is host-only and lives in ``observability/`` — never
 imported into a traced function — so the LGB005 wall-clock discipline
@@ -38,19 +33,8 @@ compiled program (allowlisted with that verdict in
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
-import os
-import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
-
-
-# legs the attribution table and profiler parse speak in (the ledger's
-# phase vocabulary: histogram build, cross-device exchange, split scan,
-# row partition, host record flush)
-LEGS = ("hist", "exchange", "scan", "partition", "flush")
 
 # sync.* phases that are NOT iteration legs: the iteration wall itself,
 # the pre-iteration queue drain, and the standalone exchange probe
@@ -60,13 +44,14 @@ _NON_LEG_SYNC = ("sync.iteration", "sync.drain", "sync.exchange_probe")
 # the full iteration wall (they run on every iteration; their global
 # means estimate their share of a sampled one).  ``tree_train`` is the
 # non-pipelined sync path's fully-host-synchronous tree build.
-_HOST_LEGS = ("bagging", "tree_dispatch", "score_update",
-              "pipeline_flush", "tree_assemble", "tree_train")
+_HOST_LEGS = ("bagging", "feature_sample", "dispatch", "tree_dispatch",
+              "score_update", "flush", "tree_train")
 
 # host phases whose window is a strict prefix of a sync leg's
 # [dispatch, completion] window — when that sync leg was recorded,
 # counting the host phase too would double-count the dispatch time
-_HOST_SHADOWED = {"tree_dispatch": "sync.tree_build",
+_HOST_SHADOWED = {"dispatch": "sync.tree_build",
+                  "tree_dispatch": "sync.tree_build",
                   "score_update": "sync.score_update",
                   "tree_train": "sync.tree_train"}
 
@@ -81,8 +66,7 @@ def force_sync(*arrays: Any) -> None:
 def timeit(fn: Callable, *args: Any, iters: int = 5, warmup: int = 2,
            sync: Optional[Callable[[Any], None]] = None) -> float:
     """Best-of-``iters`` seconds for one synced call of ``fn(*args)`` —
-    THE timing implementation (profiling/profile_phases.py,
-    profile_wave_phases.py and the exchange probe all route here).
+    THE timing implementation (the exchange probe routes here).
 
     ``sync`` overrides the default ``force_sync`` on the result (callers
     whose output pytree needs a specific leaf fetched pass their own).
@@ -207,94 +191,3 @@ def attribution_table(phases_ms: Dict[str, Dict[str, float]]
         "exchange_probe_ms": (probe["total_ms"] / probe["count"]
                               if probe and probe.get("count") else None),
     }
-
-
-# -- jax.profiler capture & parse --------------------------------------------
-
-# device-op name -> leg mapping, first match wins.  The names are XLA HLO
-# op names (TPU) / thunk names (CPU) — substring regexes keep this robust
-# across backend renames; unmatched ops land in "other".
-_LEG_PATTERNS = [
-    ("exchange", re.compile(
-        r"all-reduce|reduce-scatter|all-gather|collective|all-to-all"
-        r"|psum|ppermute", re.I)),
-    ("hist", re.compile(r"hist|one.?hot|scatter|segment|dot|conv", re.I)),
-    ("partition", re.compile(r"sort|partition|gather|dynamic-slice", re.I)),
-    ("scan", re.compile(r"while|scan|reduce|select|arg.?max|cumsum", re.I)),
-    ("flush", re.compile(r"copy|transfer|infeed|outfeed|donat", re.I)),
-]
-
-
-def _profiler_trace_files(trace_dir: str) -> List[str]:
-    pats = [os.path.join(trace_dir, "**", "*.trace.json.gz"),
-            os.path.join(trace_dir, "**", "*.trace.json")]
-    out: List[str] = []
-    for p in pats:
-        out.extend(glob.glob(p, recursive=True))
-    return sorted(out)
-
-
-def parse_profiler_trace(trace_dir: str, top_k: int = 20
-                         ) -> Optional[Dict[str, Any]]:
-    """Map a ``jax.profiler`` Chrome trace's device events to the named
-    legs.  Best-effort: returns None when the directory holds no
-    Chrome-format trace (some backends emit only ``*.xplane.pb``, whose
-    protobuf schema this repo deliberately does not depend on)."""
-    files = _profiler_trace_files(trace_dir)
-    if not files:
-        return None
-    path = files[-1]             # newest capture wins (sorted run dirs)
-    try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rt") as fh:
-                data = json.load(fh)
-        else:
-            with open(path) as fh:
-                data = json.load(fh)
-    except Exception:
-        return None
-    events = data.get("traceEvents", [])
-    legs = {leg: 0.0 for leg, _ in _LEG_PATTERNS}
-    legs["other"] = 0.0
-    per_op: Dict[str, float] = {}
-    total_us = 0.0
-    n = 0
-    for ev in events:
-        if ev.get("ph") != "X" or "dur" not in ev:
-            continue
-        name = str(ev.get("name", ""))
-        dur = float(ev["dur"])
-        total_us += dur
-        n += 1
-        per_op[name] = per_op.get(name, 0.0) + dur
-        for leg, pat in _LEG_PATTERNS:
-            if pat.search(name):
-                legs[leg] += dur
-                break
-        else:
-            legs["other"] += dur
-    if n == 0:
-        return None
-    top = dict(sorted(per_op.items(), key=lambda kv: -kv[1])[:top_k])
-    return {"source": path, "events": n,
-            "total_ms": total_us / 1e3,
-            "legs_ms": {k: v / 1e3 for k, v in legs.items()},
-            "top_ops_ms": {k: v / 1e3 for k, v in top.items()}}
-
-
-def attribute_profile(trace_dir: str, ledger=None
-                      ) -> Optional[Dict[str, Any]]:
-    """``parse_profiler_trace`` plus a cross-check of its exchange leg
-    against the ledger's static collective sites: every site op name the
-    profile's collective events matched is listed, so a site with zero
-    runtime evidence (dead code, wrong cadence estimate) is visible."""
-    prof = parse_profiler_trace(trace_dir)
-    if prof is None:
-        return None
-    sites = list(ledger.sites()) if ledger is not None else []
-    if sites:
-        pat = _LEG_PATTERNS[0][1]
-        matched_ops = [op for op in prof["top_ops_ms"] if pat.search(op)]
-        prof["ledger_sites"] = [s["op"] for s in sites]
-        prof["collective_ops_seen"] = matched_ops
-    return prof

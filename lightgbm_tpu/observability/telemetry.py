@@ -13,12 +13,12 @@ the boosting loop materializes tree records.
 
 from __future__ import annotations
 
-import contextlib
-import time
 import tracemalloc
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+
+from .trace import span
 
 # -- device counter vector layout (accumulated by the wave learner) ---------
 # int32 slots; the vector is carried through the tree program only when
@@ -173,12 +173,12 @@ class Telemetry:
 
     # -- phases --------------------------------------------------------------
 
-    def phase(self, name: str):
-        """Context manager timing one phase occurrence (no-op when
-        disabled)."""
-        if not self.enabled:
-            return contextlib.nullcontext()
-        return _PhaseCtx(self, name)
+    def phase(self, name: str, **args: Any):
+        """Context manager around one phase occurrence: ``trace.span``
+        with this accumulator attached (a profiler event always, a
+        recorder span when ``self.tracer`` is set, a row of the phase
+        table when ``self.enabled``)."""
+        return span(name, self, **args)
 
     def add_phase_time(self, name: str, seconds: float,
                        t0: Optional[float] = None) -> None:
@@ -385,22 +385,3 @@ class Telemetry:
                 # histograms in ONE collective; each extra member is one
                 # collective the round-5 per-member loop would have issued
                 "saved_by_stall_batching": dev["stall_extras"]}
-
-
-class _PhaseCtx:
-    __slots__ = ("tel", "name", "t0")
-
-    def __init__(self, tel: Telemetry, name: str):
-        self.tel = tel
-        self.name = name
-
-    def __enter__(self):
-        self.tel._heap_enter()
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.tel.add_phase_time(self.name, time.perf_counter() - self.t0,
-                                t0=self.t0)
-        self.tel._heap_exit(self.name)
-        return False

@@ -9,23 +9,25 @@ or ``chrome://tracing`` and every span nests under its thread track.
 
 Design constraints (the same ones the telemetry layer lives under):
 
-  * **Host-only.**  Spans time host-visible phases (the existing
-    ``Telemetry.phase`` sites: gradients / tree_dispatch / score_update /
-    pipeline_flush on the training side, queue / pad / bin / traverse /
+  * **Host-only.**  Spans time host-visible phases (``span()`` below: the
+    ``Telemetry.phase`` sites — iteration / dispatch / flush / d2h_wait /
+    assemble_tree on the training side, queue / pad / bin / traverse /
     unpad on the serving side).  Nothing here is ever traced into an XLA
-    program, and recording a span never forces a device sync — device
-    work is attributed through the per-tree counter lane and the opt-in
-    ``profile_trace_dir`` profiler trace, exactly as before.
+    program, and recording a span never forces a device sync.  Device
+    work is named from inside the program (``phases.py``:
+    ``jax.named_scope`` phases, pinned kernel names) and timed by a
+    ``jax.profiler`` session (``profile_trace_dir``), in whose
+    ``.xplane.pb`` the same spans appear as ``lgbt.<name>`` events.
   * **Monotonic clocks only** (``time.perf_counter``); wall-clock reads
     would both misbehave under NTP steps and violate the repo's LGB005
     lint discipline.
   * **Bounded.**  Completed spans land in a ``deque(maxlen=capacity)``;
     a long-lived server overwrites its oldest spans instead of growing
     without bound (``dropped_spans`` in the export counts the loss).
-  * **Zero overhead when off.**  A disabled recorder's ``span()`` returns
-    a shared ``nullcontext`` and every record call returns immediately;
-    attaching no recorder at all (``Telemetry.tracer is None``) costs one
-    attribute read per phase exit.
+  * **Kept when someone listens.**  A span is recorded when a recorder
+    is attached (or a profiler session runs), whatever ``telemetry`` says;
+    with neither it costs one ``TraceAnnotation`` (under a microsecond).
+    A disabled recorder's ``span()`` returns a shared ``nullcontext``.
 
 Causal linkage: serving requests carry a ``trace_id`` (client-supplied or
 server-generated) end-to-end — the per-request span, the micro-batch span
@@ -46,6 +48,10 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Union
+
+from jax.profiler import TraceAnnotation
+
+from .phases import SPAN_PREFIX
 
 #: shared no-op context for disabled recorders (allocation-free hot path)
 _NULL_CTX = contextlib.nullcontext()
@@ -238,6 +244,57 @@ class TraceRecorder:
             json.dump(self.export(), fh)
             fh.write("\n")
         os.replace(tmp, path)
+
+
+def span(name: str, telemetry=None, **args: Any):
+    """The program's ONE host-span call (every ``Telemetry.phase`` site goes
+    through it).  The span always enters
+    ``jax.profiler.TraceAnnotation("lgbt." + name, **args)``: with no
+    profiler session that is a sub-microsecond no-op, and inside one it is
+    an event in the same ``.xplane.pb`` as the device operations — the
+    shared clock that lets a device idle gap be put down to a host span —
+    with ``args`` as the event's stats.  Besides, the span is recorded in
+    the attached ``TraceRecorder`` (``telemetry.tracer``, else the
+    process-global one) when there is one, and feeds the phase table when
+    ``telemetry.enabled``.  Whether a span is kept is decided by who
+    listens (a profiler session, a recorder), never by ``telemetry``."""
+    return _ProgramSpan(name, telemetry, args)
+
+
+class _ProgramSpan:
+    __slots__ = ("name", "tel", "args", "ann", "rec", "t0")
+
+    def __init__(self, name, tel, args):
+        self.name = name
+        self.tel = tel
+        self.args = args
+
+    def __enter__(self):
+        self.ann = TraceAnnotation(SPAN_PREFIX + self.name, **self.args)
+        self.ann.__enter__()
+        tel = self.tel
+        timed = tel is not None and tel.enabled
+        rec = tel.tracer if tel is not None else _global_tracer
+        self.rec = rec if rec is not None and rec.enabled else None
+        if timed:
+            tel._heap_enter()
+        self.t0 = time.perf_counter() \
+            if timed or self.rec is not None else None
+        return self
+
+    def __exit__(self, *exc):
+        t0 = self.t0
+        if t0 is not None:
+            dur = time.perf_counter() - t0
+            if self.rec is not None:
+                self.rec.add_complete(self.name, t0, dur, cat="phase",
+                                      args=self.args or None)
+            tel = self.tel
+            if tel is not None and tel.enabled:
+                tel.add_phase_time(self.name, dur)
+                tel._heap_exit(self.name)
+        self.ann.__exit__(*exc)
+        return False
 
 
 class _BindCtx:

@@ -114,6 +114,7 @@ def build_histogram_pallas(bins: jax.Array, w: jax.Array, *, num_bins: int,
         out_shape=jax.ShapeDtypeStruct((f, 3, b_pad), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="build_histogram_pallas",
     )(bins, w)
     return out[:, :, :num_bins].transpose(0, 2, 1)
 
@@ -256,7 +257,7 @@ def _hist_kernel_packed(bins_ref, w_ref, out_ref, *, num_bins_padded: int,
     # output axis and the bf16 terms stack along the channel axis, so each
     # word costs a single (3*nterms, Rb) x (Rb, 4*B) MXU contraction
     # instead of 4*nterms skinny ones — measured 6x on v5e
-    # (profiling/profile_hist_variants.py)
+    # (round-5 chip sweep of the kernel variants)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -358,6 +359,7 @@ def build_histogram_packed(bins_words: jax.Array, w: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="build_histogram_packed",
     )(bins_words, w)
     # (fw, 3, 4, B) -> (fw*4, B, 3)
     out = out.reshape(fw, 3, 4, b_pad).transpose(0, 2, 3, 1) \
@@ -498,6 +500,7 @@ def build_histogram_segments(bins_words: jax.Array, w: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="build_histogram_segments",
     )(chunk_slot, chunk_block, chunk_leaf, bins_words, w, lid)
     # (S, fw, 3, 4, B) -> (S, fw*4, B, 3)
     out = out[:n_slots].reshape(n_slots, fw, 3, 4, b_pad) \
@@ -610,6 +613,7 @@ def build_histogram_multislot(bins_words: jax.Array, w: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="build_histogram_multislot",
     )(bins_words, w, slot)
     # (fw, K, 3, 4, B) -> (K, fw*4, B, 3)
     out = out.reshape(fw, n_slots, 3, 4, b_pad) \

@@ -2,7 +2,7 @@
 
 On TPU an XLA gather of 1M rows from a small table costs ~5-8 ms (the
 gather unit serializes element loads) while the equivalent one-hot matmul
-runs in ~0.5 ms (`profiling/profile_gather_alts.py`).  Every per-row
+runs in ~0.5 ms (round-5 chip reading).  Every per-row
 ``table[leaf_id]``-style lookup in the training path routes through here.
 """
 

@@ -83,7 +83,7 @@ def exclusive_cumsum_i32(flags: jax.Array, chunk: int = 512) -> jax.Array:
     """(L, N) {0,1} int flags -> (L, N) int32 exclusive prefix sums.
 
     XLA lowers ``jnp.cumsum`` over a 1M-row axis to an O(N)-depth scan
-    (~1.8 ms/M elements on v5e — profiling/profile_primitives.py); the
+    (~1.8 ms/M elements on v5e, round 5); the
     bin-scan trick from ``ops/split.py`` applies here too: cumsum within
     ``chunk``-sized pieces via one triangular-matrix MXU contraction plus
     a short carry cumsum over the per-chunk totals.  Exact at any N: the
@@ -343,6 +343,7 @@ def _apply_partition_call(ot, it, kind, bins_p, w_bits, rid_p, lid_p, dest,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="apply_partition_permute",
     )(ot, it, kind, bins_p, w_bits,
       *(v[None, :] for v in (rid_p, lid_p, dest, mvd)))
     return out

@@ -231,6 +231,7 @@ def find_best_splits_batched(hist, sum_gradients, sum_hessians, num_data,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="find_best_splits_batched",
     )(hist_t, totals, _feature_meta(num_bin, missing_type, default_bin))
     return _candidates(out, sum_gradients, sum_hessians, num_data,
                        feature_mask, lambda_l1=lambda_l1,
@@ -348,6 +349,7 @@ def fused_child_scans(h_small, h_par, left_small, sum_g2, sum_h2, num2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_child_scans",
     )(hs_t, hp_t, left_small.astype(jnp.int32), totals,
       _feature_meta(num_bin, missing_type, default_bin))
     cands = _candidates(out.reshape(2 * k, f, N_OUT), sum_g2, sum_h2, num2,

@@ -47,7 +47,7 @@ K_MIN_SCORE = -np.inf
 def _scan_by_dot(dt, b: int) -> bool:
     """On TPU, bin-axis prefix/suffix sums run as triangular-matrix MXU
     contractions: XLA's cumsum lowers to an O(B)-depth scan that costs
-    ~1.8 ms per million elements on v5e (profiling/profile_primitives.py)
+    ~1.8 ms per million elements on v5e (round-5 chip reading)
     while the equivalent (.., B)x(B, B) dot is ~free.  The summation
     ORDER differs from the reference's sequential accumulation, so
     near-tie thresholds can flip vs the CPU path — the same accepted

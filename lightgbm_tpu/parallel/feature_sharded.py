@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_wave import WaveTPUTreeLearner
+from ..observability.phases import scope
 from .compact_sharded import ShardedCompactLearner
 
 
@@ -209,19 +210,22 @@ class FeatureShardedWaveLearner(FeatureShardedCompactLearner,
         self._stall_branches = [
             self._make_stall_branch(S, sort_mode=S > self._stall_cutoff)
             for S in self._win_sizes]
-        st = self._init_root_wave(bins_p, grad, hess, bag, fmask_pad)
+        with scope("root"):
+            st = self._init_root_wave(bins_p, grad, hess, bag, fmask_pad)
 
         def gcond(s):
             return (s.num_splits < self.grow_budget) & \
                 (jnp.max(self._pool_gains(s)) > 0.0)
 
-        st = lax.while_loop(gcond,
-                            lambda s: self._wave_step(s, fmask_pad), st)
-        if self._defer_sorts and self._stall_batch == 1:
-            # batched (K>1) replay corrections mask through phys_i spans
-            # and skip the pre-replay materialization (see learner_wave)
-            st = lax.cond(st.pending, self._materialize_sort,
-                          lambda s: s, st)
+        with scope("grow"):
+            st = lax.while_loop(
+                gcond, lambda s: self._wave_step(s, fmask_pad), st)
+            if self._defer_sorts and self._stall_batch == 1:
+                # batched (K>1) replay corrections mask through phys_i
+                # spans and skip the pre-replay materialization (see
+                # learner_wave)
+                st = lax.cond(st.pending, self._materialize_sort,
+                              lambda s: s, st)
         return self._emit_tree_wave(st, fmask_pad)
 
     def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,

@@ -145,3 +145,59 @@ def test_partition_permute_compiles(one_chip):
         _ROW_I, member, member, member, ((W,), jnp.bool_), _ROW_I, _ROW_I,
         member, member)
     assert text.count("tpu_custom_call") > 1   # one kernel per bucket
+
+
+def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
+                                                             monkeypatch):
+    """The whole fused iteration of the default wave path (gradients, tree,
+    score update) at a small shape, with the learner steered onto its TPU
+    branch: every ``sort`` and every Mosaic call of the compiled program
+    sits under one of the program's phase scopes, every kernel carries its
+    pinned name, and the phases of a tree all occur (the opening only with
+    ``tpu_wave_open_levels``, which the default path leaves at 0)."""
+    import re
+
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import learner_compact, learner_wave
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.observability import phases
+    from lightgbm_tpu.ops import histogram
+    for mod in (histogram, learner_compact, learner_wave, gbdt):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    rng = np.random.RandomState(0)
+    X = rng.randn(8192, F)
+    y = (X[:, 0] > 0).astype(float)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "min_data_in_leaf": 5}
+    with jax.enable_x64(False):
+        g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).gbdt
+        learner = g.learner
+        assert learner._use_pallas and learner._use_scan \
+            and learner._use_partition
+        args = (g.train_score.score, learner.bins_packed(), g._bag_mask,
+                g._feature_sample(), jnp.float32(0.1))
+        shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                  for a in args]
+        text = g._fused_iter_fn().lower(*shapes).compile().as_text()
+    named = [(line, re.search(r'op_name="([^"]*)"', line).group(1))
+             for line in text.split("\n")
+             if "tpu_custom_call" in line or re.search(r"[ )]sort\(", line)]
+    kernels = set()
+    for line, op_name in named:
+        parts = op_name.split("/")
+        assert set(parts) & set(phases.DEVICE_PHASES), op_name
+        if "tpu_custom_call" in line:
+            kernel = set(parts) & set(phases.KERNEL_NAMES)
+            assert len(kernel) == 1, op_name
+            # the instruction is named by the kernel too: what the trace shows
+            assert line.strip().lstrip("%").startswith(tuple(kernel)), line
+            kernels |= kernel
+    assert kernels == {"build_histogram_packed", "build_histogram_segments",
+                       "apply_partition_permute", "find_best_splits_batched"}
+    seen = {p for _, op_name in named for p in op_name.split("/")}
+    assert {"root", "grow", "replay", "emit", "hist", "scan", "partition",
+            "stall"} <= seen
+    for phase in ("gradients", "score_update"):
+        assert f"/{phase}/" in text

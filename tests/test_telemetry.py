@@ -58,7 +58,7 @@ def test_report_schema_smoke(rng):
     assert validate_report(rep, load_schema()) == []
     assert rep["enabled"] is True
     # per-phase wall timings
-    for phase in ("binning", "iteration", "tree_dispatch"):
+    for phase in ("binning", "iteration", "dispatch"):
         assert phase in rep["phases"], rep["phases"].keys()
         assert rep["phases"][phase]["count"] >= 1
         assert rep["phases"][phase]["total_ms"] >= 0.0

@@ -247,22 +247,35 @@ def test_tracing_adds_no_recompiles_to_warm_buckets(rng):
 
 
 def test_training_trace_off_is_noop_and_model_identical(rng):
-    """telemetry=False + no tracer: an attached-but-disabled recorder
-    records nothing, and training with trace_out produces the exact same
-    model text as without (tracing cannot perturb training)."""
+    """telemetry=False: a span is kept when a recorder listens and only
+    then (no recorder attached records nothing anywhere; an attached one
+    records the training spans with their arguments), and training with
+    trace_out produces the exact same model text as without (tracing
+    cannot perturb training)."""
     X = rng.randn(1500, 5)
     y = (X[:, 0] > 0).astype(float)
     p = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
          "seed": 7, "min_data_in_leaf": 10}
     plain = lgb.train(dict(p), lgb.Dataset(X.copy(), label=y.copy()), 6)
-    # a disabled-telemetry booster with a tracer attached records nothing
+    # nobody listens: no recorder, no phase table, no counters
+    tel = plain.gbdt.telemetry
+    assert tel.tracer is None and not tel.enabled
+    assert tel._phases == {} and tel._counters == {}
+    # a telemetry-off booster with a recorder attached records its spans
     bst2 = lgb.Booster(dict(p), lgb.Dataset(X.copy(), label=y.copy()))
     rec = TraceRecorder(True)
     bst2.gbdt.telemetry.tracer = rec
     for _ in range(3):
         bst2.update()
     bst2.gbdt._flush_pending()
-    assert len(rec) == 0            # telemetry off → no phase spans at all
+    by_name = {}
+    for s in rec.spans():
+        by_name.setdefault(s[0], []).append(s[7])
+    assert [a["it"] for a in by_name["iteration"]] == [0, 1, 2]
+    assert [a["queued"] for a in by_name["dispatch"]] == [0, 1, 2]
+    assert [a["tree"] for a in by_name["assemble_tree"]] == [0, 1, 2]
+    assert len(by_name["d2h_wait"]) == 3 and by_name["flush"]
+    assert bst2.gbdt.telemetry._phases == {}    # the table needs telemetry
     import tempfile
     with tempfile.TemporaryDirectory() as td:
         trace_path = os.path.join(td, "train_trace.json")
@@ -271,11 +284,11 @@ def test_training_trace_off_is_noop_and_model_identical(rng):
         assert traced.model_to_string() == plain.model_to_string()
         trace = json.loads(open(trace_path).read())
     names = {e["name"] for e in trace["traceEvents"] if e.get("ph") == "B"}
-    # training phase spans present (engine/gbdt phase timers as spans);
-    # the tree phase name depends on the dispatch path taken
+    # training spans present; the tree span's name depends on the dispatch
+    # path taken
     assert "iteration" in names
-    assert names & {"tree_train", "tree_dispatch", "gradients",
-                    "pipeline_flush"}
+    assert names & {"tree_train", "tree_dispatch", "dispatch", "gradients",
+                    "flush"}
 
 
 # -- podtrace: per-rank export + cross-host merge ----------------------------
