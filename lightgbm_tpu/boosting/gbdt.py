@@ -31,8 +31,7 @@ from ..learner import TPUTreeLearner
 from ..metrics import Metric, create_metric
 from ..objectives import ObjectiveFunction, create_objective
 from ..observability.phases import scope
-from ..ops.histogram import _on_tpu
-from ..ops.lookup import lookup_f32 as _lookup_small
+from ..ops.lookup import lookup_leaf_values
 from ..tree import Tree
 
 K_MODEL_VERSION = "v2"
@@ -65,19 +64,14 @@ class ScoreUpdater:
 
     def add_by_leaf_id(self, leaf_values: np.ndarray, leaf_id: jax.Array,
                        class_id: int) -> None:
-        """Train-side update: gather the (host-renewed, shrunk) leaf values by
-        the learner's final leaf partition (`score_updater.hpp:74-96`).
+        """Train-side update: look the (host-renewed, shrunk) leaf values up
+        by the learner's final leaf partition (`score_updater.hpp:74-96`).
 
-        On TPU the per-row lookup is a one-hot matmul, not an XLA gather — a
-        1M-row gather from a small table costs ~8 ms there while the MXU
-        one-hot contraction is ~0.5 ms (round-5 chip reading);
-        on CPU/GPU backends a plain gather is cheaper and the results are
-        bit-identical either way (lookup_f32 is exact)."""
+        On a TPU the per-row lookup is a chunked one-hot contraction, not an
+        XLA gather (`ops/lookup.py: lookup_leaf_values` has the readings and
+        the rule); the results are bit-identical either way."""
         lv = jnp.asarray(leaf_values.astype(np.float32))
-        if _on_tpu():
-            upd = _lookup_small(lv, leaf_id)
-        else:
-            upd = lv[leaf_id]
+        upd = lookup_leaf_values(lv, leaf_id, _row_sharded(leaf_id))
         self.score = self.score.at[class_id].add(upd)
 
     def add_by_tree(self, tree: Tree, class_id: int) -> None:
@@ -229,11 +223,18 @@ def _traverse_jit(bins, feat, thr, node_missing, node_default_bin,
     return leaf_value[leaf]
 
 
-@functools.partial(jax.jit, static_argnames=("k",), donate_argnums=(0,))
-def _score_add_leaf(score, leaf_output, leaf_id, lr, k):
+def _row_sharded(a: jax.Array) -> bool:
+    """Whether ``a`` (a learner's ``leaf_id``) lives on more than one device."""
+    return len(a.sharding.device_set) > 1
+
+
+@functools.partial(jax.jit, static_argnames=("k", "row_sharded"),
+                   donate_argnums=(0,))
+def _score_add_leaf(score, leaf_output, leaf_id, lr, k, row_sharded=False):
     """Device-side training-score update from the learner's final leaf
     partition — the sync-free fast path of ``ScoreUpdater.add_by_leaf_id``."""
-    return score.at[k].add(lr * jnp.take(leaf_output, leaf_id))
+    return score.at[k].add(
+        lr * lookup_leaf_values(leaf_output, leaf_id, row_sharded))
 
 
 class GBDT:
@@ -684,7 +685,7 @@ class GBDT:
                 rec_f, rec_i, rec_cat, leaf_id, leaf_out = out[:5]
                 with scope("score_update"):
                     score = score.at[0].add(
-                        lr * jnp.take(leaf_out, leaf_id))
+                        lr * lookup_leaf_values(leaf_out, leaf_id))
                 # out[5:] is the telemetry counter lane (present only when
                 # cfg.telemetry — the program is unchanged otherwise)
                 return (score, rec_f, rec_i, rec_cat) + tuple(out[5:])
@@ -763,7 +764,7 @@ class GBDT:
             with tel.phase("score_update", it=self.iter_):
                 self.train_score.score = _score_add_leaf(
                     self.train_score.score, leaf_out, leaf_id,
-                    self._lr_dev, k)
+                    self._lr_dev, k, row_sharded=_row_sharded(leaf_id))
             self._sync_sampler.leg("score_update", _t0,
                                    (self.train_score.score,))
             telem = self.learner.take_telemetry() \

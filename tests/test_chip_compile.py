@@ -23,6 +23,7 @@ it but not read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,8 @@ FW = 8                  # 28 features -> 32 padded columns -> 8 int32 words
 F = 28
 B = 256                 # max_bin=255 -> 256 padded bins
 W = 64                  # Config.tpu_wave_width: wave batch W, child scans 2W
+HIGGS_ROWS = 10_500_096  # the benchmark's 10,500,000 rows padded: 2^11 x 5,127
+_GATHER = re.compile(r"[ )]gather\(")
 
 
 @pytest.fixture(scope="module")
@@ -155,16 +158,13 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
     sits under one of the program's phase scopes, every kernel carries its
     pinned name, and the phases of a tree all occur (the opening only with
     ``tpu_wave_open_levels``, which the default path leaves at 0)."""
-    import re
-
     import numpy as np
 
     import lightgbm_tpu as lgb
     from lightgbm_tpu import learner_compact, learner_wave
-    from lightgbm_tpu.boosting import gbdt
     from lightgbm_tpu.observability import phases
-    from lightgbm_tpu.ops import histogram
-    for mod in (histogram, learner_compact, learner_wave, gbdt):
+    from lightgbm_tpu.ops import histogram, lookup
+    for mod in (histogram, learner_compact, learner_wave, lookup):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     rng = np.random.RandomState(0)
     X = rng.randn(8192, F)
@@ -201,3 +201,30 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
             "stall"} <= seen
     for phase in ("gradients", "score_update"):
         assert f"/{phase}/" in text
+    # the score update reads its leaf values by contraction, not by gather
+    in_update = [line for line in text.split("\n") if "/score_update/" in line]
+    assert not any(_GATHER.search(line) for line in in_update)
+    assert any("convolution(" in line for line in in_update)
+
+
+def test_score_update_at_higgs_rows_is_a_small_contraction(one_chip,
+                                                           monkeypatch):
+    """The pipelined path's score update (`gbdt._score_add_leaf`; the fused
+    step calls the same helper) at the benchmark's row count and 255 leaves:
+    no ``gather``, a convolution in its place, under 256 MiB of temporaries,
+    and no array of rows x 256 anywhere in the program, fused or not, so an
+    unchunked one-hot (5.4 GB written out) cannot come back unseen."""
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.ops import lookup
+    monkeypatch.setattr(lookup, "_on_tpu", lambda: True)
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in (((1, HIGGS_ROWS), jnp.float32),
+                           ((255,), jnp.float32),
+                           ((HIGGS_ROWS,), jnp.int32), ((), jnp.float32))]
+    with jax.enable_x64(False):
+        compiled = gbdt._score_add_leaf.lower(*shapes, k=0).compile()
+    text = compiled.as_text()
+    assert not _GATHER.search(text)
+    assert "convolution(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    assert not re.search(rf"\[(256,{HIGGS_ROWS}|{HIGGS_ROWS},256)\]", text)
