@@ -49,6 +49,31 @@ def _double_upper_bound(a: float) -> float:
     return float(np.nextafter(a, np.inf))
 
 
+def distinct_with_zero(values: np.ndarray, zero_cnt: int):
+    """Distinct sample values, ascending, with their counts, and zero put in
+    at its sorted place with the ``zero_cnt`` rows the sample left out
+    (`src/io/bin.cpp:236-270`).  A value within one ulp of its predecessor
+    joins that one's group, and the group keeps its last, largest value."""
+    values = np.sort(values, kind="stable")
+    n = len(values)
+    if n == 0:
+        return np.array([0.0]), np.array([zero_cnt])
+    first = np.flatnonzero(np.concatenate(
+        ([True], values[1:] > np.nextafter(values[:-1], np.inf))))
+    last = np.concatenate((first[1:], [n])) - 1
+    dv, ct = values[last], last - first + 1
+    cross = np.flatnonzero((values[first[1:] - 1] < 0.0)
+                           & (values[first[1:]] > 0.0))
+    if cross.size:          # zero sits between the two signs
+        dv = np.insert(dv, cross[0] + 1, 0.0)
+        ct = np.insert(ct, cross[0] + 1, zero_cnt)
+    if zero_cnt > 0 and values[0] > 0.0:
+        dv, ct = np.insert(dv, 0, 0.0), np.insert(ct, 0, zero_cnt)
+    if zero_cnt > 0 and values[-1] < 0.0:
+        dv, ct = np.append(dv, 0.0), np.append(ct, zero_cnt)
+    return dv, ct
+
+
 def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
                     max_bin: int, total_cnt: int, min_data_in_bin: int) -> List[float]:
     """Port of ``GreedyFindBin`` (`src/io/bin.cpp:72-150`)."""
@@ -82,17 +107,20 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
     upper_bounds = [math.inf] * max_bin
     lower_bounds = [math.inf] * max_bin
     bin_cnt = 0
-    lower_bounds[0] = float(distinct_values[0])
+    # plain lists: the loop below visits every distinct value of the sample
+    distinct_values = np.asarray(distinct_values, dtype=np.float64).tolist()
+    counts, is_big = counts.tolist(), is_big.tolist()
+    lower_bounds[0] = distinct_values[0]
     cur_cnt_inbin = 0
     for i in range(num_distinct - 1):
         if not is_big[i]:
-            rest_sample_cnt -= int(counts[i])
-        cur_cnt_inbin += int(counts[i])
+            rest_sample_cnt -= counts[i]
+        cur_cnt_inbin += counts[i]
         if (is_big[i] or cur_cnt_inbin >= mean_bin_size or
                 (is_big[i + 1] and cur_cnt_inbin >= max(1.0, mean_bin_size * np.float32(0.5)))):
-            upper_bounds[bin_cnt] = float(distinct_values[i])
+            upper_bounds[bin_cnt] = distinct_values[i]
             bin_cnt += 1
-            lower_bounds[bin_cnt] = float(distinct_values[i + 1])
+            lower_bounds[bin_cnt] = distinct_values[i + 1]
             if bin_cnt >= max_bin - 1:
                 break
             cur_cnt_inbin = 0
@@ -118,13 +146,8 @@ def find_bin_with_zero_as_one_bin(distinct_values: np.ndarray, counts: np.ndarra
                           & (distinct_values <= kZeroThreshold)].sum())
     right_cnt_data = int(counts[distinct_values > kZeroThreshold].sum())
 
-    left_cnt = -1
-    for i in range(num_distinct):
-        if distinct_values[i] > -kZeroThreshold:
-            left_cnt = i
-            break
-    if left_cnt < 0:
-        left_cnt = num_distinct
+    above = np.flatnonzero(distinct_values > -kZeroThreshold)
+    left_cnt = int(above[0]) if above.size else num_distinct
 
     bin_upper_bound: List[float] = []
     if left_cnt > 0:
@@ -135,11 +158,8 @@ def find_bin_with_zero_as_one_bin(distinct_values: np.ndarray, counts: np.ndarra
                                           left_max_bin, left_cnt_data, min_data_in_bin)
         bin_upper_bound[-1] = -kZeroThreshold
 
-    right_start = -1
-    for i in range(left_cnt, num_distinct):
-        if distinct_values[i] > kZeroThreshold:
-            right_start = i
-            break
+    above = np.flatnonzero(distinct_values[left_cnt:] > kZeroThreshold)
+    right_start = left_cnt + int(above[0]) if above.size else -1
 
     if right_start >= 0:
         right_max_bin = max_bin - 1 - len(bin_upper_bound)
@@ -212,35 +232,7 @@ class BinMapper:
         self.default_bin = 0
         zero_cnt = int(total_sample_cnt - len(values) - na_cnt)
 
-        # distinct values with zero injected at its sorted position
-        # (`src/io/bin.cpp:236-270`); equal-within-1ulp values merge keeping the
-        # larger one.
-        values = np.sort(values, kind="stable")
-        distinct_values: List[float] = []
-        counts: List[int] = []
-        if len(values) == 0 or (values[0] > 0.0 and zero_cnt > 0):
-            distinct_values.append(0.0)
-            counts.append(zero_cnt)
-        if len(values) > 0:
-            distinct_values.append(float(values[0]))
-            counts.append(1)
-        for i in range(1, len(values)):
-            prev, cur = values[i - 1], values[i]
-            if not _check_double_equal_ordered(prev, cur):
-                if prev < 0.0 and cur > 0.0:
-                    distinct_values.append(0.0)
-                    counts.append(zero_cnt)
-                distinct_values.append(float(cur))
-                counts.append(1)
-            else:
-                distinct_values[-1] = float(cur)
-                counts[-1] += 1
-        if len(values) > 0 and values[-1] < 0.0 and zero_cnt > 0:
-            distinct_values.append(0.0)
-            counts.append(zero_cnt)
-
-        dv = np.asarray(distinct_values)
-        ct = np.asarray(counts)
+        dv, ct = distinct_with_zero(values, zero_cnt)
         self.min_val = float(dv[0]) if len(dv) else 0.0
         self.max_val = float(dv[-1]) if len(dv) else 0.0
         cnt_in_bin: List[int] = []
@@ -265,10 +257,10 @@ class BinMapper:
             # count per bin for trivial-feature filtering (`src/io/bin.cpp:289-301`)
             cnt_in_bin = [0] * self.num_bin
             i_bin = 0
-            for i in range(num_distinct):
-                if dv[i] > self.bin_upper_bound[i_bin]:
+            for v, c in zip(dv.tolist(), ct.tolist()):
+                if v > bounds[i_bin]:
                     i_bin += 1
-                cnt_in_bin[i_bin] += int(ct[i])
+                cnt_in_bin[i_bin] += c
             if self.missing_type == MISSING_NAN:
                 cnt_in_bin[self.num_bin - 1] = na_cnt
             assert self.num_bin <= max_bin

@@ -233,8 +233,9 @@ def _row_sharded(a: jax.Array) -> bool:
 def _score_add_leaf(score, leaf_output, leaf_id, lr, k, row_sharded=False):
     """Device-side training-score update from the learner's final leaf
     partition — the sync-free fast path of ``ScoreUpdater.add_by_leaf_id``."""
-    return score.at[k].add(
-        lr * lookup_leaf_values(leaf_output, leaf_id, row_sharded))
+    with scope("score_update"):
+        return score.at[k].add(
+            lr * lookup_leaf_values(leaf_output, leaf_id, row_sharded))
 
 
 class GBDT:
@@ -487,6 +488,9 @@ class GBDT:
                     f"tree_learner={self.cfg.tree_learner} was requested "
                     f"but only ONE device is visible "
                     f"({jax.devices()[0]}): training SERIAL on it")
+        # the learner the job was routed to puts the table on its device(s)
+        # now, before anything is traced
+        self.learner.place_table()
 
     def add_valid_data(self, valid_data: Dataset, name: str,
                        metrics: Sequence[Metric]) -> None:
@@ -572,14 +576,15 @@ class GBDT:
                 for n, v in arrs.items():
                     setattr(obj, n, v)
                 try:
-                    if obj.name == "multiclass":
-                        return obj.get_gradients_all(score)
-                    gs, hs = [], []
-                    for k in range(K):
-                        g, h = obj.get_gradients(score[k], k)
-                        gs.append(g)
-                        hs.append(h)
-                    return jnp.stack(gs), jnp.stack(hs)
+                    with scope("gradients"):
+                        if obj.name == "multiclass":
+                            return obj.get_gradients_all(score)
+                        gs, hs = [], []
+                        for k in range(K):
+                            g, h = obj.get_gradients(score[k], k)
+                            gs.append(g)
+                            hs.append(h)
+                        return jnp.stack(gs), jnp.stack(hs)
                 finally:
                     for n, v in saved.items():
                         setattr(obj, n, v)
@@ -752,7 +757,7 @@ class GBDT:
         for k in range(self.num_tree_per_iteration):
             fmask = self._feature_sample()
             _t0 = time.perf_counter()
-            with tel.phase("tree_dispatch", it=self.iter_,
+            with tel.phase("dispatch", it=self.iter_,
                            queued=len(self._pending)):
                 rec_f, rec_i, rec_cat, leaf_id, leaf_out = \
                     self.learner.train_async(grad[k], hess[k],

@@ -149,7 +149,7 @@ class TPUTreeLearner:
                               "jax_enable_x64 is off; falling back to f32 "
                               "histogram accumulation")
                 self.hist_dp = False
-        self.bins = data.device_bins()
+        self._bins = None   # the device table: ``place_table``, ``bins``
         self._split_kwargs = dict(
             lambda_l1=float(cfg.lambda_l1), lambda_l2=float(cfg.lambda_l2),
             max_delta_step=float(cfg.max_delta_step),
@@ -203,6 +203,32 @@ class TPUTreeLearner:
         self._jit_init = jax.jit(self._init_root)
         self._jit_step = jax.jit(self._split_step, donate_argnums=(0,))
         self._jit_tree = jax.jit(self._train_tree_fused)
+
+    @property
+    def bins(self) -> jax.Array:
+        """The (F, N) table of bin codes on the default device.  Not placed
+        by ``__init__``: the sharded learners (``parallel/``) never read it
+        (they place per-device shards from the host table), nor does the
+        serial learner that ``GBDT.init`` builds before it routes a job to
+        one of them, so a job over a mesh never stages the whole table on
+        one chip.  ``GBDT.init`` places it (:meth:`place_table`); a learner
+        built by hand gets it at its first read."""
+        if self._bins is None:
+            # a learner built by hand may first read it under a trace
+            with jax.ensure_compile_time_eval():
+                self._bins = self.data.device_bins()
+        return self._bins
+
+    @bins.setter
+    def bins(self, value) -> None:
+        self._bins = value
+
+    def place_table(self) -> None:
+        """Put the table of bin codes where this learner's programs read it
+        (here the default device; a sharded learner, its shards).  Called
+        once by ``GBDT.init`` on the learner the job was routed to, before
+        anything is traced."""
+        self.bins
 
     # -- observability seams --------------------------------------------------
 

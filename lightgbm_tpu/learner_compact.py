@@ -133,9 +133,7 @@ class CompactTPUTreeLearner(TPUTreeLearner):
         sizes.append(self.n_pad)
         self._win_sizes = sizes
         self._win_sizes_arr = jnp.asarray(sizes, dtype=jnp.int32)
-        self._use_pallas = (hist_backend in ("auto", "pallas")
-                            and _on_tpu() and not self.hist_dp
-                            and self.n_pad % 1024 == 0)
+        self._use_pallas = self._kernels_fit(hist_backend, self.n_pad)
         prec_map = {"bf16x2": 2, "bf16x3": 3, "highest": 0}
         if cfg.tpu_hist_precision not in prec_map:
             raise ValueError(f"tpu_hist_precision must be one of "
@@ -151,6 +149,12 @@ class CompactTPUTreeLearner(TPUTreeLearner):
         self._q_cnt = None      # 1/(sh·m̄) count rescale — traced
         self._q_mbar = None     # m̄ mean hess mass per bagged row
         self._jit_tree_c = jax.jit(self._train_tree_compact)
+
+    def _kernels_fit(self, hist_backend: str, rows: int) -> bool:
+        """Whether the Pallas histogram kernels run over a row axis of
+        ``rows`` (the whole table; a shard under a mesh)."""
+        return (hist_backend in ("auto", "pallas") and _on_tpu()
+                and not self.hist_dp and rows % 1024 == 0)
 
     # -- packed bins ---------------------------------------------------------
 

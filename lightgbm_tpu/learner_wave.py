@@ -149,6 +149,15 @@ class WaveState(NamedTuple):
     telem: Optional[jax.Array] = None
 
 
+def _segment_row_block(rows: int) -> int:
+    """Row block of the segment histogram kernel: the largest power of two
+    up to 2048 that divides the row axis."""
+    rb = min(2048, rows)
+    while rows % rb:
+        rb //= 2
+    return rb
+
+
 class WaveTPUTreeLearner(CompactTPUTreeLearner):
     """Frontier-wave serial learner (factory slot
     `src/treelearner/tree_learner.cpp:9-33`, tree_learner=serial,
@@ -171,10 +180,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         self.fw_col = jnp.asarray(col)
         self.fw_goff = jnp.asarray(goff)
         self.fw_bnd = jnp.asarray(bnd)
-        rb = min(2048, self.n_pad)
-        while self.n_pad % rb:
-            rb //= 2
-        self._seg_rb = rb
+        self._seg_rb = _segment_row_block(self.n_pad)
         # fused Pallas split-scan (Config.tpu_wave_pallas_scan): the
         # batched child scans run as one kernel; constrained/categorical/
         # penalized/f64 configs keep the XLA path (scan_ineligible_reason)
@@ -480,7 +486,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         else:
             sum_g = self._global_scalar(jnp.sum((grad * bag).astype(acc)))
             sum_h = self._global_scalar(jnp.sum((hess * bag).astype(acc)))
-            cnt = self._global_scalar(jnp.sum(bag.astype(acc)))
+            # the bagged rows cross a mesh as an INTEGER: four shards of a
+            # 53M-row job sum past 2^24, where float32 skips odd numbers
+            cnt = self._global_scalar(
+                jnp.sum(bag > 0.5, dtype=jnp.int32)).astype(acc)
         md = int(self.cfg.max_depth)
         depth_ok = jnp.asarray([True if md <= 0 else md > 0])
         with scope("scan"):

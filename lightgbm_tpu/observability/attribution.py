@@ -44,14 +44,13 @@ _NON_LEG_SYNC = ("sync.iteration", "sync.drain", "sync.exchange_probe")
 # the full iteration wall (they run on every iteration; their global
 # means estimate their share of a sampled one).  ``tree_train`` is the
 # non-pipelined sync path's fully-host-synchronous tree build.
-_HOST_LEGS = ("bagging", "feature_sample", "dispatch", "tree_dispatch",
-              "score_update", "flush", "tree_train")
+_HOST_LEGS = ("bagging", "feature_sample", "dispatch", "score_update",
+              "flush", "tree_train")
 
 # host phases whose window is a strict prefix of a sync leg's
 # [dispatch, completion] window — when that sync leg was recorded,
 # counting the host phase too would double-count the dispatch time
 _HOST_SHADOWED = {"dispatch": "sync.tree_build",
-                  "tree_dispatch": "sync.tree_build",
                   "score_update": "sync.score_update",
                   "tree_train": "sync.tree_train"}
 

@@ -26,8 +26,16 @@ DEVICE_PHASES = ("gradients", "root", "opening", "grow", "replay", "emit",
 # stages nested inside root / opening / grow (and ``stall`` inside replay)
 DEVICE_STAGES = ("select", "partition", "hist", "scan", "stall")
 DEVICE_SCOPES = DEVICE_PHASES + DEVICE_STAGES
+# inside a phase or stage of the sharded learners (``parallel/``) only: the
+# collectives of one exchange site and the slices and converts around them
+# (``benchmark/phases_mesh.json`` holds the same; the serial step, every
+# scope of which is in DEVICE_SCOPES, has none)
+MESH_STAGES = ("exchange",)
 
-# host spans of the training window (recorded as ``lgbt.<name>``)
+# host spans of the training window (recorded as ``lgbt.<name>``).  No site
+# records ``tree_dispatch`` since the pipelined path's span became
+# ``dispatch`` (PR 28); the name stays while ``benchmark/phases.json``, which
+# a test holds equal to this tuple and only a benchmark PR may edit, has it
 SPAN_PREFIX = "lgbt."
 HOST_SPANS = ("iteration", "bagging", "feature_sample", "dispatch",
               "tree_dispatch", "tree_train", "flush", "d2h_wait",
@@ -44,8 +52,9 @@ KERNEL_NAMES = ("build_histogram_pallas", "build_histogram_packed",
 
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for one of :data:`DEVICE_SCOPES`."""
-    if name not in DEVICE_SCOPES:
+    """``jax.named_scope(name)`` for one of :data:`DEVICE_SCOPES` or
+    :data:`MESH_STAGES`."""
+    if name not in DEVICE_SCOPES + MESH_STAGES:
         raise ValueError(f"{name!r} is not one of the program's device "
-                         f"scopes {DEVICE_SCOPES}")
+                         f"scopes {DEVICE_SCOPES + MESH_STAGES}")
     return jax.named_scope(name)

@@ -635,6 +635,36 @@ def pack_bin_words(bins: jax.Array) -> jax.Array:
     return (b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24))
 
 
+def pack_bin_words_host(bins):
+    """:func:`pack_bin_words` on the host (numpy in, numpy out): what a
+    sharded learner packs one device's rows with before it places them, so
+    that no chip ever holds the unpacked table, nor another chip's rows."""
+    import os
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    import numpy as np
+
+    f, n = bins.shape
+    assert f % 4 == 0, f
+    assert bins.dtype == np.uint8, \
+        f"packable bins must be uint8, got {bins.dtype}"
+    assert sys.byteorder == "little"
+    words = np.empty((f // 4, n), np.int32)
+    # byte s of word k, little-endian; written in blocks of rows that stay in
+    # the cache (whole rows at a time: 44 s for 72 x 53M codes, this 0.5 s)
+    lanes = words.view(np.uint8).reshape(f // 4, n, 4)
+    step = 1 << 18
+
+    def pack(a):
+        for s in range(4):
+            lanes[:, a:a + step, s] = bins[s::4, a:a + step]
+
+    with ThreadPoolExecutor(max_workers=os.cpu_count() or 1) as pool:
+        list(pool.map(pack, range(0, n, step)))
+    return words
+
+
 def unpack_bin_words(words: jax.Array, num_features: int) -> jax.Array:
     """(Fw, S) int32 → (num_features, S) int32 bin codes."""
     import jax.numpy as jnp

@@ -42,6 +42,7 @@ from ..learner_compact import (CF_GAIN, CF_LCNT, CF_LOUT, CF_LSG, CF_LSH,
                                LF_MIN_C, LF_OUT, LF_SUM_G, LF_SUM_H, NUM_CF,
                                NUM_CI, NUM_LF, CompactState,
                                CompactTPUTreeLearner)
+from ..observability.phases import scope
 from ..ops.split import find_best_splits
 from ..tree import Tree
 
@@ -151,8 +152,9 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         owner, loc = divmod(fi, self.fs)
         row = state.hist_pool[fs.leaf, loc]              # (B, 3) slice row
         d = lax.axis_index(self.axis)
-        hrow = lax.psum(jnp.where(d == owner, row, jnp.zeros_like(row)),
-                        self.axis)
+        with scope("exchange"):
+            hrow = lax.psum(jnp.where(d == owner, row, jnp.zeros_like(row)),
+                            self.axis)
         return self._fix_hrow(hrow, fi, sum_g, sum_h, cnt)
 
     # -- sharded data placement (rule-driven, `parallel/sharding.py`) --------
@@ -162,10 +164,27 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         return rules_for_mode(self._placement_mode, self.mesh)
 
     def sharded_bins(self) -> jax.Array:
+        """The packed table, each device's block packed on the host and
+        placed on that device alone: the unpacked table never reaches a
+        chip (packing it on one device casts it to int32 on the way: four
+        times the table, beside it, on the chip that holds one shard)."""
         if self._sharded_bins is None:
-            self._sharded_bins = self._rules().place("bins",
-                                                     self.bins_packed())
+            from ..ops.hist_pallas import pack_bin_words_host
+            bins = self.data.bins                 # host (f_pad, n_pad) uint8
+            fw = bins.shape[0] // 4     # all words (``self.fw`` is a TILE's
+            #                             under a 2-D mesh)
+
+            def block(index):
+                words, rows = index
+                lo, hi, _ = words.indices(fw)
+                return pack_bin_words_host(bins[4 * lo:4 * hi, rows])
+
+            self._sharded_bins = jax.make_array_from_callback(
+                (fw, self.n_pad), self._rules().sharding_for("bins"), block)
         return self._sharded_bins
+
+    def place_table(self) -> None:
+        self.sharded_bins()
 
     def _row_sharded(self, arr):
         return self._rules().place("rows", arr)
@@ -183,19 +202,26 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         would pay K collective latencies per stall event."""
         return self._exchange(local_hists, 1)
 
+    # every collective of the tree program sits under the device scope
+    # ``exchange`` (observability/phases.py: MESH_STAGES), inside the phase
+    # and stage of its call site
+
     def _sync_counts(self, lc_bag, c_bag):
         """Global bagged counts from the local partition's sums."""
         self._rec_coll("psum", lc_bag)
         self._rec_coll("psum", c_bag)
-        return (lax.psum(lc_bag, self.axis), lax.psum(c_bag, self.axis))
+        with scope("exchange"):
+            return (lax.psum(lc_bag, self.axis), lax.psum(c_bag, self.axis))
 
     def _global_scalar(self, v):
         self._rec_coll("psum", v)
-        return lax.psum(v, self.axis)
+        with scope("exchange"):
+            return lax.psum(v, self.axis)
 
     def _global_max(self, v):
         self._rec_coll("pmax", v)
-        return lax.pmax(v, self.axis)
+        with scope("exchange"):
+            return lax.pmax(v, self.axis)
 
     def _global_row_offset(self):
         # rows are shard-contiguous in axis order, so shard d quantizes
@@ -219,18 +245,19 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         rescaled after the integer reduction (exact: sums are bounded by
         the tier gate).  The ledger records the PACKED operand so traced
         collective payload bytes reflect the wire format."""
-        if self._wire_int16():
-            from ..ops.quant import pack_hist_int16, unpack_hist_int16
-            inv_sg, inv_sh = self._q_inv
-            h16 = pack_hist_int16(h, inv_sg, inv_sh, self._q_mbar)
-            self._rec_coll("psum_scatter", h16)
-            h16 = lax.psum_scatter(h16, self.axis, scatter_dimension=dim,
-                                   tiled=True)
-            return unpack_hist_int16(h16, *self._q_scales,
-                                     1.0 / self._q_mbar)
-        self._rec_coll("psum_scatter", h)
-        return lax.psum_scatter(h, self.axis, scatter_dimension=dim,
-                                tiled=True)
+        with scope("exchange"):
+            if self._wire_int16():
+                from ..ops.quant import pack_hist_int16, unpack_hist_int16
+                inv_sg, inv_sh = self._q_inv
+                h16 = pack_hist_int16(h, inv_sg, inv_sh, self._q_mbar)
+                self._rec_coll("psum_scatter", h16)
+                h16 = lax.psum_scatter(h16, self.axis, scatter_dimension=dim,
+                                       tiled=True)
+                return unpack_hist_int16(h16, *self._q_scales,
+                                         1.0 / self._q_mbar)
+            self._rec_coll("psum_scatter", h)
+            return lax.psum_scatter(h, self.axis, scatter_dimension=dim,
+                                    tiled=True)
 
     def _child_best_rows(self, hist_left, hist_right, crow_f, fmask_pad,
                          depth_ok, constraints):
@@ -359,9 +386,10 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
         # global winner per child (tiny allgather)
         for x in (cf, ci, cb):
             self._rec_coll("all_gather", x)
-        cf_all = lax.all_gather(cf, self.axis)     # (D, K, NUM_CF)
-        ci_all = lax.all_gather(ci, self.axis)
-        cb_all = lax.all_gather(cb, self.axis)
+        with scope("exchange"):
+            cf_all = lax.all_gather(cf, self.axis)     # (D, K, NUM_CF)
+            ci_all = lax.all_gather(ci, self.axis)
+            cb_all = lax.all_gather(cb, self.axis)
         win = jnp.argmax(cf_all[:, :, CF_GAIN], axis=0)   # (K,) device idx
         cf_g = jnp.take_along_axis(
             cf_all, win[None, :, None], axis=0)[0]
@@ -493,14 +521,18 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
             self._jit_tree_c = jax.jit(fn)
         return self._jit_tree_c
 
-    def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
-                    feature_mask: Optional[jax.Array] = None):
+    def _padded_feature_mask(self, feature_mask) -> jax.Array:
+        """The (f_pad,) mask of the tree step: the sampled features (all of
+        them where none were sampled), False on the padding columns."""
         if feature_mask is None:
             feature_mask = jnp.ones(self.num_features, dtype=bool)
-        fmask_pad = jnp.zeros(self.f_pad, bool).at[:self.num_features].set(
+        return jnp.zeros(self.f_pad, bool).at[:self.num_features].set(
             feature_mask)
+
+    def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
+                    feature_mask: Optional[jax.Array] = None):
         return self._build_jit()(self.sharded_bins(), grad, hess, bag,
-                                 fmask_pad)
+                                 self._padded_feature_mask(feature_mask))
 
     def lowered_hlo_text(self) -> str:
         """Compiled HLO of the sharded tree step (for collective asserts)."""
@@ -589,8 +621,9 @@ class ShardedVotingLearner(ShardedCompactLearner):
     def _forced_hrow(self, state, fs, sum_g, sum_h, cnt):
         # the voting pool is full-width LOCAL-unreduced: reduce the one
         # forced feature's row across devices, then fix it
-        hrow = lax.psum(state.hist_pool[fs.leaf, fs.feature_inner],
-                        self.axis)
+        with scope("exchange"):
+            hrow = lax.psum(state.hist_pool[fs.leaf, fs.feature_inner],
+                            self.axis)
         return self._fix_hrow(hrow, fs.feature_inner, sum_g, sum_h, cnt)
 
     def exchange_probe(self):
@@ -622,9 +655,10 @@ class ShardedVotingLearner(ShardedCompactLearner):
             vals, votes = lax.top_k(g_loc, self.k_vote)       # (k,)
             self._rec_coll("all_gather", votes)
             self._rec_coll("all_gather", vals)
-            all_votes = lax.all_gather(votes, self.axis).reshape(-1)
-            all_valid = ~jnp.isneginf(
-                lax.all_gather(vals, self.axis).reshape(-1))
+            with scope("exchange"):
+                all_votes = lax.all_gather(votes, self.axis).reshape(-1)
+                all_valid = ~jnp.isneginf(
+                    lax.all_gather(vals, self.axis).reshape(-1))
             counts = jnp.zeros(self.f_pad, jnp.int32).at[all_votes].add(
                 all_valid.astype(jnp.int32), mode="drop")
             # GlobalVoting: top-2k by count, low feature index breaks ties
@@ -660,9 +694,10 @@ class ShardedVotingLearner(ShardedCompactLearner):
             cf, ci, cb = jax.vmap(
                 lambda h, g, hh, c: one(h, g, hh, c, None, None)
             )(hist2, sg2, sh2, cn2)
-        cf_all = lax.all_gather(cf, self.axis)
-        ci_all = lax.all_gather(ci, self.axis)
-        cb_all = lax.all_gather(cb, self.axis)
+        with scope("exchange"):
+            cf_all = lax.all_gather(cf, self.axis)
+            ci_all = lax.all_gather(ci, self.axis)
+            cb_all = lax.all_gather(cb, self.axis)
         # global winner; exact tie-break toward the LOWEST feature index —
         # unlike the sharded scan, the election's device slices are not
         # contiguous feature ranges, so the argmax alone is not enough

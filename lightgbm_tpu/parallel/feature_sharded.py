@@ -230,10 +230,7 @@ class FeatureShardedWaveLearner(FeatureShardedCompactLearner,
 
     def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
                     feature_mask: Optional[jax.Array] = None):
-        if feature_mask is None:
-            feature_mask = jnp.ones(self.num_features, dtype=bool)
-        fmask_pad = jnp.zeros(self.f_pad, bool).at[:self.num_features].set(
-            feature_mask)
+        fmask_pad = self._padded_feature_mask(feature_mask)
         if self._jit_tree_w is None:
             ax = self.axis
             out_specs = (P(), P(), P(), P(), P())

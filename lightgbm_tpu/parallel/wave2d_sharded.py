@@ -52,6 +52,7 @@ from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_compact import CF_GAIN, CI_FEAT, CompactTPUTreeLearner
 from ..learner_wave import WaveState, wave_budget_reason
+from ..observability.phases import scope
 from .sharding import AXIS_DATA, AXIS_FEATURE
 from .wave_sharded import ShardedWaveLearner
 
@@ -138,7 +139,8 @@ class ShardedWave2DLearner(ShardedWaveLearner):
         for wdi in range(self.fws):
             word = word + jnp.where(loc == wdi, bins_c[wdi], 0)
         self._rec_coll("psum", word)
-        return lax.psum(word, self.faxis)
+        with scope("exchange"):
+            return lax.psum(word, self.faxis)
 
     def _window_word(self, bw, col):
         """Stall-partition word extraction over a sliced (fws, S) window;
@@ -151,7 +153,8 @@ class ShardedWave2DLearner(ShardedWaveLearner):
         word = lax.dynamic_slice(bw, (safe, jnp.int32(0)), (1, S))[0]
         word = jnp.where((w >= 0) & (w < self.fws), word, 0)
         self._rec_coll("psum", word)
-        return lax.psum(word, self.faxis)
+        with scope("exchange"):
+            return lax.psum(word, self.faxis)
 
     # -- best-split merge over BOTH axes --------------------------------------
 
@@ -191,9 +194,10 @@ class ShardedWave2DLearner(ShardedWaveLearner):
         axes = (self.axis, self.faxis)
         for x in (cf, ci, cb):
             self._rec_coll("all_gather", x)
-        cf_all = lax.all_gather(cf, axes)      # (Dd*Df, K, NUM_CF)
-        ci_all = lax.all_gather(ci, axes)
-        cb_all = lax.all_gather(cb, axes)
+        with scope("exchange"):
+            cf_all = lax.all_gather(cf, axes)      # (Dd*Df, K, NUM_CF)
+            ci_all = lax.all_gather(ci, axes)
+            cb_all = lax.all_gather(cb, axes)
         gains = cf_all[:, :, CF_GAIN]
         max_gain = jnp.max(gains, axis=0)
         at_max = gains == max_gain[None, :]
@@ -254,26 +258,8 @@ class ShardedWave2DLearner(ShardedWaveLearner):
 
     # -- host orchestration ---------------------------------------------------
 
-    def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
-                    feature_mask: Optional[jax.Array] = None):
-        if feature_mask is None:
-            feature_mask = jnp.ones(self.num_features, dtype=bool)
-        fmask_pad = jnp.zeros(self.f_pad, bool).at[:self.num_features].set(
-            feature_mask)
-        if self._jit_tree_w is None:
-            ax, fx = self.axis, self.faxis
-            out_specs = (P(), P(), P(), P(ax), P())
-            if self._telemetry:
-                out_specs = out_specs + (P(),)
-            kw = dict(mesh=self.mesh,
-                      in_specs=(P(fx, ax), P(ax), P(ax), P(ax), P()),
-                      out_specs=out_specs)
-            fn = jax.shard_map(self._train_tree_wave_sharded,
-                               check_vma=False, **kw)
-            self._jit_tree_w = jax.jit(fn, donate_argnums=(1, 2)) \
-                if self._donate else jax.jit(fn)
-        return self._pop_telem(self._jit_tree_w(
-            self.sharded_bins(), grad, hess, bag, fmask_pad))
+    def _bins_spec(self) -> P:
+        return P(self.faxis, self.axis)     # a tile a device
 
     def exchange_probe(self):
         """The 2D learner's dominant wire: the per-wave data-axis

@@ -46,7 +46,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..config import Config
 from ..dataset import _ConstructedDataset
 from ..learner_wave import WaveState, WaveTPUTreeLearner, \
-    wave_budget_reason
+    _segment_row_block, wave_budget_reason
 from ..observability.phases import scope
 from .compact_sharded import ShardedCompactLearner
 
@@ -63,6 +63,14 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         # wave bookkeeping over the PADDED feature axis (no EFB bundles in
         # the sharded path; metadata was padded by the sharded __init__)
         self._init_wave_dims(cfg)
+        # the kernels per shard, where the serial learner would run them
+        # over these rows; set AFTER _init_wave_dims, so the partition stays
+        # the XLA sort (the Pallas partition has not run under a mesh).
+        # A shard's histogram keeps the padded feature axis (the exchange
+        # scatters it; the voting learner elects from it)
+        self._use_pallas = self._kernels_fit(hist_backend, self.n_local)
+        self._hist_cols = self.f_pad
+        self._seg_rb = _segment_row_block(self.n_local)
         # the sharded program keeps the round-4 per-wave flow (one
         # collective per wave); the serial opening's multi-slot kernel has
         # no exchange seam yet — growth starts at wave 1 as before
@@ -78,7 +86,8 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         # row 0 (left ROW count) is local window geometry; rows 1-2 are
         # the global bagged counts every device must agree on
         self._rec_coll("psum", cnt3[1:])
-        bagged = lax.psum(cnt3[1:], self.axis)
+        with scope("exchange"):
+            bagged = lax.psum(cnt3[1:], self.axis)
         return jnp.concatenate([cnt3[:1], bagged], axis=0)
 
     def _replicated_spans(self, spans):
@@ -86,7 +95,8 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         # batched-stall gate with the cross-device max so bv (and the
         # whole replay bookkeeping) stays identical on every shard
         self._rec_coll("pmax", spans)
-        return lax.pmax(spans, self.axis)
+        with scope("exchange"):
+            return lax.pmax(spans, self.axis)
 
     def _cand_rows_batch(self, hists, sg, sh, cn, feature_mask, depth_ok,
                          constraints):
@@ -100,6 +110,12 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         """Local per-member histograms over the full padded feature axis,
         ONE batched psum_scatter over features per wave, then subtraction
         against the (scattered) parent pool slices."""
+        if self._use_pallas:     # one segment-kernel call for the wave
+            h_local = self._segment_hists(st, sm_slot, sm_start, sm_cnt,
+                                          valid)
+            return self._scattered_children(st, h_local, ph, lh_w, rh_w,
+                                            left_small)
+
         def hist_member(_, xs):
             slot, start, cnt, vk = xs
 
@@ -116,6 +132,11 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
 
         _, h_local = lax.scan(hist_member, 0,
                               (sm_slot, sm_start, sm_cnt, valid))
+        return self._scattered_children(st, h_local, ph, lh_w, rh_w,
+                                        left_small)
+
+    def _scattered_children(self, st: WaveState, h_local, ph, lh_w, rh_w,
+                            left_small):
         # (W, f_pad, B, 3) -> (W, fs, B, 3): one collective per wave,
         # int16-packed in quantized mode (_exchange)
         h_small = self._exchange(h_local, 1)
@@ -126,6 +147,13 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         hr = jnp.where(lsm, h_large, h_small)
         pool = st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr)
         return pool, hl, hr
+
+    def _make_hist_branch_shard(self, S: int):
+        # with ``_hist_cols`` = f_pad the serial branch IS the shard's
+        # (padded features kept), and it runs the packed kernel
+        if self._use_pallas:
+            return self._make_hist_branch(S)
+        return super()._make_hist_branch_shard(S)
 
     def _hist_dtype(self):
         import jax.numpy as jnp
@@ -160,34 +188,37 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
 
     def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
                     feature_mask: Optional[jax.Array] = None):
-        if feature_mask is None:
-            feature_mask = jnp.ones(self.num_features, dtype=bool)
-        fmask_pad = jnp.zeros(self.f_pad, bool).at[:self.num_features].set(
-            feature_mask)
+        return self._pop_telem(self._tree_program()(
+            self.sharded_bins(), grad, hess, bag,
+            self._padded_feature_mask(feature_mask)))
+
+    def _tree_program(self):
+        """The jitted ``shard_map`` tree step (bins, grad, hess, bag, padded
+        feature mask), built on first use."""
         if self._jit_tree_w is None:
             ax = self.axis
             out_specs = (P(), P(), P(), P(ax), P())
             if self._telemetry:  # the counter lane is replicated bookkeeping
                 out_specs = out_specs + (P(),)
             kw = dict(mesh=self.mesh,
-                      in_specs=(P(None, ax), P(ax), P(ax), P(ax), P()),
+                      in_specs=(self._bins_spec(), P(ax), P(ax), P(ax), P()),
                       out_specs=out_specs)
             fn = jax.shard_map(self._train_tree_wave_sharded,
                                check_vma=False, **kw)
             self._jit_tree_w = jax.jit(fn, donate_argnums=(1, 2)) \
                 if self._donate else jax.jit(fn)
-        return self._pop_telem(self._jit_tree_w(
-            self.sharded_bins(), grad, hess, bag, fmask_pad))
+        return self._jit_tree_w
+
+    def _bins_spec(self) -> P:
+        return P(None, self.axis)           # every word, a device's rows
 
     def lowered_hlo_text(self) -> str:
         # grad/hess are donate_argnums under _donate: each position gets
         # its OWN buffer so the donated args never alias bag (LGB009)
         n = self.n_pad
         g, h, b = (jnp.zeros(n, jnp.float32) for _ in range(3))
-        self.train_async(g, h, b)  # build the jit
-        g, h, b = (jnp.zeros(n, jnp.float32) for _ in range(3))
         fmask_pad = jnp.ones(self.f_pad, bool)
-        return self._jit_tree_w.lower(
+        return self._tree_program().lower(
             self.sharded_bins(), g, h, b, fmask_pad).compile().as_text()
 
     def exchange_probe(self):

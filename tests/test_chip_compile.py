@@ -36,6 +36,10 @@ F = 28
 B = 256                 # max_bin=255 -> 256 padded bins
 W = 64                  # Config.tpu_wave_width: wave batch W, child scans 2W
 HIGGS_ROWS = 10_500_096  # the benchmark's 10,500,000 rows padded: 2^11 x 5,127
+# one device's block of the four-chip cell (criteo-v5e128-share): 53,125,000
+# rows padded to 53,125,120 over four, 67 columns padded to 72 = 18 words
+SHARD_ROWS = 13_281_280
+SHARD_FW = 18
 _GATHER = re.compile(r"[ )]gather\(")
 
 
@@ -228,3 +232,114 @@ def test_score_update_at_higgs_rows_is_a_small_contraction(one_chip,
     assert "convolution(" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
     assert not re.search(rf"\[(256,{HIGGS_ROWS}|{HIGGS_ROWS},256)\]", text)
+
+
+# -- the four-chip cell's per-shard pieces, at a shard's shape -----------------
+
+_SHARD_BINS = ((SHARD_FW, SHARD_ROWS), jnp.int32)
+_SHARD_W3 = ((3, SHARD_ROWS), jnp.float32)
+
+
+def test_shard_histograms_compile_at_the_criteo_share(one_chip):
+    """The two histogram kernels a shard of ``criteo-v5e128-share`` runs
+    (root and window pass; wave members) at 18 words x 13,281,280 rows and
+    W = 64."""
+    from lightgbm_tpu.ops.hist_pallas import (build_histogram_packed,
+                                              build_histogram_segments)
+    _compile(lambda b, w: build_histogram_packed(b, w, num_bins=B, nterms=3),
+             one_chip, _SHARD_BINS, _SHARD_W3)
+    rb = 2048
+    t = SHARD_ROWS // rb + W + W * (8192 // rb + 2) + 1
+    chunk = ((t,), jnp.int32)
+    _compile(lambda b, w, lid, cs, cb, cl: build_histogram_segments(
+        b, w, lid, cs, cb, cl, num_bins=B, n_slots=W, row_block=rb,
+        nterms=3), one_chip, _SHARD_BINS, _SHARD_W3,
+        ((SHARD_ROWS,), jnp.int32), chunk, chunk, chunk)
+
+
+@pytest.mark.slow
+def test_partition_sort_compiles_at_a_shard_of_the_criteo_share(one_chip):
+    """`learner_wave._materialize_sort`'s operands at 18 words (the key, the
+    words, three weight lanes, row ids and leaf ids = 24; Higgs has 13) x
+    13,281,280 rows: compiles, with 53 MB of temporaries beside 1.28 GB of
+    operands and as much of results (AOT, PR 28).  Slow: this one compile
+    takes 19 minutes here (16 at 2^20 rows: the emitter's time goes with the
+    operands once the rows pass some size), so tier 1 keeps the sort only
+    inside the four-chip step below, at 8,192 rows a shard."""
+    shapes = [((SHARD_ROWS,), jnp.int32)] * (1 + SHARD_FW) \
+        + [((SHARD_ROWS,), jnp.float32)] * 3 \
+        + [((SHARD_ROWS,), jnp.int32)] * 2
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda *ops: jax.lax.sort(
+            list(ops), num_keys=1, is_stable=True)).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("learner_name", ["ShardedWaveLearner",
+                                          "ShardedVotingWaveLearner"])
+def test_sharded_wave_step_compiles_for_four_chips(one_chip, monkeypatch,
+                                                   learner_name):
+    """The whole tree step of ``tree_learner=data`` (`ShardedWaveLearner`)
+    and of voting (`ShardedVotingWaveLearner`) for the FOUR described chips
+    of a v5e 2x2, at 67 columns and a small row count, with the learner
+    steered onto its TPU branch: each shard runs the Pallas histogram
+    kernels inside the ``shard_map`` program, and the program's collectives
+    sit under the scope ``exchange`` inside their phase (the compiler's own
+    merges of them may drop the name)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import learner_compact, learner_wave
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops import histogram
+    from lightgbm_tpu.parallel import wave_sharded
+    for mod in (histogram, learner_compact, learner_wave):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    from jax.experimental import topologies
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = Mesh(np.array(devices).reshape(4), ("data",))
+    rng = np.random.RandomState(0)
+    n = 32768
+    X = rng.randn(n, 67).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "min_data_in_leaf": 20, "tpu_wave_pallas_partition": "off",
+              "tree_learner": "data" if learner_name == "ShardedWaveLearner"
+              else "voting"}
+    with jax.enable_x64(False):
+        ds = lgb.Dataset(X, label=(X[:, 0] > 0).astype(np.float32),
+                         params=params).construct()
+        learner = getattr(wave_sharded, learner_name)(
+            Config.from_params(params), ds.constructed, mesh)
+        assert learner._use_pallas and not learner._use_partition
+        assert (learner.fw, learner.f_pad, learner.n_local) == (18, 72,
+                                                                n // 4)
+        rows = NamedSharding(mesh, P("data"))
+        shapes = [
+            jax.ShapeDtypeStruct((18, n), jnp.int32,
+                                 sharding=NamedSharding(mesh,
+                                                        P(None, "data"))),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((72,), jnp.bool_,
+                                 sharding=NamedSharding(mesh, P()))]
+        text = learner._tree_program().lower(*shapes).compile().as_text()
+    kernels = set(re.findall(r"%(build_histogram\w+?)[.\d]* =", text))
+    assert kernels == {"build_histogram_packed", "build_histogram_segments"}
+    coll = [line for line in text.split("\n") if re.search(
+        r" (all-reduce|reduce-scatter|all-gather)(-start|-done)?\(", line)]
+    # a site under ``vmap`` (the voting election, a scan per child) reads
+    # ``vmap(exchange)``
+    scoped = [line for line in coll
+              if re.search(r"/(vmap\()?exchange\)?/", line)]
+    assert len(coll) >= 8 and len(scoped) >= len(coll) - 2, \
+        [line[:160] for line in coll if line not in scoped]
+    for line in scoped:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert {"root", "grow", "replay"} & set(op_name.split("/")), op_name
+    # the histogram exchange of a wave (voting: of its elected features)
+    assert any("reduce-scatter" in line and "/grow/" in line
+               for line in scoped)

@@ -255,3 +255,18 @@ def test_trace_out_needs_no_telemetry_and_leaves_the_step_alone(rng,
 
     plain = lgb.Booster(dict(_BASE), lgb.Dataset(X, label=y))
     assert shape(bst.gbdt) == shape(plain.gbdt)
+
+
+# -- the sharded learners' own scope -------------------------------------------
+
+def test_mesh_scope_file_equals_the_programs():
+    """``benchmark/phases_mesh.json`` (new with the four-chip cell; the
+    accepted ``phases.json`` is the benchmark's and does not know the name)
+    holds ``phases.MESH_STAGES``, and a mesh stage is a scope ``scope()``
+    lets through without being one of the accepted phases or stages."""
+    with open(os.path.join(ROOT, "benchmark", "phases_mesh.json")) as fh:
+        data = json.load(fh)
+    assert tuple(data["mesh_stages"]) == phases.MESH_STAGES
+    assert not set(phases.MESH_STAGES) & set(phases.DEVICE_SCOPES)
+    with phases.scope("exchange"):
+        pass
