@@ -449,7 +449,8 @@ class Config:
     # histograms scan the member's materialized span with lid masks, ~2x
     # the child window area); the next wave's single sort materializes
     # both levels.  Halves the number of full-array sorts — the wave
-    # learner's largest per-wave cost (~6 ms each on v5e at 1M rows)
+    # learner's largest per-wave cost (ledger, PR 28: 144 ms each on v5e
+    # at 10.5M rows x 7 bin words; four a tree with deferral on)
     tpu_wave_defer_sorts: bool = True
     # --- observability ---
     # structured training telemetry (observability/): host phase timers,

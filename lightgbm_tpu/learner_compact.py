@@ -80,6 +80,23 @@ class CompactState(NamedTuple):
     rec_cat: jax.Array     # (L-1, W) uint32 — bin bitset of cat splits
 
 
+def to_row_order(rid_p: jax.Array, values: jax.Array, bound: int
+                 ) -> jax.Array:
+    """``values`` (int32 in ``[0, bound)``, one a row in partition order)
+    back in original row order: ``out[rid_p[i]] = values[i]``, by a sort on
+    ``rid_p`` (a permutation of ``arange(n)``, so no sort here needs
+    stability, which costs the chip a hidden row-index operand).  Where row
+    id and value fit 32 bits together they sort as ONE unsigned word; the
+    shapes decide, at trace time."""
+    n = rid_p.shape[0]
+    bits = (bound - 1).bit_length()
+    if (n - 1).bit_length() + bits <= 32:
+        word = (rid_p.astype(jnp.uint32) << bits) | values.astype(jnp.uint32)
+        low = lax.sort(word, is_stable=False) & jnp.uint32((1 << bits) - 1)
+        return low.astype(jnp.int32)
+    return lax.sort([rid_p, values], num_keys=1, is_stable=False)[1]
+
+
 class CompactTPUTreeLearner(TPUTreeLearner):
     """Leaf-wise learner with leaf-contiguous row compaction (see module
     docstring).  Factory slot: `src/treelearner/tree_learner.cpp:9-33`,
@@ -724,10 +741,8 @@ class CompactTPUTreeLearner(TPUTreeLearner):
                                                     st.num_leaves - 1),
                 state)
         # leaf partition in ORIGINAL row order for the score updater
-        # descatter to original row order via a 2-lane sort (~3x cheaper
-        # than the equivalent scatter on TPU)
         with scope("emit"):
-            leaf_id = lax.sort([state.rid_p, state.lid_p], num_keys=1)[1]
+            leaf_id = to_row_order(state.rid_p, state.lid_p, self.num_leaves)
             leaf_output = state.leaf_f[:, LF_OUT].astype(jnp.float32)
         return (state.rec_f, state.rec_i, state.rec_cat, leaf_id,
                 leaf_output)

@@ -8,7 +8,8 @@ restructures the growth into ~13 *frontier waves* while preserving exact
 best-first (leaf-wise) semantics:
 
   1. **Grow.**  Each wave splits the top-W positive-gain frontier leaves at
-     once: one full-array stable sort re-compacts every split window
+     once: one full-array sort (``growth_sort``, the permutation of a
+     stable sort on the window keys) re-compacts every split window
      simultaneously (per-row split parameters come from an MXU mask-matmul,
      never an XLA gather: ~10x slower on the chip, round 5), then the
      smaller-child histograms run per member (subtraction for siblings) and
@@ -56,7 +57,8 @@ from .learner_compact import (CF_GAIN, CF_LCNT, CF_LOUT, CF_LSG, CF_LSH,
                               CF_RCNT, CF_ROUT, CF_RSG, CF_RSH, CI_FEAT,
                               CI_FLAGS, CI_THR, LF_CNT, LF_DEPTH, LF_MAX_C,
                               LF_MIN_C, LF_OUT, LF_SUM_G, LF_SUM_H, NUM_CF,
-                              NUM_CI, NUM_LF, CompactTPUTreeLearner)
+                              NUM_CI, NUM_LF, CompactTPUTreeLearner,
+                              to_row_order)
 from .observability.phases import scope
 from .observability.telemetry import (TEL_FROZEN_MEMBERS, TEL_GROW_SPLITS,
                                       TEL_NSLOTS, TEL_POPS,
@@ -124,7 +126,8 @@ class WaveState(NamedTuple):
     w_p: jax.Array        # (3, N) f32 (g*bag, h*bag, bag)
     rid_p: jax.Array      # (N,) int32 original row ids
     lid_p: jax.Array      # (N,) int32 node-slot ids
-    key_p: jax.Array      # (N,) int32 window-order sort keys (2*start+bit)
+    key_p: jax.Array      # (N,) int32 window-order sort keys: 2 x the
+    #                       start of the row's logical window
     # per-node-slot state (M slots; a split allocates 2 fresh child slots)
     node_i: jax.Array     # (M, 2) int32 LOGICAL window [start, width]
     phys_i: jax.Array     # (M, 2) int32 materialized covering span (equals
@@ -147,6 +150,44 @@ class WaveState(NamedTuple):
     # (TEL_NSLOTS,) int32 device counter lane, or None when telemetry is
     # off — None is an empty pytree, so the disabled program is unchanged
     telem: Optional[jax.Array] = None
+
+
+# the growth sort carries the bagging bit above the node slot in ONE int32
+_BAG_SHIFT = 30
+
+
+def growth_sort_operands(fw: int) -> int:
+    """Row-sized operands of ``growth_sort`` at ``fw`` bin words: key, row
+    id, the words, two weight lanes, node slot with the bagging bit."""
+    return fw + 5
+
+
+def growth_sort(key_p, bins_p, w_p, rid_p, lid_p, num_slots: int):
+    """The full-array sort that re-compacts every keyed window: the five
+    row payloads of a ``WaveState`` permuted by ``key_p``, ties in position
+    order, as a stable sort on ``key_p`` would leave them.  Only operands
+    that hold information are sorted (ledger, PR 28: a sort costs 10 ms an
+    operand at 10.5M rows):
+
+    * no stability, which the chip pays for with a hidden row-index
+      operand: ``rid_p`` starts a tree as ``arange`` and every permutation
+      since is a stable partition of windows, so among rows of one key
+      position order IS ``rid_p`` order, and ``rid_p`` sorts as second key;
+    * ``w_p[2]`` is the bagging mask, 0.0 or 1.0 on every path, and rides
+      as bit ``_BAG_SHIFT`` of the ``lid_p`` operand (node slots lie below
+      ``num_slots``)."""
+    fw = bins_p.shape[0]
+    assert num_slots <= 1 << _BAG_SHIFT, num_slots
+    lid_bag = lid_p | ((w_p[2] > 0.5).astype(jnp.int32) << _BAG_SHIFT)
+    ops = ([key_p, rid_p] + [bins_p[i] for i in range(fw)]
+           + [w_p[0], w_p[1], lid_bag])
+    assert len(ops) == growth_sort_operands(fw)
+    sd = lax.sort(ops, num_keys=2, is_stable=False)
+    lid_bag = sd[4 + fw]
+    w_p = jnp.stack([sd[2 + fw], sd[3 + fw],
+                     (lid_bag >> _BAG_SHIFT).astype(w_p.dtype)])
+    return (sd[0], jnp.stack(sd[2:2 + fw]), w_p, sd[1],
+            lid_bag & ((1 << _BAG_SHIFT) - 1))
 
 
 def _segment_row_block(rows: int) -> int:
@@ -809,11 +850,12 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
 
             # -- pass 2: window-order keys.  INVARIANT: every leaf's rows carry
             # key = 2 * (its window start) — strictly increasing with position,
-            # so the stable sort is the identity on untouched leaves and
-            # partitions each split window in place.  The children's starts are
-            # already known pre-sort (s and s+lc), so both get final keys here.
-            # Starts are routed through the contraction as hi/lo 12-bit planes
-            # (one nonzero per row -> each plane f32-exact at any N).
+            # so ``growth_sort`` (ties in position order) is the identity on
+            # untouched leaves and partitions each split window in place.  The
+            # children's starts are already known pre-sort (s and s+lc), so
+            # both get final keys here.  Starts are routed through the
+            # contraction as hi/lo 12-bit planes (one nonzero per row -> each
+            # plane f32-exact at any N).
             # Partition mode needs no carried keys (each wave materializes its
             # own windows from wave-local destinations) — the pass is skipped.
             if self._use_partition and not opening:
@@ -844,7 +886,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                         (st.lid_p.reshape(Cm, ch), go_left.reshape(Cm, ch),
                          sort_r.reshape(Cm, ch),
                          st.key_p.reshape(Cm, ch))).reshape(-1)
-            # ---- ONE stable sort re-compacts every sortable split window.
+            # ---- ONE ``growth_sort`` re-compacts every sortable split window.
             # Skipped when the whole wave froze (the tree's bottom waves), when
             # opening mode defers ALL compaction to the materialization sort,
             # and — under sort-deferral alternation — on every wave without a
@@ -932,16 +974,8 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 else:
                     sort_now = do_sort
 
-                def run_sort(args):
-                    key_p, bins_p, w_p, rid_p, lid_p = args
-                    ops = ([key_p] + [bins_p[i] for i in range(fw)]
-                           + [w_p[0], w_p[1], w_p[2], rid_p, lid_p])
-                    sd = lax.sort(ops, num_keys=1, is_stable=True)
-                    return (sd[0], jnp.stack(sd[1:1 + fw]),
-                            jnp.stack(sd[1 + fw:4 + fw]), sd[4 + fw], sd[5 + fw])
-
                 key_p, bins_p, w_p, rid_p, lid_p = lax.cond(
-                    sort_now, run_sort, lambda a: a,
+                    sort_now, lambda a: growth_sort(*a, self.M), lambda a: a,
                     (key_p, st.bins_p, st.w_p, st.rid_p, lid_p))
                 st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
                                  lid_p=lid_p, key_p=key_p)
@@ -1143,21 +1177,17 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             jnp.full_like(sm_slot, n), valid, ph, lh_w, rh_w, left_small)
 
     def _materialize_sort(self, st: WaveState) -> WaveState:
-        """One stable full-array sort on the window keys assigned by the
+        """One full-array ``growth_sort`` on the window keys assigned by the
         opening levels: every leaf's rows land contiguously at its logical
         window (keys are 2×(window start), strictly increasing with
         position — the invariant the per-wave sorts maintain), after which
         the regular wave flow's physical-window machinery applies."""
-        fw = self.fw
         with scope("partition"):
-            ops = ([st.key_p] + [st.bins_p[i] for i in range(fw)]
-                   + [st.w_p[0], st.w_p[1], st.w_p[2], st.rid_p, st.lid_p])
-            sd = lax.sort(ops, num_keys=1, is_stable=True)
+            key_p, bins_p, w_p, rid_p, lid_p = growth_sort(
+                st.key_p, st.bins_p, st.w_p, st.rid_p, st.lid_p, self.M)
             return st._replace(
-                key_p=sd[0], bins_p=jnp.stack(sd[1:1 + fw]),
-                w_p=jnp.stack(sd[1 + fw:4 + fw]), rid_p=sd[4 + fw],
-                lid_p=sd[5 + fw], phys_i=st.node_i,
-                pending=jnp.asarray(False))
+                key_p=key_p, bins_p=bins_p, w_p=w_p, rid_p=rid_p,
+                lid_p=lid_p, phys_i=st.node_i, pending=jnp.asarray(False))
 
     def _segment_hists(self, st: WaveState, sm_slot, sm_start, sm_cnt,
                        valid, t_cap: Optional[int] = None):
@@ -1952,9 +1982,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 leaf_ref = lax.map(
                     lambda lid_c: lookup_int(slot2ref, lid_c),
                     st.lid_p.reshape(Cl, self._rows_len() // Cl)).reshape(-1)
-            # descatter to original row order by sorting on rid (a 2-lane sort
-            # is ~3x cheaper than the equivalent scatter on TPU)
-            leaf_id = lax.sort([st.rid_p, leaf_ref], num_keys=1)[1]
+            leaf_id = to_row_order(st.rid_p, leaf_ref, self.num_leaves)
             leaf_out = jnp.zeros(self.num_leaves, jnp.float32).at[
                 jnp.where(final, refidx, self.num_leaves + 7)].set(
                     st.node_f[:, LF_OUT].astype(jnp.float32))
@@ -2070,12 +2098,11 @@ def wave_transient_bytes(cfg: Config, n_pad: int, f_pad: int, b: int
     m_pad = ((M + 127) // 128) * 128
     mask_bytes = min(n_pad, 1 << 20) * W * 4 + n_pad * 12
     lookup_bytes = min(n_pad, 1 << 17) * m_pad * 4
-    # double-buffered sort operands (key + fw words + 3 weights + rid +
-    # lid).  Also covers partition mode: the permute kernel's bf16
-    # byte-plane output is (4·fw + 17) * 2 bytes/row ≈ (8·fw + 34)·n vs
-    # the sort's (8·fw + 48)·n, so the sort term is the conservative
-    # bound for either flow
-    sort_bytes = 2 * (f_pad // 4 + 6) * n_pad * 4
+    # the growth sort's operands, in and out.  Also covers partition mode:
+    # the permute kernel's bf16 byte-plane output is (4·fw + 17) * 2
+    # bytes/row ≈ (8·fw + 34)·n vs the sort's (8·fw + 40)·n, so the sort
+    # term is the conservative bound for either flow
+    sort_bytes = 2 * growth_sort_operands(f_pad // 4) * n_pad * 4
     # batched replay correction: the vectorized partition stacks the K-1
     # extras' (fw, S) bin-word + (3, S) weight + (S,) lid slices, S up to
     # the vec cap — on wide datasets (fw in the hundreds) this per-event

@@ -2,10 +2,10 @@
 
 The OpenCL reference ships histogram, split-scan AND a data-partition
 kernel; only the histogram family had been ported.  The wave learner
-re-compacts every split window with a full-array 13-lane ``lax.sort``
-(the learner's single largest per-wave cost in the round-5 chip record,
-deleted in PR 21; not re-measured).  ``lax.sort`` cost is operand-count- and
-key-entropy-insensitive (pure bitonic stage latency), but the wave's
+re-compacts every split window with a full-array ``lax.sort``
+(`learner_wave.growth_sort`: 144 ms at 10.5M rows x 7 words, 10 ms an
+operand, and 62 % of an iteration in the ledger of PR 28).  Its cost does
+not depend on the keys (bitonic stages), but the wave's
 permutation is *not* a general sort: every row's destination is known in
 closed form before any row moves —
 

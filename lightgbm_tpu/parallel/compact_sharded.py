@@ -41,7 +41,7 @@ from ..learner_compact import (CF_GAIN, CF_LCNT, CF_LOUT, CF_LSG, CF_LSH,
                                CI_FLAGS, CI_THR, LF_CNT, LF_DEPTH, LF_MAX_C,
                                LF_MIN_C, LF_OUT, LF_SUM_G, LF_SUM_H, NUM_CF,
                                NUM_CI, NUM_LF, CompactState,
-                               CompactTPUTreeLearner)
+                               CompactTPUTreeLearner, to_row_order)
 from ..observability.phases import scope
 from ..ops.split import find_best_splits
 from ..tree import Tree
@@ -469,7 +469,7 @@ class ShardedCompactLearner(CompactTPUTreeLearner):
                                             st.num_leaves - 1)
 
         state = jax.lax.fori_loop(0, L - 1, body, state)
-        leaf_id = lax.sort([state.rid_p, state.lid_p], num_keys=1)[1]
+        leaf_id = to_row_order(state.rid_p, state.lid_p, L)
         leaf_output = state.leaf_f[:, LF_OUT].astype(jnp.float32)
         return (state.rec_f, state.rec_i, state.rec_cat, leaf_id,
                 leaf_output)

@@ -259,21 +259,31 @@ def test_shard_histograms_compile_at_the_criteo_share(one_chip):
 
 @pytest.mark.slow
 def test_partition_sort_compiles_at_a_shard_of_the_criteo_share(one_chip):
-    """`learner_wave._materialize_sort`'s operands at 18 words (the key, the
-    words, three weight lanes, row ids and leaf ids = 24; Higgs has 13) x
-    13,281,280 rows: compiles, with 53 MB of temporaries beside 1.28 GB of
-    operands and as much of results (AOT, PR 28).  Slow: this one compile
-    takes 19 minutes here (16 at 2^20 rows: the emitter's time goes with the
-    operands once the rows pass some size), so tier 1 keeps the sort only
-    inside the four-chip step below, at 8,192 rows a shard."""
-    shapes = [((SHARD_ROWS,), jnp.int32)] * (1 + SHARD_FW) \
-        + [((SHARD_ROWS,), jnp.float32)] * 3 \
-        + [((SHARD_ROWS,), jnp.int32)] * 2
+    """`learner_wave.growth_sort` at 18 words x 13,281,280 rows (the key and
+    the row ids as two keys, the words, two weight lanes, leaf ids with the
+    bagging bit = 23 operands, no stability and so no hidden row index;
+    Higgs has 12): compiles, and its temporaries (the unstacked operands
+    and results: 1.81 GB; AOT, PR 29) stay under the `sort_buffer_bytes`
+    that `wave_transient_bytes` reckons for them.  Slow: 16 minutes here
+    (19 for the 24-operand stable sort it replaced; AOT, PR 28: the
+    emitter's time goes with the operands once the rows pass some size), so
+    tier 1 keeps the sort only inside the four-chip step below, at 8,192
+    rows a shard."""
+    from lightgbm_tpu.learner_wave import growth_sort, growth_sort_operands
+    shapes = [((SHARD_ROWS,), jnp.int32), _SHARD_BINS, _SHARD_W3,
+              ((SHARD_ROWS,), jnp.int32), ((SHARD_ROWS,), jnp.int32)]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     with jax.enable_x64(False):
-        compiled = jax.jit(lambda *ops: jax.lax.sort(
-            list(ops), num_keys=1, is_stable=True)).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+        compiled = jax.jit(lambda *a: growth_sort(*a, 1021)) \
+            .lower(*args).compile()
+    sorts = [line for line in compiled.as_text().split("\n")
+             if re.search(r"[ )]sort\(", line)]
+    assert len(sorts) == 1 and "is_stable=true" not in sorts[0]
+    results = re.split(r"[ )]sort\(", sorts[0])[0]
+    assert results.count(f"[{SHARD_ROWS}]") == growth_sort_operands(SHARD_FW) \
+        == 23
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * growth_sort_operands(SHARD_FW) * SHARD_ROWS * 4
 
 
 @pytest.mark.parametrize("learner_name", ["ShardedWaveLearner",

@@ -9,8 +9,11 @@ cutoff the physical row alignment differs so agreement is to float
 tolerance.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner_wave import WaveTPUTreeLearner
@@ -210,7 +213,6 @@ def test_wave_width_invariance():
 def test_segment_hist_kernel_interpret():
     # the wave learner's one-call-per-wave histogram kernel vs a bincount
     # oracle, in Pallas interpret mode (runs on CPU)
-    import jax.numpy as jnp
     from lightgbm_tpu.ops.hist_pallas import (build_histogram_segments,
                                               pack_bin_words)
 
@@ -355,7 +357,6 @@ def test_wave_defer_sorts_deep_tree():
 def test_multislot_hist_kernel_interpret():
     # the opening-phase full-pass kernel (K leaves in one pass, slot routing
     # in the weight operand) vs a bincount oracle, Pallas interpret mode
-    import jax.numpy as jnp
     from lightgbm_tpu.ops.hist_pallas import (build_histogram_multislot,
                                               pack_bin_words)
 
@@ -424,3 +425,174 @@ def test_wave_chunked_rows_exact(monkeypatch):
     b = _train(pb, X, y)
     assert b.gbdt.learner._row_chunk == 1024
     assert a.model_to_string() == b.model_to_string()
+
+
+# -- the partition's sorts carry only operands that hold information (PR 29) --
+
+def _stable_one_key_sort(key_p, bins_p, w_p, rid_p, lid_p, num_slots):
+    """The growth sort as it stood until PR 29, kept as the reference:
+    every payload its own operand, one key, stable."""
+    fw = bins_p.shape[0]
+    ops = ([key_p] + [bins_p[i] for i in range(fw)]
+           + [w_p[0], w_p[1], w_p[2], rid_p, lid_p])
+    sd = lax.sort(ops, num_keys=1, is_stable=True)
+    return (sd[0], jnp.stack(sd[1:1 + fw]), jnp.stack(sd[1 + fw:4 + fw]),
+            sd[4 + fw], sd[5 + fw])
+
+
+def _pending_state(n, fw, seed, slots=1021):
+    """Row payloads of a wave about to sort: 12 materialised windows with
+    row ids ascending inside each; a third of them untouched, a third split
+    (their rows keyed to either child's start, interleaved), a third frozen
+    (one key, the two children's node slots interleaved in a shared span)."""
+    rng = np.random.RandomState(seed)
+    win_of_id = rng.randint(0, 12, n)
+    rid = np.argsort(win_of_id, kind="stable").astype(np.int32)
+    size = np.bincount(win_of_id, minlength=12)
+    start = np.concatenate([[0], np.cumsum(size)[:-1]])
+    win = np.repeat(np.arange(12), size)
+    right = rng.rand(n) < 0.4
+    n_left = np.bincount(win, weights=~right, minlength=12).astype(np.int64)
+    split, frozen = win % 3 == 1, win % 3 == 2
+    key = 2 * (start[win] + np.where(split & right, n_left[win], 0))
+    lid = np.where(split | frozen, 100 + 2 * win + right, win)
+    lid[::97] = slots - 1
+    w = rng.randn(3, n).astype(np.float32)
+    w[2] = rng.rand(n) < 0.7
+    bins = rng.randint(0, 2**31 - 1, (fw, n)).astype(np.int32)
+    return (key.astype(np.int32), bins, w, rid, lid.astype(np.int32))
+
+
+@pytest.mark.parametrize("fw", [7, 18])
+def test_growth_sort_is_the_stable_one_key_sort(fw):
+    from lightgbm_tpu.learner_wave import (growth_sort,
+                                           growth_sort_operands)
+    assert growth_sort_operands(fw) == fw + 5
+    for seed in (0, 1):
+        state = _pending_state(5000, fw, seed)
+        assert 0.0 < state[2][2].mean() < 1.0      # bag of 0 and 1 mixed
+        got = jax.jit(lambda *a: growth_sort(*a, 1021))(*state)
+        want = jax.jit(lambda *a: _stable_one_key_sort(*a, 1021))(*state)
+        assert not np.array_equal(want[3], state[3])   # the sort moves rows
+        for g, w_ in zip(got, want):
+            assert g.dtype == w_.dtype and g.shape == w_.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
+
+
+def test_growth_sort_refuses_node_slots_that_reach_the_bag_bit():
+    from lightgbm_tpu.learner_wave import growth_sort
+    state = _pending_state(64, 1, 0)
+    with pytest.raises(AssertionError):
+        growth_sort(*state, (1 << 30) + 1)
+
+
+@pytest.mark.parametrize("n,bound,packed", [
+    (5000, 255, True), (5000, 300, True), (1, 255, True),
+    ((1 << 23) + 8, 256, True),     # 24 + 8 bits: the word's top bit is used
+    ((1 << 23) + 8, 300, False),    # 24 + 9 bits
+    (5000, 1 << 20, False)])        # 13 + 20 bits
+def test_to_row_order_packed_word_fallback_and_scatter_agree(n, bound,
+                                                             packed):
+    from lightgbm_tpu.learner_compact import to_row_order
+    rng = np.random.RandomState(n % 1000 + bound % 1000)
+    rid = rng.permutation(n).astype(np.int32)
+    values = rng.randint(0, bound, n).astype(np.int32)
+    values[:2] = (bound - 1, 0)[:min(n, 2)]
+    fn = jax.jit(lambda r, v: to_row_order(r, v, bound))
+    text = fn.lower(rid, values).as_text()
+    assert ("ui32" in text) == packed
+    got = fn(rid, values)
+    want = jnp.zeros(n, jnp.int32).at[rid].set(values)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _rows_with_a_category(n=30000, seed=11):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, 9)
+    X[:, 4] = rng.randint(0, 12, n)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] + 0.8 * (X[:, 4] % 3 == 0)
+         + 0.3 * rng.randn(n) > 0).astype(float)
+    return X, y
+
+
+_SORT_PATH = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 20, "verbosity": -1, "metric": "none",
+              "bagging_fraction": 0.7, "bagging_freq": 1, "bagging_seed": 5,
+              # a shard of the four-device run holds 7,680 rows: under the
+              # default cutoff of 8,192 no window of it would ever sort
+              "tpu_wave_sort_cutoff": 256,
+              "tpu_wave_pallas_partition": "off"}
+_SORT_PATH_RUNS = {
+    "serial": {},
+    "data4": {"tree_learner": "data", "parallel_mesh": "4"},
+    # the opening levels end in ``_materialize_sort``
+    "serial-opening": {"tpu_wave_open_levels": 2}}
+
+
+def _train_on_sort_path(run, rounds=3):
+    if run == "data4" and len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    X, y = _rows_with_a_category()
+    params = dict(_SORT_PATH, **_SORT_PATH_RUNS[run])
+    bst = _train(params, X, y, rounds, categorical_feature=[4])
+    assert isinstance(bst.gbdt.learner, WaveTPUTreeLearner)
+    assert not bst.gbdt.learner._use_partition
+    return bst
+
+
+@pytest.mark.parametrize("run", list(_SORT_PATH_RUNS))
+def test_row_ids_ascend_inside_every_materialised_window(run, monkeypatch):
+    """What lets ``growth_sort`` break ties by row id without stability:
+    after every wave (and after the replay's stall splits) ``rid_p`` ascends
+    strictly inside every leaf's materialised span, shared frozen spans
+    included, on every device."""
+    import lightgbm_tpu.learner_wave as lw
+    seen = []
+
+    def note(rid_p, phys_i, split_m, num_nodes):
+        leaves = ~np.asarray(split_m)[:int(num_nodes)]
+        seen.append((np.asarray(rid_p),
+                     np.asarray(phys_i)[:int(num_nodes)][leaves]))
+
+    def spy_on(name, state_of):
+        orig = getattr(lw.WaveTPUTreeLearner, name)
+
+        def spied(self, *args, **kw):
+            out = orig(self, *args, **kw)
+            st = state_of(out)
+            jax.debug.callback(note, st.rid_p, st.phys_i, st.split_m,
+                               st.num_nodes)
+            return out
+
+        monkeypatch.setattr(lw.WaveTPUTreeLearner, name, spied)
+
+    spy_on("_wave_body", lambda st: st)
+    spy_on("_materialize_sort", lambda st: st)
+    spy_on("_replay", lambda out: out[0])
+    bst = _train_on_sort_path(run)
+    bst.model_to_string()
+    jax.effects_barrier()
+    assert len(seen) >= 3 * 5 * (4 if run == "data4" else 1)
+    moved = 0
+    for rid, spans in seen:
+        assert np.array_equal(np.sort(rid), np.arange(rid.shape[0]))
+        moved += not np.array_equal(rid, np.arange(rid.shape[0]))
+        for s, c in spans:
+            assert np.all(np.diff(rid[s:s + c]) > 0), (s, c)
+    assert moved > len(seen) // 2
+
+
+@pytest.mark.parametrize("run", list(_SORT_PATH_RUNS))
+def test_growth_sort_builds_the_stable_sorts_models(run, monkeypatch):
+    import lightgbm_tpu.learner_wave as lw
+    new = _train_on_sort_path(run).model_to_string()
+    calls = []
+
+    def old(*args):
+        calls.append(1)
+        return _stable_one_key_sort(*args)
+
+    monkeypatch.setattr(lw, "growth_sort", old)
+    assert new == _train_on_sort_path(run).model_to_string()
+    assert calls
