@@ -15,8 +15,8 @@ Default run (one chip).  Each phase prints one JSON line as it finishes:
           AUC; then 3 iterations with a validation set and early stopping
           (the synchronous path).  Asserts the learner and what ``auto``
           promised on a TPU, with no kernel in interpret mode
-  parity  the same data and seed on the XLA path (Pallas partition and
-          scan off), and at 65,536 rows against the masked f32 learner
+  parity  the same data and seed on the XLA path (Pallas scan off), and
+          at 65,536 rows against the masked f32 learner
   serve   saved model -> in-process server on port 0 -> mixed-size
           requests through ServingClient == Booster.predict; zero
           host-fallback batches, compile-cache misses == warmed buckets
@@ -67,9 +67,8 @@ PARAMS = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
 AUC_BAND_FULL = 0.90     # 1,000,000 training rows
 AUC_BAND_SMALL = 0.85    # 65,536 rows / rehearsal sizes, fewer iterations
 # Two learners that build the SAME trees differ by f32 rounding (~1e-7 in
-# probability).  The Pallas partition is record-exact against the sort; the
-# Pallas scan, the bf16x3 histogram and the f32 one-hot histogram differ
-# from each other by summation-order ulps in split gains, so a near-tie
+# probability).  The Pallas scan, the bf16x3 histogram and the f32 one-hot
+# histogram differ by summation-order ulps in split gains, so a near-tie
 # can flip a split and move a few rows' predictions by much more than an
 # ulp.  Agreement is therefore held on the mean |dp| and on AUC; the max
 # |dp| is printed for the record.  (First chip run, PR 21: mean 2e-7 to
@@ -160,8 +159,8 @@ def _timed_train(lgb, params, ds, iters, **kw):
 
 def _learner_flags(learner):
     return {k: getattr(learner, "_" + k, None)
-            for k in ("use_pallas", "use_scan", "use_partition", "donate",
-                      "scan_interpret", "partition_interpret")}
+            for k in ("use_pallas", "use_scan", "donate",
+                      "scan_interpret")}
 
 
 class Smoke:
@@ -296,11 +295,11 @@ class Smoke:
             # -- checks, after everything above is on the line
             want = self.on_tpu      # what `auto` promises on this platform
             _require(out["learner"] == "WaveTPUTreeLearner", out["learner"])
-            for k in ("use_pallas", "use_scan", "use_partition", "donate"):
+            for k in ("use_pallas", "use_scan", "donate"):
                 _require(out["flags"][k] is want,
                          f"_{k} is {out['flags'][k]}, auto promises {want}")
-            for k in ("scan_interpret", "partition_interpret"):
-                _require(out["flags"][k] is False, f"_{k} is on")
+            _require(out["flags"]["scan_interpret"] is False,
+                     "_scan_interpret is on")
             _require(out["fused"] and out["pipelined"],
                      "the no-validation run left the fused pipelined path")
             _require(out["iterations"] == ITERS, "stopped early")
@@ -331,10 +330,8 @@ class Smoke:
     def run_parity(self, lgb):
         with self.phase("parity") as out:
             _require(hasattr(self, "bst"), "train phase left no model")
-            # (a) same data, same seed, the XLA path the config comments
-            # call record-exact (sort + XLA scan instead of the kernels)
-            xla = dict(PARAMS, tpu_wave_pallas_partition="off",
-                       tpu_wave_pallas_scan="off")
+            # (a) same data, same seed, the XLA scan instead of the kernel
+            xla = dict(PARAMS, tpu_wave_pallas_scan="off")
             b_xla, first, steady = _timed_train(lgb, xla, self.ds, ITERS)
             out["xla_path"] = {
                 "flags": _learner_flags(b_xla.gbdt.learner),
@@ -358,9 +355,8 @@ class Smoke:
                 **_agreement(self.yh, b_wave.predict(self.Xh),
                              b_mask.predict(self.Xh))}
             out["tolerance"] = {"mean_abs_dp": TOL_MEAN_ABS, "auc": TOL_AUC}
-            _require(out["xla_path"]["flags"]["use_scan"] is False
-                     and out["xla_path"]["flags"]["use_partition"] is False,
-                     "the XLA-path run still used a Pallas scan/partition")
+            _require(out["xla_path"]["flags"]["use_scan"] is False,
+                     "the XLA-path run still used the Pallas scan")
             _require(out["masked_reference"]["learners"]
                      == ["WaveTPUTreeLearner", "TPUTreeLearner"],
                      str(out["masked_reference"]["learners"]))

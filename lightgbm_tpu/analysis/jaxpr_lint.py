@@ -48,11 +48,10 @@ BANNED_SUBSTRINGS = ("callback", "infeed", "outfeed")
 #: program name -> the source file a finding anchors to
 PROGRAM_FILES = {
     "wave_serial": "lightgbm_tpu/learner_wave.py",
-    # the serial wave program with BOTH round-6 Pallas kernels forced on
-    # (stable partition replacing the re-compaction sort + fused split
-    # scan) — traced in interpret mode off-TPU, which exercises the same
-    # jaxpr structure the TPU path compiles
-    "wave_serial_pallas": "lightgbm_tpu/ops/partition_pallas.py",
+    # the serial wave program with the Pallas split scan forced on —
+    # traced in interpret mode off-TPU, which exercises the same jaxpr
+    # structure the TPU path compiles
+    "wave_serial_pallas": "lightgbm_tpu/ops/scan_pallas.py",
     # round-8 quantized-gradient programs: the serial step with int8/int16
     # discretization, and the data-sharded step whose histogram exchange
     # rides the int16 wire tier (ops/quant.py) — its psum_scatter payload
@@ -227,15 +226,9 @@ def _trace_wave_serial_pallas():
     from ..learner_wave import WaveTPUTreeLearner
 
     ds = _toy_dataset(512, 4, dict(_BASE_PARAMS))
-    cfg = Config.from_params(dict(
-        _BASE_PARAMS, tpu_wave_pallas_partition="on",
-        tpu_wave_pallas_scan="on",
-        # CI-sized windows must clear the sortable cutoff or the
-        # partition cond never traces its kernel branch
-        tpu_wave_sort_cutoff=64, tpu_sort_cutoff=32))
+    cfg = Config.from_params(dict(_BASE_PARAMS, tpu_wave_pallas_scan="on"))
     learner = WaveTPUTreeLearner(cfg, ds.constructed)
-    assert learner._use_partition and learner._use_scan, \
-        "forced Pallas knobs did not resolve on"
+    assert learner._use_scan, "the forced Pallas scan did not resolve on"
     z = jnp.zeros(ds.constructed.num_data_padded, jnp.float32)
     fmask = jnp.ones(learner.num_features, bool)
     return jax.make_jaxpr(learner._train_tree_wave)(

@@ -389,6 +389,12 @@ class Config:
     gpu_platform_id: int = -1
     gpu_device_id: int = -1
     gpu_use_dp: bool = False
+    # accepted and ignored since PR 30: the wave learner moves rows with
+    # `learner_wave.growth_sort` alone.  The field stays because the
+    # benchmark's traffic files pass "off" and an unknown key warns in every
+    # run; it goes when the next `benchmark` issue drops the key there
+    # ("on" raises in _finalize)
+    tpu_wave_pallas_partition: str = "auto"
     # TPU additions
     tpu_row_block: int = 1024
     tpu_hist_dtype: str = "float32"
@@ -449,8 +455,9 @@ class Config:
     # histograms scan the member's materialized span with lid masks, ~2x
     # the child window area); the next wave's single sort materializes
     # both levels.  Halves the number of full-array sorts — the wave
-    # learner's largest per-wave cost (ledger, PR 28: 144 ms each on v5e
-    # at 10.5M rows x 7 bin words; four a tree with deferral on)
+    # learner's largest per-wave cost (ledger, PR 29: 128.3 ms each on v5e
+    # at 10.5M rows x 7 bin words; four a tree with deferral on).  Since
+    # PR 30 only tests turn it off (ROADMAP queue 3, item 3)
     tpu_wave_defer_sorts: bool = True
     # --- observability ---
     # structured training telemetry (observability/): host phase timers,
@@ -616,9 +623,8 @@ class Config:
     # growth overshoot is — the replay pops exactly (num_leaves - 1)
     # splits regardless — and the slot/pool sizing already reserves
     # (num_leaves - 1) correction splits, so a guard stops batching near
-    # that reserve.  1 = the round-4 one-miss-per-pass behavior;
-    # -1 = auto (currently 4 at every scale — the round-5 sweep winner;
-    # re-sweep {2,3,4,6} was never run on the chip)
+    # that reserve.  1 = one miss per pass; -1 = auto = 4 at every scale
+    # (no reading in the ledger compares widths: ROADMAP queue 3, item 3)
     tpu_wave_stall_batch: int = -1
     # fuse the batched replay correction's TOP member into the
     # span-vectorized partition stage whenever its covering span fits the
@@ -626,18 +632,6 @@ class Config:
     # dispatch) instead of top-switch + extras-switch.  Exact — both
     # stages share _span_decide; False = the round-5 two-stage flow
     tpu_wave_stall_fuse_top: bool = True
-    # Pallas stable row-partition kernel (ops/partition_pallas.py): the
-    # wave learner's full-array re-compaction sort becomes a two-pass
-    # stable partition (exact destinations from prefix sums + a chunked
-    # byte-plane permute kernel), the port of the reference's OpenCL
-    # data-partition kernel.  "auto" = on whenever the Pallas histogram
-    # path runs and the shape gates pass (record-exact vs the sort path);
-    # "on" forces it (interpret mode off-TPU — tests); "off" keeps the
-    # round-5 sort flow.  Partition mode disables sort-deferral (each
-    # wave partitions its own windows; a partition pass is cheap enough
-    # that halving pass count no longer pays for the deferred waves'
-    # double-area member histograms)
-    tpu_wave_pallas_partition: str = "auto"
     # Pallas fused split-scan kernel (ops/scan_pallas.py): the
     # (leaves x features x bins) best-split search — cumulative
     # histograms, gain evaluation, validity masks, per-feature argmax —
@@ -732,6 +726,12 @@ class Config:
                                   "data_feature") and self.num_machines > 1
         self.is_parallel_find_bin = tl in ("data", "data_feature") \
             and self.num_machines > 1
+        if self.tpu_wave_pallas_partition not in ("auto", "off"):
+            raise ValueError(
+                "tpu_wave_pallas_partition=%r: the Pallas partition kernel "
+                "was removed; the wave learner partitions rows with "
+                "growth_sort (accepted and ignored: auto, off)"
+                % (self.tpu_wave_pallas_partition,))
         if self.is_unbalance and abs(self.scale_pos_weight - 1.0) > 1e-6:
             raise ValueError(
                 "Cannot set is_unbalance and scale_pos_weight at the same time")

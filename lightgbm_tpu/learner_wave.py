@@ -80,10 +80,8 @@ def _stall_extras_cap(budget: int) -> int:
 
 
 def _resolve_stall_batch(cfg: Config) -> int:
-    """``tpu_wave_stall_batch`` with -1 = auto.  Auto is 4 at every
-    measured scale (the round-5 K sweep winner over {1, 8, 16}; the
-    round-6 re-sweep {2, 3, 6} rides profile_stall_batch.py and bakes
-    its winner here)."""
+    """``tpu_wave_stall_batch`` with -1 = auto = 4 at every scale (no
+    reading in the ledger compares widths: ROADMAP queue 3, item 3)."""
     k = int(getattr(cfg, "tpu_wave_stall_batch", -1))
     if k < 0:
         k = 4
@@ -278,7 +276,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         cannot straddle."""
         from .ops.scan_pallas import fused_scan_ineligible_reason
         return (self._quant and getattr(self, "_use_scan", False)
-                and self._bundle is None and not self._ablate
+                and self._bundle is None
                 and type(self)._cand_rows_batch
                 is WaveTPUTreeLearner._cand_rows_batch
                 and type(self)._wave_member_hists
@@ -346,29 +344,6 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         # partition of a shared window would reorder sibling rows)
         self._wave_cutoff = int(cfg.tpu_wave_sort_cutoff)
         self._stall_cutoff = max(self._sort_cutoff, self._wave_cutoff)
-        # Pallas stable-partition kernel (Config.tpu_wave_pallas_partition):
-        # replaces the full-array re-compaction sort with exact
-        # destination computation + a chunked permute kernel.  Partition
-        # mode runs WITHOUT sort-deferral: each wave materializes its own
-        # windows (a partition pass is cheap enough that halving pass
-        # count no longer pays for deferred waves' double-area member
-        # hists), which also means phys_i always equals node_i at the
-        # replay and the dest lane is wave-local (no carried key state)
-        from .ops.partition_pallas import partition_ineligible_reason
-        pp = str(getattr(cfg, "tpu_wave_pallas_partition", "auto"))
-        reason = partition_ineligible_reason(rows, self.M, self.open_levels)
-        if pp == "on":
-            self._use_partition = reason is None
-            self._partition_interpret = not _on_tpu()
-        elif pp == "auto":
-            self._use_partition = (getattr(self, "_use_pallas", False)
-                                   and reason is None)
-            self._partition_interpret = False
-        else:
-            self._use_partition = False
-            self._partition_interpret = False
-        if self._use_partition:
-            self._defer_sorts = False
         # quantized-gradient training (Config.tpu_quantized_grad): int8
         # gradient / int16 hessian discretization with stochastic rounding
         # (ops/quant.py — the LightGBM quantized-training recipe).  Set
@@ -406,20 +381,6 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             # iteration (rf.py keeps _rf_grad across iters); donating
             # those buffers would invalidate them after the first tree
             self._donate = False
-        # dev-only phase ablation (its profiling script is deleted):
-        # comma-set of {nohist, noscan, nosort} — NOT a user knob; a leaked
-        # env var would silently train WRONG trees, so warn loudly
-        import os
-        self._ablate = set(
-            t for t in os.environ.get("LGBMTPU_WAVE_ABLATE", "").split(",")
-            if t)
-        if self._ablate:
-            import warnings
-            warnings.warn(
-                "LGBMTPU_WAVE_ABLATE=%s is set: the wave learner is running "
-                "in a PROFILING-ONLY ablation mode and will produce WRONG "
-                "trees. Unset it for real training." %
-                os.environ["LGBMTPU_WAVE_ABLATE"])
 
     # -- batched split finder -------------------------------------------------
 
@@ -628,16 +589,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         sg2 = i2(pcf[:, CF_LSG], pcf[:, CF_RSG])
         sh2 = i2(pcf[:, CF_LSH], pcf[:, CF_RSH])
         cn2 = i2(pcf[:, CF_LCNT], pcf[:, CF_RCNT])
-        if "noscan" in self._ablate:  # profiling: fabricated candidates
-            g2 = jnp.repeat(pcf[:, CF_GAIN], 2) * 0.9
-            cf2 = jnp.zeros((2 * K, NUM_CF), self._acc) \
-                .at[:, CF_GAIN].set(g2) \
-                .at[:, CF_LCNT].set(cn2 / 2).at[:, CF_RCNT].set(cn2 / 2) \
-                .at[:, CF_LSG].set(sg2 / 2).at[:, CF_RSG].set(sg2 / 2) \
-                .at[:, CF_LSH].set(sh2 / 2).at[:, CF_RSH].set(sh2 / 2)
-            ci2 = jnp.zeros((2 * K, NUM_CI), jnp.int32).at[:, CI_THR].set(127)
-            cb2 = jnp.zeros((2 * K, self.cat_W), jnp.uint32)
-        elif fused_parts is not None:
+        if fused_parts is not None:
             from .learner import _FeatCand
             from .ops.scan_pallas import fused_child_scans
             h_small, ph_k, left_small, lh_w, rh_w = fused_parts
@@ -856,36 +808,31 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             # both get final keys here.  Starts are routed through the
             # contraction as hi/lo 12-bit planes (one nonzero per row -> each
             # plane f32-exact at any N).
-            # Partition mode needs no carried keys (each wave materializes its
-            # own windows from wave-local destinations) — the pass is skipped.
-            if self._use_partition and not opening:
-                key_p = st.key_p
+            starts2 = jnp.stack([ps, ps + lc_w], axis=1)            # (W, 2)
+            planes = jnp.concatenate(
+                [(starts2 >> 12).astype(jnp.float32),
+                 (starts2 & 0xFFF).astype(jnp.float32)], axis=1)    # (W, 4)
+
+            def keys(lid_old_c, go_c, sort_c, key_c):
+                mask_f = ((lid_old_c[:, None] == wi[None, :])
+                          & valid[None, :]).astype(jnp.float32)
+                ks = lax.dot_general(mask_f, planes,
+                                     (((1,), (0,)), ((), ())),
+                                     precision=_HIGH)               # (ch, 4)
+                ki = jnp.rint(ks).astype(jnp.int32)
+                kl = 2 * ((ki[:, 0] << 12) + ki[:, 2])
+                kr = 2 * ((ki[:, 1] << 12) + ki[:, 3])
+                return jnp.where(sort_c, jnp.where(go_c, kl, kr), key_c)
+
+            if Cm == 1:
+                key_p = keys(st.lid_p, go_left, sort_r, st.key_p)
             else:
-                starts2 = jnp.stack([ps, ps + lc_w], axis=1)        # (W, 2)
-                planes = jnp.concatenate(
-                    [(starts2 >> 12).astype(jnp.float32),
-                     (starts2 & 0xFFF).astype(jnp.float32)], axis=1)  # (W, 4)
-
-                def keys(lid_old_c, go_c, sort_c, key_c):
-                    mask_f = ((lid_old_c[:, None] == wi[None, :])
-                              & valid[None, :]).astype(jnp.float32)
-                    ks = lax.dot_general(mask_f, planes,
-                                         (((1,), (0,)), ((), ())),
-                                         precision=_HIGH)           # (ch, 4)
-                    ki = jnp.rint(ks).astype(jnp.int32)
-                    kl = 2 * ((ki[:, 0] << 12) + ki[:, 2])
-                    kr = 2 * ((ki[:, 1] << 12) + ki[:, 3])
-                    return jnp.where(sort_c, jnp.where(go_c, kl, kr), key_c)
-
-                if Cm == 1:
-                    key_p = keys(st.lid_p, go_left, sort_r, st.key_p)
-                else:
-                    ch = n // Cm
-                    key_p = lax.map(
-                        lambda a: keys(*a),
-                        (st.lid_p.reshape(Cm, ch), go_left.reshape(Cm, ch),
-                         sort_r.reshape(Cm, ch),
-                         st.key_p.reshape(Cm, ch))).reshape(-1)
+                ch = n // Cm
+                key_p = lax.map(
+                    lambda a: keys(*a),
+                    (st.lid_p.reshape(Cm, ch), go_left.reshape(Cm, ch),
+                     sort_r.reshape(Cm, ch),
+                     st.key_p.reshape(Cm, ch))).reshape(-1)
             # ---- ONE ``growth_sort`` re-compacts every sortable split window.
             # Skipped when the whole wave froze (the tree's bottom waves), when
             # opening mode defers ALL compaction to the materialization sort,
@@ -896,79 +843,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             if opening:
                 st = st._replace(lid_p=lid_p, key_p=key_p)
                 sorted_now = jnp.asarray(False)
-            elif self._use_partition and "nosort" not in self._ablate:
-                # ---- Pallas stable partition (ops/partition_pallas.py): the
-                # permutation the stable sort produces, computed directly —
-                # per-row destinations from two exclusive prefix sums over
-                # the left/right flags plus per-member window bases routed
-                # through the same mask-matmul as the key pass, then one
-                # chunked byte-plane permute kernel.  Record-exact vs the
-                # sort (tests/test_partition.py).
-                sort_now = do_sort
-
-                def run_partition(args):
-                    from .ops.partition_pallas import (apply_partition,
-                                                       exclusive_cumsum_i32)
-                    bins_p_i, w_p_i, rid_p_i, lid_p_i = args
-                    gl = sort_r & go_left
-                    gr = sort_r & ~go_left
-                    cum = exclusive_cumsum_i32(
-                        jnp.stack([gl, gr]).astype(jnp.int32))
-                    cl, cr = cum[0], cum[1]
-                    active = sortable
-                    ps_s = jnp.where(active, ps, 0)
-                    cl_ps = jnp.take(cl, ps_s)
-                    cr_ps = jnp.take(cr, ps_s)
-                    # member bases shifted by +n so the 13/12-bit plane split
-                    # stays non-negative (each plane has one nonzero per row
-                    # -> f32-exact at any N <= 2^24)
-                    base_l = ps + n - cl_ps
-                    base_r = ps + lc_w + n - cr_ps
-                    dplanes = jnp.stack(
-                        [(base_l >> 12).astype(jnp.float32),
-                         (base_l & 0xFFF).astype(jnp.float32),
-                         (base_r >> 12).astype(jnp.float32),
-                         (base_r & 0xFFF).astype(jnp.float32)],
-                        axis=1)                                     # (W, 4)
-
-                    def dests(lid_old_c, go_c, sort_c, pos_c, cl_c, cr_c):
-                        mask_f = ((lid_old_c[:, None] == wi[None, :])
-                                  & valid[None, :]).astype(jnp.float32)
-                        ks = lax.dot_general(mask_f, dplanes,
-                                             (((1,), (0,)), ((), ())),
-                                             precision=_HIGH)       # (ch, 4)
-                        ki = jnp.rint(ks).astype(jnp.int32)
-                        bl = (ki[:, 0] << 12) + ki[:, 1] - n
-                        br = (ki[:, 2] << 12) + ki[:, 3] - n
-                        return jnp.where(
-                            sort_c, jnp.where(go_c, bl + cl_c, br + cr_c),
-                            pos_c)
-
-                    pos = jnp.arange(n, dtype=jnp.int32)
-                    if Cm == 1:
-                        dest = dests(st.lid_p, go_left, sort_r, pos, cl, cr)
-                    else:
-                        ch = n // Cm
-                        dest = lax.map(
-                            lambda a: dests(*a),
-                            (st.lid_p.reshape(Cm, ch),
-                             go_left.reshape(Cm, ch),
-                             sort_r.reshape(Cm, ch), pos.reshape(Cm, ch),
-                             cl.reshape(Cm, ch),
-                             cr.reshape(Cm, ch))).reshape(-1)
-                    return apply_partition(
-                        bins_p_i, w_p_i, rid_p_i, lid_p_i, dest,
-                        sort_r.astype(jnp.int32), ps, lc_w, cw, active,
-                        cl, cr, cl_ps, cr_ps,
-                        interpret=self._partition_interpret)
-
-                bins_p, w_p, rid_p, lid_p = lax.cond(
-                    sort_now, run_partition, lambda a: a,
-                    (st.bins_p, st.w_p, st.rid_p, lid_p))
-                st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
-                                 lid_p=lid_p)
-                sorted_now = sort_now
-            elif "nosort" not in self._ablate:
+            else:
                 if self._defer_sorts:
                     sort_now = st.pending
                 else:
@@ -980,9 +855,6 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 st = st._replace(bins_p=bins_p, w_p=w_p, rid_p=rid_p,
                                  lid_p=lid_p, key_p=key_p)
                 sorted_now = sort_now
-            else:  # profiling skeleton: windows stay unsorted (garbage layout)
-                st = st._replace(lid_p=lid_p, key_p=key_p)
-                sorted_now = do_sort
             st = st._replace(pending=(st.pending | do_sort) & ~sorted_now)
         with scope("select"):
             # ---- child windows: sortable members split [s,lc)/[s+lc,..);
@@ -1095,10 +967,6 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         subtraction + pool writes; returns (pool, hl, hr).  The sharded
         subclass overrides this to reduce-scatter the W local histograms
         over the feature axis before subtraction."""
-        if "nohist" in self._ablate:
-            shp = (sm_slot.shape[0], self._hist_cols, self._hist_nbins, 3)
-            hl = hr = jnp.zeros(shp, st.hist_pool.dtype)
-            return st.hist_pool, hl, hr
         if self._use_pallas:
             h_small = self._segment_hists(st, sm_slot, sm_start, sm_cnt,
                                           valid)
@@ -2098,10 +1966,7 @@ def wave_transient_bytes(cfg: Config, n_pad: int, f_pad: int, b: int
     m_pad = ((M + 127) // 128) * 128
     mask_bytes = min(n_pad, 1 << 20) * W * 4 + n_pad * 12
     lookup_bytes = min(n_pad, 1 << 17) * m_pad * 4
-    # the growth sort's operands, in and out.  Also covers partition mode:
-    # the permute kernel's bf16 byte-plane output is (4·fw + 17) * 2
-    # bytes/row ≈ (8·fw + 34)·n vs the sort's (8·fw + 40)·n, so the sort
-    # term is the conservative bound for either flow
+    # the growth sort's operands, in and out
     sort_bytes = 2 * growth_sort_operands(f_pad // 4) * n_pad * 4
     # batched replay correction: the vectorized partition stacks the K-1
     # extras' (fw, S) bin-word + (3, S) weight + (S,) lid slices, S up to

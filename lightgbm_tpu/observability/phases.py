@@ -44,7 +44,9 @@ HOST_SPANS = ("iteration", "bagging", "feature_sample", "dispatch",
 
 # the ``name=`` of every ``pl.pallas_call`` in ``ops/`` (a test holds the
 # call sites to this tuple): what the Mosaic dump and the ``tf_op`` path
-# show, and what ``benchmark/kernels/*.json`` looks for
+# show, and what ``benchmark/kernels/*.json`` looks for.  No call site is
+# named ``apply_partition_permute`` since its kernel went (PR 30); the name
+# stays while ``benchmark/phases.json`` has it, as ``tree_dispatch`` above
 KERNEL_NAMES = ("build_histogram_pallas", "build_histogram_packed",
                 "build_histogram_segments", "build_histogram_multislot",
                 "apply_partition_permute", "find_best_splits_batched",

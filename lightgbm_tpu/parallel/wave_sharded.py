@@ -23,15 +23,13 @@ the serial learner):
 Exactness: the records stream is identical to the serial wave learner's
 (`tests/test_parallel.py::test_wave_sharded_records_match_serial`).
 
-Round 6: the Pallas stable-partition kernel composes here PER SHARD —
-``_wave_body`` (shared with the serial learner) computes destinations
-from LOCAL window geometry and local prefix sums and permutes only the
-local rows, so ``tpu_wave_pallas_partition`` changes ZERO collective
-sites (`analysis/budgets.json` pins them); ``_init_wave_dims`` re-runs
-with the shard-local row count, so the 2^24-row eligibility gate applies
-per shard.  The fused split-scan does NOT apply here: the sharded
-candidate scans go through ``_best_rows_global`` (feature-slice scans +
-all_gather), which overrides ``_cand_rows_batch`` entirely.
+``_wave_body`` (shared with the serial learner) sorts only a shard's own
+rows by LOCAL window geometry (``growth_sort``), so the partition holds no
+collective site (`analysis/budgets.json` pins them); ``_init_wave_dims``
+re-runs with the shard-local row count.  The fused split-scan does NOT
+apply here: the sharded candidate scans go through ``_best_rows_global``
+(feature-slice scans + all_gather), which overrides ``_cand_rows_batch``
+entirely.
 """
 
 from __future__ import annotations
@@ -64,10 +62,8 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         # the sharded path; metadata was padded by the sharded __init__)
         self._init_wave_dims(cfg)
         # the kernels per shard, where the serial learner would run them
-        # over these rows; set AFTER _init_wave_dims, so the partition stays
-        # the XLA sort (the Pallas partition has not run under a mesh).
-        # A shard's histogram keeps the padded feature axis (the exchange
-        # scatters it; the voting learner elects from it)
+        # over these rows.  A shard's histogram keeps the padded feature
+        # axis (the exchange scatters it; the voting learner elects from it)
         self._use_pallas = self._kernels_fit(hist_backend, self.n_local)
         self._hist_cols = self.f_pad
         self._seg_rb = _segment_row_block(self.n_local)

@@ -1,9 +1,9 @@
 """Ahead-of-time compiles of the main path's Pallas kernels for a TPU v5e.
 
 Interpret mode proves a kernel's arithmetic, not that Mosaic (the chip's
-kernel compiler) accepts it: PR 6's partition and split-scan kernels and
-PR 12's fused child-scan passed every interpret-mode parity test and were
-refused by the compiler the first time it saw them (PR 21).  libtpu is
+kernel compiler) accepts it: PR 6's split-scan kernel and PR 12's fused
+child-scan passed every interpret-mode parity test and were refused by
+the compiler the first time it saw them (PR 21).  libtpu is
 installed here, and it compiles for a chip that is DESCRIBED, not attached,
 so each kernel the default wave path reaches on a TPU is compiled below at
 Higgs width — 28 features = 8 packed words, 256 padded bins, 2^20 rows, the
@@ -142,23 +142,12 @@ def test_fused_child_scan_compiles(one_chip):
              child, child, child, meta, meta, meta, ((F,), jnp.bool_))
 
 
-def test_partition_permute_compiles(one_chip):
-    """`apply_partition` — chunk list, every grid-size bucket of the
-    permute kernel, and the byte-plane recombine — for one W-member wave."""
-    from lightgbm_tpu.ops.partition_pallas import apply_partition
-    member = ((W,), jnp.int32)
-    text = _compile(
-        apply_partition, one_chip, _BINS, _W3, _ROW_I, _ROW_I, _ROW_I,
-        _ROW_I, member, member, member, ((W,), jnp.bool_), _ROW_I, _ROW_I,
-        member, member)
-    assert text.count("tpu_custom_call") > 1   # one kernel per bucket
-
-
 def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
                                                              monkeypatch):
     """The whole fused iteration of the default wave path (gradients, tree,
-    score update) at a small shape, with the learner steered onto its TPU
-    branch: every ``sort`` and every Mosaic call of the compiled program
+    score update: the path of every benchmark cell) at a small shape, with
+    the learner steered onto its TPU branch: every ``sort`` (the partition's
+    among them) and every Mosaic call of the compiled program
     sits under one of the program's phase scopes, every kernel carries its
     pinned name, and the phases of a tree all occur (the opening only with
     ``tpu_wave_open_levels``, which the default path leaves at 0)."""
@@ -178,8 +167,7 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
     with jax.enable_x64(False):
         g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).gbdt
         learner = g.learner
-        assert learner._use_pallas and learner._use_scan \
-            and learner._use_partition
+        assert learner._use_pallas and learner._use_scan
         args = (g.train_score.score, learner.bins_packed(), g._bag_mask,
                 g._feature_sample(), jnp.float32(0.1))
         shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -199,7 +187,9 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
             assert line.strip().lstrip("%").startswith(tuple(kernel)), line
             kernels |= kernel
     assert kernels == {"build_histogram_packed", "build_histogram_segments",
-                       "apply_partition_permute", "find_best_splits_batched"}
+                       "find_best_splits_batched"}
+    assert any(re.search(r"[ )]sort\(", line) and "/grow/" in op_name
+               and "/partition/" in op_name for line, op_name in named)
     seen = {p for _, op_name in named for p in op_name.split("/")}
     assert {"root", "grow", "replay", "emit", "hist", "scan", "partition",
             "stall"} <= seen
@@ -315,7 +305,7 @@ def test_sharded_wave_step_compiles_for_four_chips(one_chip, monkeypatch,
     n = 32768
     X = rng.randn(n, 67).astype(np.float32)
     params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
-              "min_data_in_leaf": 20, "tpu_wave_pallas_partition": "off",
+              "min_data_in_leaf": 20,
               "tree_learner": "data" if learner_name == "ShardedWaveLearner"
               else "voting"}
     with jax.enable_x64(False):
@@ -323,7 +313,7 @@ def test_sharded_wave_step_compiles_for_four_chips(one_chip, monkeypatch,
                          params=params).construct()
         learner = getattr(wave_sharded, learner_name)(
             Config.from_params(params), ds.constructed, mesh)
-        assert learner._use_pallas and not learner._use_partition
+        assert learner._use_pallas
         assert (learner.fw, learner.f_pad, learner.n_local) == (18, 72,
                                                                 n // 4)
         rows = NamedSharding(mesh, P("data"))

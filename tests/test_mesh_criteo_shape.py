@@ -19,8 +19,7 @@ import lightgbm_tpu as lgb
 FEATURES = 67
 _BASE = {"objective": "binary", "num_leaves": 31, "learning_rate": 0.1,
          "max_bin": 255, "min_data_in_leaf": 20,
-         "min_sum_hessian_in_leaf": 1e-3, "verbosity": -1, "metric": "none",
-         "tpu_wave_pallas_partition": "off"}
+         "min_sum_hessian_in_leaf": 1e-3, "verbosity": -1, "metric": "none"}
 _EXACT = ("num_leaves", "split_feature", "threshold", "decision_type",
           "left_child", "right_child", "leaf_count", "internal_count")
 _CLOSE = ("split_gain", "leaf_value", "internal_value")

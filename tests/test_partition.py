@@ -1,123 +1,18 @@
-"""Pallas partition / split-scan kernel parity (round 6).
+"""Split-scan kernel parity, and the record-exactness of the host assembly,
+the rolling flush and the fused replay correction (round 6).
 
-The partition kernel must reproduce the stable sort's permutation BIT-
-EXACTLY (it is default-on on TPU only because of this property), and the
-fused split-scan must match ``find_best_splits`` — exactly on dyadic
+The fused split-scan must match ``find_best_splits`` — exactly on dyadic
 inputs (where every summation order is lossless), to summation-order ulps
-on arbitrary f32.  Off-TPU both kernels run in Pallas interpret mode.
+on arbitrary f32.  Off-TPU the kernel runs in Pallas interpret mode.  (The
+partition's own exactness is ``tests/test_wave.py``'s ``growth_sort`` tests.)
 """
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.partition_pallas import (apply_partition,
-                                               exclusive_cumsum_i32,
-                                               partition_ineligible_reason)
-
-
-def _rand_payload(rng, fw, n):
-    bins = rng.randint(-2**31, 2**31 - 1, size=(fw, n)) \
-        .astype(np.int64).astype(np.int32)
-    w_p = rng.randn(3, n).astype(np.float32)
-    rid = np.arange(n, dtype=np.int32)
-    lid = rng.randint(0, 1000, size=n).astype(np.int32)
-    return bins, w_p, rid, lid
-
-
-def _run_partition(n, windows, seed=0, left_bias=None):
-    """Drive the kernel directly on synthetic split windows; reference is
-    the inverse-permutation gather of the analytically known dests."""
-    rng = np.random.RandomState(seed)
-    w_slots = 8
-    bins, w_p, rid, lid = _rand_payload(rng, 2, n)
-    go_left = rng.rand(n) < (rng.rand() if left_bias is None else left_bias)
-    ps = np.zeros(w_slots, np.int32)
-    cw = np.zeros(w_slots, np.int32)
-    active = np.zeros(w_slots, bool)
-    # scatter the windows over arbitrary member slots (the wave's top-k
-    # order is position-independent — the round-6 walk bug regression)
-    slots = rng.permutation(w_slots)[:len(windows)]
-    gl = np.zeros(n, bool)
-    gr = np.zeros(n, bool)
-    lc = np.zeros(w_slots, np.int32)
-    for slot, (s, c) in zip(slots, windows):
-        ps[slot], cw[slot], active[slot] = s, c, True
-        gl[s:s + c] = go_left[s:s + c]
-        gr[s:s + c] = ~go_left[s:s + c]
-        lc[slot] = gl[s:s + c].sum()
-    mvd = (gl | gr).astype(np.int32)
-    cum = np.asarray(exclusive_cumsum_i32(
-        jnp.asarray(np.stack([gl, gr]).astype(np.int32))))
-    cl, cr = cum[0], cum[1]
-    dest = np.arange(n, dtype=np.int32)
-    for slot, (s, c) in zip(slots, windows):
-        base_l = s - cl[s]
-        base_r = s + lc[slot] - cr[s]
-        seg = slice(s, s + c)
-        dest[seg] = np.where(gl[seg], base_l + cl[seg], base_r + cr[seg])
-    out = apply_partition(
-        jnp.asarray(bins), jnp.asarray(w_p), jnp.asarray(rid),
-        jnp.asarray(lid), jnp.asarray(dest), jnp.asarray(mvd),
-        jnp.asarray(ps), jnp.asarray(lc), jnp.asarray(cw),
-        jnp.asarray(active), jnp.asarray(cl), jnp.asarray(cr),
-        jnp.asarray(cl[ps]), jnp.asarray(cr[ps]), interpret=True)
-    inv = np.zeros(n, np.int64)
-    inv[dest] = np.arange(n)
-    assert np.array_equal(np.asarray(out[0]), bins[:, inv])
-    assert np.array_equal(np.asarray(out[1]).view(np.int32),
-                          w_p[:, inv].view(np.int32))
-    assert np.array_equal(np.asarray(out[2]), rid[inv])
-    assert np.array_equal(np.asarray(out[3]), lid[inv])
-
-
-def test_partition_kernel_windows():
-    _run_partition(2048, [(0, 700), (900, 1000)], seed=1)
-
-
-def test_partition_kernel_whole_array():
-    _run_partition(1024, [(0, 1024)], seed=2)
-
-
-def test_partition_kernel_odd_adjacent():
-    _run_partition(4096, [(1, 1023), (1024, 2048), (3500, 596)], seed=3)
-
-
-def test_partition_kernel_tiny_window():
-    _run_partition(1024, [(100, 3)], seed=4)
-
-
-def test_partition_kernel_empty():
-    _run_partition(1024, [], seed=5)
-
-
-def test_partition_kernel_all_one_side():
-    _run_partition(1024, [(128, 512)], seed=6, left_bias=1.1)
-    _run_partition(1024, [(128, 512)], seed=7, left_bias=-0.1)
-
-
-def test_exclusive_cumsum_exact():
-    rng = np.random.RandomState(0)
-    for n in (512, 2048, 3072):
-        f = (rng.rand(2, n) < 0.3).astype(np.int32)
-        got = np.asarray(exclusive_cumsum_i32(jnp.asarray(f)))
-        assert np.array_equal(got, np.cumsum(f, axis=1) - f)
-
-
-def test_partition_ineligible_reasons():
-    assert partition_ineligible_reason(1 << 20, 1024, 0) is None
-    assert "rows" in partition_ineligible_reason((1 << 24) + 1, 10, 0)
-    assert "slots" in partition_ineligible_reason(1 << 20, 1 << 17, 0)
-    assert "opening" in partition_ineligible_reason(1 << 20, 10, 2)
-
-
-# ---------------------------------------------------------------------------
-# End-to-end: partition-vs-sort record-exact trees (the gate workload
-# shape: small binary train, both learners driven through the Booster).
-# ---------------------------------------------------------------------------
 
 
 def _gate_data(n=2048, f=10, seed=3):
@@ -131,11 +26,8 @@ def _gate_data(n=2048, f=10, seed=3):
 _GATE_PARAMS = {
     "objective": "binary", "num_leaves": 15, "min_data_in_leaf": 5,
     "verbosity": -1, "metric": "none",
-    # shrink the cutoffs so CI-sized windows actually partition
+    # shrink the cutoffs so CI-sized windows actually sort
     "tpu_wave_sort_cutoff": 256, "tpu_sort_cutoff": 128,
-    # partition mode runs without sort-deferral; the baseline must match
-    # the row-accumulation order or member hists drift by ulps
-    "tpu_wave_defer_sorts": False,
 }
 
 
@@ -145,26 +37,6 @@ def _train_text(X, y, params, iters):
     for _ in range(iters):
         bst.update()
     return bst.gbdt.save_model_to_string(), bst
-
-
-def test_partition_record_exact_trees():
-    X, y = _gate_data()
-    s_sort, _ = _train_text(X, y, dict(_GATE_PARAMS,
-                                       tpu_wave_pallas_partition="off"), 2)
-    s_part, b = _train_text(X, y, dict(_GATE_PARAMS,
-                                       tpu_wave_pallas_partition="on"), 2)
-    assert b.gbdt.learner._use_partition
-    assert s_sort == s_part
-
-
-def test_partition_record_exact_with_bagging():
-    X, y = _gate_data(seed=9)
-    p = dict(_GATE_PARAMS, bagging_fraction=0.8, bagging_freq=1)
-    s_sort, _ = _train_text(X, y, dict(p, tpu_wave_pallas_partition="off"),
-                            2)
-    s_part, _ = _train_text(X, y, dict(p, tpu_wave_pallas_partition="on"),
-                            2)
-    assert s_sort == s_part
 
 
 # ---------------------------------------------------------------------------
@@ -257,7 +129,6 @@ def test_split_scan_trains_same_structure():
     import re
     X, y = _gate_data(seed=21)
     p = dict(_GATE_PARAMS)
-    del p["tpu_wave_defer_sorts"]
     s_off, _ = _train_text(X, y, dict(p, tpu_wave_pallas_scan="off"), 2)
     s_on, b = _train_text(X, y, dict(p, tpu_wave_pallas_scan="on"), 2)
     assert b.gbdt.learner._use_scan
