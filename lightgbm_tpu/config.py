@@ -444,11 +444,16 @@ class Config:
     tpu_wave_sort_cutoff: int = 8192
     # level-wise OPENING: the first L tree levels grow with NO row sorting
     # (rows stay in root order; one multi-slot full-pass histogram kernel
-    # serves each level), then a single materialization sort compacts all
-    # windows at once.  A net loss on v5e in the round-5 chip record
-    # (deleted in PR 21; not re-measured): the full-array pass floors at
-    # the one-hot cost regardless of member count, so -1 = auto = DISABLED;
-    # set an explicit L > 0 to force it (exactness tests do)
+    # serves each level) and leave their sort keys pending: the first
+    # growth wave's sort compacts all windows at once.  -1 = auto: 5 levels
+    # where a level's histograms are that one kernel pass (the serial wave
+    # learner on the Pallas path) over at least 2^22 local rows, 0 anywhere
+    # else (CPU, f64, quantized gradients, the sharded learners: there a
+    # level is K full-span scans; under 2^22 rows a sort is cheap).  At
+    # 10.5M rows a full-array sort costs 128.3 ms and a pass over the rows
+    # 21.6 (ledger, PRs 29 and 30); five opened levels leave a 255-leaf
+    # tree two sorts where the sorted ramp pays four (my chip runs, PR 31:
+    # PERF.md section 6).  An explicit L keeps its meaning
     tpu_wave_open_levels: int = -1
     # defer the wave re-compaction sort on alternating waves: a deferring
     # wave assigns logical child windows + sort keys only (member

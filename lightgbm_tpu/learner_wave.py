@@ -1,26 +1,27 @@
 """Frontier-wave TPU tree learner: batched speculative leaf-wise growth.
 
 The sequential compact learner (`learner_compact.py`) builds a tree as 254
-dependent split steps inside one XLA program, which at 1M rows floors on
-per-step bookkeeping and per-window sort latency before any real data work
-(round-5 chip record, deleted in PR 21; not re-measured).  This learner
-restructures the growth into ~13 *frontier waves* while preserving exact
-best-first (leaf-wise) semantics:
+dependent split steps inside one XLA program: per-step bookkeeping and
+per-window sort latency before any real data work.  This learner
+restructures the growth into a few *frontier waves* (nine for 255 leaves at
+10.5M rows) while preserving exact best-first (leaf-wise) semantics:
 
   1. **Grow.**  Each wave splits the top-W positive-gain frontier leaves at
      once: one full-array sort (``growth_sort``, the permutation of a
-     stable sort on the window keys) re-compacts every split window
-     simultaneously (per-row split parameters come from an MXU mask-matmul,
-     never an XLA gather: ~10x slower on the chip, round 5), then the
-     smaller-child histograms run per member (subtraction for siblings) and
-     all 2W children are scanned in one batched split finder.  Replayed
-     against real split sequences, top-W selection reproduces the true
-     greedy split set with ~zero waste in ~12.6 waves
-     (`scratch/wave_sim.py`).
+     stable sort on the window keys; 128.3 ms at 10.5M rows: ledger, PR 29)
+     re-compacts every split window simultaneously, on every second wave
+     (per-row split parameters come from an MXU mask-matmul, never an XLA
+     gather: ``jnp.take`` over 10.5M rows read 86.5 ms; ledger, PR 26,
+     parent side), then the smaller-child histograms run per member
+     (subtraction for siblings) and all 2W children are scanned in one
+     batched split finder.  Where a level's histograms are one multi-slot
+     kernel pass the first levels grow UNSORTED (the opening:
+     ``tpu_wave_open_levels``) and one sort compacts them all.
   2. **Trim.**  An exact greedy replay over the grown forest re-derives the
      reference's pop order (`serial_tree_learner.cpp:185-218`: split the
      globally best leaf, insert its children): children's gains are all
-     known, so the replay is pure bookkeeping — ~6 ms of tiny ops.  The
+     known, so the replay is pure bookkeeping (``phase_replay_ms_per_iter``
+     35 to 43 ms, half of it stall corrections: ledger, PR 30).  The
      replayed pop sequence assigns the reference leaf numbering (left child
      inherits the parent index, right child gets ``num_leaves``), emits the
      host-assembly records in pop order, and maps speculative leaves back
@@ -116,6 +117,55 @@ def _resolve_overshoot(cfg: Config, local_rows: int) -> float:
         else:
             ov = 0.7 if local_rows <= 2_000_000 else 0.25
     return ov
+
+
+# auto depth of the unsorted opening and the local rows from which it pays
+# (my chip runs, PR 31; PERF.md section 6).  Five levels (members 1, 2, 4,
+# 8, 16) leave 32 + 64 + 64 + 63 splits to four waves, which sort, defer,
+# sort, defer: TWO full-array sorts a 255-leaf tree where the sorted ramp
+# pays four.  At 10.5M rows a sort costs 128 ms (12.2 ns a row) and the five
+# multi-slot passes 289; at 1M rows a sort costs 4.6 ns a row, a pass 2.7 as
+# at every size, and the opening loses; 9.0 ns a row at 4.19M (PR 30)
+_AUTO_OPEN_LEVELS = 5
+_AUTO_OPEN_MIN_ROWS = 1 << 22
+
+
+def _resolve_open_levels(cfg: Config, local_rows: int,
+                         multislot: bool) -> int:
+    """``tpu_wave_open_levels`` with -1 = auto (see config.py): an explicit
+    depth keeps its meaning; auto opens ``_AUTO_OPEN_LEVELS`` levels where
+    each level's histograms are ONE multi-slot kernel pass (``multislot``:
+    the serial learner on the Pallas path) over at least
+    ``_AUTO_OPEN_MIN_ROWS`` local rows, and none anywhere else (the fallback
+    pays K full-span scans a level; a sort over few rows is cheap)."""
+    ol = int(cfg.tpu_wave_open_levels)
+    if ol >= 0:
+        return ol
+    if multislot and local_rows >= _AUTO_OPEN_MIN_ROWS:
+        return _AUTO_OPEN_LEVELS
+    return 0
+
+
+def _segment_grid_buckets(capacity: int, width: int, opened: bool) -> list:
+    """Grid sizes (chunk capacities) the segment kernel is built for at one
+    call site, largest first: late waves have few real chunks, so the call
+    picks the smallest that holds them and no-op grid cells don't dominate.
+    The ladder halves from ``capacity`` down to ``2 * width``.  A program
+    that opens the ramp (``opened``) keeps no bucket under 256 chunks:
+    every bucket is one more trace and lowering of the kernel in the job's
+    first iteration, and the opening's five bodies have to be paid for
+    there (``setup_s``: PERF.md section 6, PR 31).  Every other program
+    (CPU, sharded, under 2^22 rows) keeps the whole ladder."""
+    floor = 2 * width
+    if opened:
+        floor = min(max(floor, 256), capacity)
+    sizes = []
+    cap = capacity
+    while cap > floor:
+        sizes.append(cap)
+        cap //= 2
+    sizes.append(max(floor, cap))
+    return sizes
 
 
 class WaveState(NamedTuple):
@@ -310,18 +360,6 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         self.grow_budget = min(
             self.budget + int(np.ceil(self.budget * ov)),
             2 * self.budget)
-        # level-wise opening depth (see Config.tpu_wave_open_levels).
-        # MEASURED on the v5e (round 5, an opening sweep since deleted + a
-        # device trace): a full-array multi-slot hist pass floors at ~6 ms
-        # of one-hot VPU work regardless of K, so an opening level costs
-        # ~8-18 ms against the ~10.6 ms wave it replaces, plus a ~6 ms
-        # materialization sort — a NET LOSS at every depth on the bench
-        # workload.  Auto therefore DISABLES the opening; the knob remains
-        # for exactness tests and future kernels that beat the floor.
-        ol = int(getattr(cfg, "tpu_wave_open_levels", -1))
-        if ol < 0:
-            ol = 0
-        self.open_levels = max(0, min(ol, (self.budget + 1).bit_length() - 1))
         # sort-deferral alternation (Config.tpu_wave_defer_sorts)
         self._defer_sorts = bool(getattr(cfg, "tpu_wave_defer_sorts", True))
         # replay stall-correction batch width (Config.tpu_wave_stall_batch)
@@ -365,6 +403,11 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 q_reason = "tpu_quantized_grad=%s (quantization is " \
                            "opt-in)" % qg
         self._quant_reason = None if self._quant else q_reason
+        # level-wise opening depth (Config.tpu_wave_open_levels)
+        self.open_levels = min(
+            _resolve_open_levels(cfg, rows, self._multislot_opening()
+                                 and not self._quant),
+            (self.budget + 1).bit_length() - 1)
         self._q_inv = None
         self._q_scales = None
         self._q_raw = None
@@ -657,10 +700,11 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         configured W).  ``opening=True`` runs the wave in LEVEL-OPENING
         mode: no sort executes — every valid member's children get distinct
         LOGICAL windows and their rows get the matching sort keys, so a
-        single later materialization sort (``_materialize_sort``) compacts
-        all opening levels at once; member histograms run as full-array
-        lid-masked passes (``_opening_hists``) since no window is
-        physically contiguous yet."""
+        single later sort (the first growth wave's, or
+        ``_materialize_sort`` without deferral) compacts all opening levels
+        at once; member histograms run as full-array lid-masked passes
+        (``_opening_hists``) since no window is physically contiguous
+        yet."""
         W = width or self.W
         M, n = self.M, self._rows_len()
         fw = self.fw
@@ -693,8 +737,8 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             # members at or below the wave cutoff split in place (lid rewrite,
             # children share the parent span); only keyed members' rows get new
             # window keys.  Opening mode keys EVERY valid member (children get
-            # logical windows now, physical compaction happens at the deferred
-            # materialization sort); normal mode keys the members it sorts
+            # logical windows now, physical compaction happens at the next
+            # sort); normal mode keys the members it sorts
             if opening:
                 sortable = valid
             else:
@@ -835,7 +879,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                      st.key_p.reshape(Cm, ch))).reshape(-1)
             # ---- ONE ``growth_sort`` re-compacts every sortable split window.
             # Skipped when the whole wave froze (the tree's bottom waves), when
-            # opening mode defers ALL compaction to the materialization sort,
+            # opening mode defers ALL compaction to the next sort,
             # and — under sort-deferral alternation — on every wave without a
             # PENDING key set: a deferring wave only assigns logical windows +
             # keys, and the NEXT wave's single sort materializes both levels.
@@ -1006,18 +1050,24 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
              valid))
         return pool, hl, hr
 
+    def _multislot_opening(self) -> bool:
+        """Whether an opening level's histograms are one multi-slot kernel
+        pass: the Pallas path and the serial member-histogram seam (the
+        sharded subclasses exchange through their own)."""
+        return self._use_pallas and type(self)._wave_member_hists is \
+            WaveTPUTreeLearner._wave_member_hists
+
     def _opening_hists(self, st: WaveState, sm_slot, valid, ph, lh_w, rh_w,
                        left_small):
         """Smaller-child histograms for one OPENING level: rows are still
         in root order (no sort has run), so the segment kernel's chunk walk
         cannot apply.  Serial TPU: ONE multi-slot full pass
-        (`ops/hist_pallas.py:build_histogram_multislot`) — the bin one-hot
-        is built once and shared across the K members.  Fallback (CPU /
+        (`ops/hist_pallas.py:build_histogram_multislot`), each row routed
+        to its member's slot, or to none.  Fallback (CPU /
         f64 / sharded subclasses): per-member full-span lid-masked scans
         through the regular member-hist seam, which keeps the sharded
         psum_scatter exchange intact."""
-        if self._use_pallas and type(self)._wave_member_hists is \
-                WaveTPUTreeLearner._wave_member_hists:
+        if self._multislot_opening():
             from .ops.hist_pallas import build_histogram_multislot
             K = sm_slot.shape[0]
             sl = jnp.where(valid, sm_slot, -1)
@@ -1045,14 +1095,17 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             jnp.full_like(sm_slot, n), valid, ph, lh_w, rh_w, left_small)
 
     def _materialize_sort(self, st: WaveState) -> WaveState:
-        """One full-array ``growth_sort`` on the window keys assigned by the
-        opening levels: every leaf's rows land contiguously at its logical
+        """One full-array ``growth_sort`` on pending window keys (a
+        deferring wave's before the K=1 replay; the opening levels' where
+        deferral is off): every leaf's rows land contiguously at its logical
         window (keys are 2×(window start), strictly increasing with
         position — the invariant the per-wave sorts maintain), after which
         the regular wave flow's physical-window machinery applies."""
         with scope("partition"):
             key_p, bins_p, w_p, rid_p, lid_p = growth_sort(
                 st.key_p, st.bins_p, st.w_p, st.rid_p, st.lid_p, self.M)
+            if st.telem is not None:
+                st = st._replace(telem=st.telem.at[TEL_WAVE_SORTS].add(1))
             return st._replace(
                 key_p=key_p, bins_p=bins_p, w_p=w_p, rid_p=rid_p,
                 lid_p=lid_p, phys_i=st.node_i, pending=jnp.asarray(False))
@@ -1094,15 +1147,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         block_t = jnp.where(tpos < total, first_blk[mem]
                             + (tpos - starts[mem]), 0).astype(jnp.int32)
         leaf_t = jnp.where(tpos < total, leaf_of[mem], -1).astype(jnp.int32)
-        # grid-size buckets: late waves have few real chunks — pick the
-        # smallest capacity that holds them so no-op grid cells don't
-        # dominate
-        Ts = []
-        tcap = T
-        while tcap > 2 * W:
-            Ts.append(tcap)
-            tcap //= 2
-        Ts.append(max(2 * W, tcap))
+        Ts = _segment_grid_buckets(T, W, self.open_levels > 0)
 
         def make_branch(Ti):
             def branch(s_t, b_t, l_t, bins_p, w_p, lid_p):
@@ -1133,7 +1178,10 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         Selection is identical (top-k of the same gain order, same budget
         guard), so the grown forest is exactly the same."""
         ws = min(8, self.W)
-        if ws >= self.W:
+        if ws >= self.W or (1 << self.open_levels) > ws:
+            # (an opened ramp leaves the narrow body a tree's exhausted
+            # bottom alone, and a second body is a second trace of every
+            # kernel in it in the job's first iteration)
             return self._wave_body(st, feature_mask)
         small = jnp.sum(self._pool_gains(st) > 0.0) <= ws
         return lax.cond(
@@ -1761,14 +1809,22 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         with scope("root"):
             st = self._init_root_wave(bins_p, grad, hess, bag, feature_mask)
         # level-wise opening: the first L levels grow unsorted (level d has
-        # at most 2^d members), then ONE materialization sort compacts
-        # every window; a level with nothing to split is an exact no-op
+        # at most 2^d leaves to split) and leave their keys PENDING, as a
+        # deferring wave does: the first growth wave's sort (``sort_now =
+        # st.pending``) compacts every level's windows with its own.  Without
+        # deferral nothing downstream sorts a pending key set, so the
+        # opening materializes itself.  A level with nothing to split is an
+        # exact no-op
         with scope("opening"):
+            # (a body a level: sharing one body cost more on the device
+            # than its traces cost the host, through a ``fori_loop``'s
+            # carries, 27 ms a tree, or an inner jit's call boundaries, 42:
+            # my chip runs, PR 31, calls 38 and 40)
             for d in range(self.open_levels):
                 st = self._wave_body(st, feature_mask,
                                      width=min(1 << d, self.W),
                                      opening=True)
-            if self.open_levels > 0:
+            if self.open_levels > 0 and not self._defer_sorts:
                 st = lax.cond(st.pending, self._materialize_sort,
                               lambda s: s, st)
 

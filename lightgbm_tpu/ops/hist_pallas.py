@@ -443,7 +443,7 @@ def _hist_kernel_segment(slot_ref, block_ref, leaf_ref, bins_ref, w_ref,
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "n_slots",
                                              "word_tile", "row_block",
-                                             "nterms", "radix", "quant",
+                                             "nterms", "quant",
                                              "interpret"))
 def build_histogram_segments(bins_words: jax.Array, w: jax.Array,
                              lid: jax.Array, chunk_slot: jax.Array,
@@ -514,20 +514,91 @@ def build_histogram_segments(bins_words: jax.Array, w: jax.Array,
 # The first tree levels run UNSORTED (rows stay in root order, only the
 # per-row leaf-id lane advances), so the segment kernel's chunk walk — which
 # needs each member's rows physically contiguous — cannot serve them.  This
-# kernel histograms K leaves in ONE pass over the full row axis: the bin
-# one-hot (the VPU-bound part, built once per packed word exactly as in
-# ``build_histogram_packed``) is SHARED across slots, and slot routing rides
-# the weight operand — a cheap (K, Rb) slot one-hot multiplied into the bf16
-# weight terms, so the MXU contraction per word becomes
-# ``(K·3·nterms, Rb) × (Rb, 4·B)``.  FLOPs scale with K, which keeps the
-# kernel MXU-cheap for the opening's K ≤ 16 members while the one-hot cost
-# stays that of a single pass.
+# kernel histograms K leaves in ONE pass over the full row axis.  Two
+# formulations.  PLAIN (``tpu_hist_precision=highest``, or a K with no
+# split): the 256-wide bin one-hot of ``build_histogram_packed``'s plain
+# branch is built once and shared across slots, and slot routing rides the
+# weight operand, ``(K·3·nterms, Rb) × (Rb, 4·B)`` a word; its one-hot alone
+# costs 60 ms a pass at 10.5M rows x 8 words whatever K is, against the
+# 21.6 ms of the radix root pass (my chip runs, PR 31: 60.0 / 64.7 / 71.5 /
+# 87.5 / 143.7 / 334.6 ms at K = 1 / 2 / 4 / 8 / 16 / 32).  RADIX (the
+# default, as in the other two kernels): ``_radix_word_slots`` splits the
+# slot index as ``_radix_word`` splits the bin, low part on the column side
+# with the 32-wide lo one-hot, high part on the weight side with the hi
+# one-hot: 27.6 / 32.7 / 31.1 / 52.6 / 102.5 / 202.1 ms at the same K with
+# the words unrolled (call 26), 36 / 42 / 40 / 63 / 116 to K = 16 looped.
 # ---------------------------------------------------------------------------
+
+
+def _radix_word_slots(wt_g, word, cs, rb: int, bp: int, nterms: int,
+                      n_col: int, quant: bool = False):
+    """``_radix_word`` for K = G x C slots: one packed word's partials of
+    every slot.  The slot index splits as the bin does: its low part ``cs``
+    (C = ``n_col`` values, at most 4) rides the COLUMN side with the 32-wide
+    lo one-hot (column 32 x cs + lo), its high part the weight side
+    (``wt_g[g]``: the bf16 terms of the rows of row group g, zero
+    elsewhere), stacked with the hi one-hot.  A dot takes as many
+    sub-features as fill its 128 columns (4 // C), so no product is wasted
+    once C = 4 and K = 1 is ``_radix_word`` itself: the MXU streams
+    ``G x 224`` rows a word and the VPU builds ``128 x C`` one-hot rows,
+    where the 256-wide one-hot of the plain formulation costs 1024 whatever
+    K is.  Returns ``[(c, sub-feature, (G, 3, HI, 32))]``: the block of the
+    G slots ``c x G .. c x G + G`` in the kernel's COLUMN-MAJOR slot order
+    (the wrapper puts the slots back in order)."""
+    nt = wt_g[0].shape[0]
+    n_grp = len(wt_g)
+    hi_n = bp // 32
+    per_dot = 4 // n_col                     # sub-features a dot
+    width = 32 * n_col                       # columns a sub-feature
+    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (hi_n, rb), 0)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (width, rb), 0)
+    outs = []
+    for d in range(n_col):
+        a_parts, lo_parts = [], []
+        for s in range(d * per_dot, (d + 1) * per_dot):
+            code = (word >> (8 * s)) & 0xFF
+            hi_oh = ((code >> 5)[None, :] == iota_hi).astype(jnp.bfloat16)
+            col = (code & 31) + (cs << 5)
+            lo_parts.append((col[None, :] == iota_c).astype(jnp.bfloat16))
+            for wt in wt_g:
+                a_parts.append((hi_oh[None, :, :] * wt[:, None, :])
+                               .reshape(nt * hi_n, rb))
+        a = jnp.concatenate(a_parts, axis=0)    # (per_dot*G*nt*HI, Rb)
+        lo = jnp.concatenate(lo_parts, axis=0)  # (128, Rb)
+        part = jax.lax.dot_general(
+            a, lo, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # leading split only: the 128 lanes stay whole until sliced
+        p5 = part.reshape(per_dot, n_grp, nt, hi_n, 128)
+        if quant:
+            g, h = p5[:, :, 0], p5[:, :, 1]
+            acc = jnp.stack([g, h, h], axis=2)
+        else:
+            g, h = p5[:, :, 0], p5[:, :, nterms]
+            for t in range(1, nterms):
+                g = g + p5[:, :, t]
+                h = h + p5[:, :, nterms + t]
+            acc = jnp.stack([g, h, p5[:, :, 2 * nterms]], axis=2)
+        for i in range(per_dot):                # (G, 3, HI, 128) each
+            for c in range(n_col):
+                c0 = i * width + c * 32
+                outs.append((c, d * per_dot + i, acc[i, :, :, :, c0:c0 + 32]))
+    return outs
+
+
+def _multislot_split(n_slots: int):
+    """(column slots C, row groups G) of ``_radix_word_slots``: C x G =
+    ``n_slots`` with C a power of two up to 4; None where there is no such
+    split (the plain formulation serves)."""
+    if n_slots in (1, 2):
+        return n_slots, 1
+    return (4, n_slots // 4) if n_slots % 4 == 0 else None
 
 
 def _hist_kernel_multislot(bins_ref, w_ref, slot_ref, out_ref, *,
                            num_bins_padded: int, word_tile: int, nterms: int,
-                           n_slots: int, quant: bool = False):
+                           n_slots: int, radix: bool = False,
+                           quant: bool = False):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -538,6 +609,30 @@ def _hist_kernel_multislot(bins_ref, w_ref, slot_ref, out_ref, *,
     slot_blk = slot_ref[...]    # (Rb,) int32; >= n_slots means masked
     rb = w_blk.shape[1]
     bp = num_bins_padded
+    if radix:
+        n_col, n_grp = _multislot_split(n_slots)
+        wt = _expand_terms_quant(w_blk) if quant \
+            else _expand_terms_mixed(w_blk, nterms)
+        grp = slot_blk >> (n_col.bit_length() - 1)
+        # a row outside [0, n_slots) falls in no row group
+        wt_g = [jnp.where((grp == g)[None, :], wt, jnp.zeros_like(wt))
+                for g in range(n_grp)]
+        cs = slot_blk & (n_col - 1)
+        hi_n = bp // 32
+
+        # a loop, not an unrolled body: the words' code is the same, and a
+        # kernel of 8 unrolled words costs the host 8 times the lowering in
+        # every job's first iteration, compile cache or not
+        def one_word(wd, carry):
+            for c, s, acc in _radix_word_slots(
+                    wt_g, bins_ref[wd, :], cs, rb, bp, nterms, n_col,
+                    quant=quant):
+                out_ref[wd, c * n_grp:(c + 1) * n_grp, :,
+                        s * hi_n:(s + 1) * hi_n, :] += acc
+            return carry
+
+        jax.lax.fori_loop(0, word_tile, one_word, 0)
+        return
     iota_s = jax.lax.broadcasted_iota(jnp.int32, (n_slots, rb), 0)
     soh = slot_blk[None, :] == iota_s                      # (K, Rb) bool
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (bp, rb), 0)
@@ -595,26 +690,43 @@ def build_histogram_multislot(bins_words: jax.Array, w: jax.Array,
     fw, n = bins_words.shape
     word_tile, rb, b_pad = _tile_params(fw, n, word_tile, row_block,
                                         num_bins)
+    split = _multislot_split(n_slots)
+    radix = (nterms > 0 or quant) and split is not None
     grid = (fw // word_tile, n // rb)
+    if radix:
+        # the 32-lane (..., HI, 32) layout of the other radix kernels
+        hi_n = b_pad // 32
+        out_specs = pl.BlockSpec((word_tile, n_slots, 3, 4 * hi_n, 32),
+                                 lambda i, j: (i, 0, 0, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((fw, n_slots, 3, 4 * hi_n, 32),
+                                         jnp.float32)
+    else:
+        out_specs = pl.BlockSpec((word_tile, n_slots, 3, 4 * b_pad),
+                                 lambda i, j: (i, 0, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((fw, n_slots, 3, 4 * b_pad),
+                                         jnp.float32)
     out = pl.pallas_call(
         functools.partial(_hist_kernel_multislot, num_bins_padded=b_pad,
                           word_tile=word_tile, nterms=nterms,
-                          n_slots=n_slots, quant=quant),
+                          n_slots=n_slots, radix=radix, quant=quant),
         grid=grid,
         in_specs=[
             pl.BlockSpec((word_tile, rb), lambda i, j: (i, j)),
             pl.BlockSpec((3, rb), lambda i, j: (0, j)),
             pl.BlockSpec((rb,), lambda i, j: (j,)),
         ],
-        out_specs=pl.BlockSpec((word_tile, n_slots, 3, 4 * b_pad),
-                               lambda i, j: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((fw, n_slots, 3, 4 * b_pad),
-                                       jnp.float32),
+        out_specs=out_specs,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="build_histogram_multislot",
     )(bins_words, w, slot)
+    if radix:
+        # the kernel's slot c x G + g is slot g x C + c
+        n_col, n_grp = split
+        out = out.reshape(fw, n_col, n_grp, 3, 4 * hi_n, 32) \
+            .transpose(0, 2, 1, 3, 4, 5)
     # (fw, K, 3, 4, B) -> (K, fw*4, B, 3)
     out = out.reshape(fw, n_slots, 3, 4, b_pad) \
         .transpose(1, 0, 3, 4, 2).reshape(n_slots, fw * 4, b_pad, 3)

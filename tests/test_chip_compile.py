@@ -102,12 +102,24 @@ def test_segment_histogram_compiles(one_chip):
         nterms=3), one_chip, _BINS, _W3, _ROW_I, chunk, chunk, chunk)
 
 
-def test_multislot_histogram_compiles(one_chip):
-    """Level-wise opening pass (`learner_wave._opening_hists`, off by
-    default: tpu_wave_open_levels auto = 0) at a level-3 width."""
+@pytest.mark.parametrize("rows", [ROWS, HIGGS_ROWS])
+@pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16])
+def test_multislot_histogram_compiles(one_chip, n_slots, rows):
+    """The opening's pass (`learner_wave._opening_hists`) at every width
+    the auto depth of five levels uses, at 2^20 rows and at the benchmark's
+    own 10,500,096 (a size only the cells reach hid PR 30's SMEM fault)."""
     from lightgbm_tpu.ops.hist_pallas import build_histogram_multislot
     _compile(lambda b, w, s: build_histogram_multislot(
-        b, w, s, num_bins=B, n_slots=8, row_block=2048, nterms=3),
+        b, w, s, num_bins=B, n_slots=n_slots, row_block=2048, nterms=3),
+        one_chip, ((FW, rows), jnp.int32), ((3, rows), jnp.float32),
+        ((rows,), jnp.int32))
+
+
+def test_multislot_histogram_plain_formulation_compiles(one_chip):
+    """What `tpu_hist_precision=highest` and a width with no split run."""
+    from lightgbm_tpu.ops.hist_pallas import build_histogram_multislot
+    _compile(lambda b, w, s: build_histogram_multislot(
+        b, w, s, num_bins=B, n_slots=8, row_block=2048, nterms=0),
         one_chip, _BINS, _W3, _ROW_I)
 
 
@@ -149,8 +161,10 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
     the learner steered onto its TPU branch: every ``sort`` (the partition's
     among them) and every Mosaic call of the compiled program
     sits under one of the program's phase scopes, every kernel carries its
-    pinned name, and the phases of a tree all occur (the opening only with
-    ``tpu_wave_open_levels``, which the default path leaves at 0)."""
+    pinned name, and the phases of a tree all occur, the opening among them
+    (auto resolves to its depth on this branch, the row floor lowered to the
+    test's size: three levels fit 15 leaves) with its multi-slot kernel and
+    no sort of its own: its keys wait for the first growth wave's."""
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -159,6 +173,7 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
     from lightgbm_tpu.ops import histogram, lookup
     for mod in (histogram, learner_compact, learner_wave, lookup):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(learner_wave, "_AUTO_OPEN_MIN_ROWS", 8192)
     rng = np.random.RandomState(0)
     X = rng.randn(8192, F)
     y = (X[:, 0] > 0).astype(float)
@@ -168,6 +183,7 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
         g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).gbdt
         learner = g.learner
         assert learner._use_pallas and learner._use_scan
+        assert learner.open_levels == 3
         args = (g.train_score.score, learner.bins_packed(), g._bag_mask,
                 g._feature_sample(), jnp.float32(0.1))
         shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -187,12 +203,19 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
             assert line.strip().lstrip("%").startswith(tuple(kernel)), line
             kernels |= kernel
     assert kernels == {"build_histogram_packed", "build_histogram_segments",
+                       "build_histogram_multislot",
                        "find_best_splits_batched"}
     assert any(re.search(r"[ )]sort\(", line) and "/grow/" in op_name
                and "/partition/" in op_name for line, op_name in named)
+    # (the opening's only sorts are its selection's small top-k)
+    assert not any(re.search(r"[ )]sort\(", line) and "/opening/" in op_name
+                   and "/partition/" in op_name for line, op_name in named)
+    assert all("/opening/" in op_name and "/hist/" in op_name
+               for line, op_name in named
+               if "build_histogram_multislot" in op_name)
     seen = {p for _, op_name in named for p in op_name.split("/")}
-    assert {"root", "grow", "replay", "emit", "hist", "scan", "partition",
-            "stall"} <= seen
+    assert {"root", "opening", "grow", "replay", "emit", "hist", "scan",
+            "partition", "stall"} <= seen
     for phase in ("gradients", "score_update"):
         assert f"/{phase}/" in text
     # the score update reads its leaf values by contraction, not by gather

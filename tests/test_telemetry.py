@@ -81,6 +81,25 @@ def test_report_schema_smoke(rng):
     assert rep["collectives"]["sites"] == []
 
 
+def test_materialisation_sort_is_counted(rng):
+    """``wave_sorts`` counts the opening's one materialisation sort with the
+    waves' own: at 2,048 rows every window is under the sort cut-off, so a
+    tree without the opening sorts nothing and a tree with it sorts once."""
+    X, y = _problem(rng)
+    sorts = {}
+    for levels in (0, 2):
+        params = dict(_BASE, telemetry=True, tpu_learner="wave",
+                      tpu_wave_open_levels=levels)
+        bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+        for _ in range(2):
+            bst.update()
+        assert bst.gbdt.learner.open_levels == levels
+        c = bst.get_telemetry()["counters"]
+        assert c["trees_measured"] == 2
+        sorts[levels] = c["wave_sorts"]
+    assert sorts == {0: 0, 2: 2}
+
+
 def test_disabled_report_is_inert(rng):
     X, y = _problem(rng)
     ds = lgb.Dataset(X, label=y, params=dict(_BASE))

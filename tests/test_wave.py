@@ -183,29 +183,112 @@ def test_segment_hist_kernel_interpret():
                                            rtol=1e-5, atol=1e-4)
 
 
-def test_wave_opening_first_tree_bit_exact():
+@pytest.mark.parametrize("params,rows,multislot,want", [
+    ({}, 10_500_096, True, "auto"),             # serial, Pallas: the cells
+    ({}, 1 << 22, True, "auto"),
+    ({}, (1 << 22) - 1024, True, 0),            # a sort is cheap there
+    ({}, 1_000_448, True, 0),
+    ({}, 10_500_096, False, 0),                 # CPU, f64, sharded seams
+    ({"tpu_wave_open_levels": 0}, 10_500_096, True, 0),     # explicit: kept
+    ({"tpu_wave_open_levels": 2}, 4096, True, 2),
+    ({"tpu_wave_open_levels": 6}, 4096, False, 6),
+])
+def test_open_levels_auto_rule(params, rows, multislot, want):
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner_wave import (_AUTO_OPEN_LEVELS,
+                                           _resolve_open_levels)
+    assert _AUTO_OPEN_LEVELS == 5
+    got = _resolve_open_levels(Config.from_params(params), rows, multislot)
+    assert got == (_AUTO_OPEN_LEVELS if want == "auto" else want)
+
+
+@pytest.mark.parametrize("capacity,width,opened,want", [
+    # the Higgs cells' growth waves: 10,500,096 rows in blocks of 2048
+    (5576, 64, False, [5576, 2788, 1394, 697, 348, 174, 128]),
+    (5576, 64, True, [5576, 2788, 1394, 697, 348, 256]),
+    (5144, 8, True, [5144, 2572, 1286, 643, 321, 256]),    # corrections
+    (5144, 8, False, [5144, 2572, 1286, 643, 321, 160, 80, 40, 20, 16]),
+    (191, 63, True, [191]),                 # under the floor: one grid
+    (191, 63, False, [191, 126]),
+    (512, 200, True, [512, 400]),           # 2W over the floor: 2W rules
+])
+def test_segment_grid_buckets(capacity, width, opened, want):
+    """The segment kernel's grid sizes at a call site: the parent's ladder
+    down to 2W wherever the ramp is not opened (every sharded learner, the
+    CPU, small inputs), none under 256 chunks where it is."""
+    from lightgbm_tpu.learner_wave import _segment_grid_buckets
+    assert _segment_grid_buckets(capacity, width, opened) == want
+
+
+@pytest.mark.parametrize("learner,opens", [
+    ("serial-cpu", False), ("serial-tpu", True), ("serial-tpu-quant", False),
+    ("serial-tpu-f64", False), ("data4", False)])
+def test_open_levels_auto_by_learner(learner, opens, monkeypatch):
+    """Auto opens the ramp only where ``_opening_hists`` takes the
+    multi-slot kernel: the serial learner steered onto its TPU branch, not
+    on the CPU, not with quantized gradients (no chip reading), not with
+    float64 histograms, and not in a sharded learner, steered or not."""
+    from lightgbm_tpu import learner_compact, learner_wave
+    from lightgbm_tpu.ops import histogram
+    if learner == "data4" and len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    if learner != "serial-cpu":
+        for mod in (histogram, learner_compact, learner_wave):
+            monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    extra = {"serial-tpu-quant": {"tpu_quantized_grad": "on"},
+             "serial-tpu-f64": {"gpu_use_dp": True},
+             "data4": {"tree_learner": "data", "parallel_mesh": "4"}}
+    # the rule's row floor, lowered to the test's size
+    monkeypatch.setattr(learner_wave, "_AUTO_OPEN_MIN_ROWS", 4096)
+    X, y = _make(n=4096)
+    params = dict(_pair("shipped", num_leaves=255)[1],
+                  **extra.get(learner, {}))
+    if learner == "data4":
+        del params["tpu_learner"]       # the factory's choice under a mesh
+    g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).gbdt
+    assert isinstance(g.learner, WaveTPUTreeLearner)
+    assert g.learner.open_levels == (
+        learner_wave._AUTO_OPEN_LEVELS if opens else 0)
+    assert g.learner._multislot_opening() == (
+        learner in ("serial-tpu", "serial-tpu-quant"))
+
+
+# the depth auto resolves to on the chip, and one less
+_OPEN_DEPTHS = [5, 4]
+
+
+@pytest.mark.parametrize("defer", [False, True])
+@pytest.mark.parametrize("levels", _OPEN_DEPTHS)
+def test_wave_opening_first_tree_bit_exact(levels, defer):
     """Opening vs no-opening, ONE boosting round: the first iteration's
     gradients are dyadic rationals (grad ±0.5, hess 0.25 at score 0 —
     boost_from_average off), so f32 histogram sums are EXACT in any
-    summation order — the two flows must emit bit-identical models."""
+    summation order — the two flows must emit bit-identical models.  Without
+    deferral the opening ends in its own materialisation sort; with it the
+    levels' keys stay pending into the first growth wave's sort (and, under
+    the reference base's ``stall_batch=1``, into the sort before the
+    replay where no wave follows)."""
     X, y = _make()
     _, pb = _pair(boost_from_average=False)
-    p_open = dict(pb, tpu_wave_open_levels=5)
+    p_open = dict(pb, tpu_wave_open_levels=levels,
+                  tpu_wave_defer_sorts=defer)
     a = _train(pb, X, y, rounds=1)
     b = _train(p_open, X, y, rounds=1)
     assert isinstance(b.gbdt.learner, WaveTPUTreeLearner)
-    assert b.gbdt.learner.open_levels > 0
+    assert b.gbdt.learner.open_levels == 4     # 31 leaves hold 4 levels
+    assert b.gbdt.learner._defer_sorts == defer
     assert a.model_to_string() == b.model_to_string()
 
 
-def test_wave_opening_matches_no_opening():
+@pytest.mark.parametrize("levels", _OPEN_DEPTHS)
+def test_wave_opening_matches_no_opening(levels):
     """Multi-round: behaviorally equivalent models (opening changes the f32
     histogram summation ORDER for the first levels, so a near-tie split can
     legitimately flip by one bin in later trees — the first-tree test above
     pins exactness where sums are exact)."""
     X, y = _make()
     _, pb = _pair()
-    p_open = dict(pb, tpu_wave_open_levels=5)
+    p_open = dict(pb, tpu_wave_open_levels=levels)
     a = _train(pb, X, y, rounds=5)
     b = _train(p_open, X, y, rounds=5)
     a.model_to_string(), b.model_to_string()
@@ -215,7 +298,8 @@ def test_wave_opening_matches_no_opening():
                                atol=2e-3)
 
 
-def test_wave_opening_with_default_cutoffs_and_bagging():
+@pytest.mark.parametrize("levels", _OPEN_DEPTHS)
+def test_wave_opening_with_default_cutoffs_and_bagging(levels):
     """Opening under the DEFAULT sort cutoffs + bagging + feature_fraction
     (the bench configuration's flow) stays structurally identical to the
     sequential compact learner."""
@@ -224,17 +308,38 @@ def test_wave_opening_with_default_cutoffs_and_bagging():
                    feature_fraction=0.8)
     del pa["tpu_sort_cutoff"], pa["tpu_wave_sort_cutoff"]
     del pb["tpu_sort_cutoff"], pb["tpu_wave_sort_cutoff"]
-    pb["tpu_wave_open_levels"] = 5
+    pb["tpu_wave_open_levels"] = levels
     _models_equal(pa, pb, X, y, exact=False)
 
 
-def test_wave_opening_deep_tree_and_tiny_budget():
+@pytest.mark.parametrize("leaves,levels", [(63, 5), (63, 4), (16, 4)])
+def test_wave_opening_on_the_shipped_options(leaves, levels):
+    """What the cells run since PR 31: the opened levels, then sort
+    deferral, batched stall corrections and the default cut-offs (no
+    ``tpu_wave_*`` override but the depth, which the CPU's auto leaves at
+    0).  63 leaves grow on after the opening, so its pending keys meet the
+    first wave's sort; 16 leaves are spent inside four levels, so the
+    replay corrects on rows that never moved: the compact learner's
+    structure either way, values to float tolerance."""
+    X, y = _make()
+    pa, pb = _pair("shipped", num_leaves=leaves,
+                   tpu_wave_open_levels=levels, bagging_fraction=0.7,
+                   bagging_freq=1, bagging_seed=5)
+    _, b = _models_equal(pa, pb, X, y, rounds=3, exact=False,
+                         thresholds=False)
+    learner = b.gbdt.learner
+    assert learner._defer_sorts and learner._stall_batch > 1
+    assert learner.open_levels == min(levels, leaves.bit_length() - 1)
+
+
+@pytest.mark.parametrize("levels", _OPEN_DEPTHS)
+def test_wave_opening_deep_tree_and_tiny_budget(levels):
     # budget smaller than a full opening (num_leaves=4 -> 2 levels), and a
     # deeper-than-opening tree; both must replay to exact best-first
     X, y = _make(n=6000)
     for leaves in (4, 88):
         _, pb = _pair(num_leaves=leaves)
-        p_open = dict(pb, tpu_wave_open_levels=5)
+        p_open = dict(pb, tpu_wave_open_levels=levels)
         a = _train(pb, X, y, rounds=2)
         b = _train(p_open, X, y, rounds=2)
         a.model_to_string(), b.model_to_string()
@@ -319,6 +424,149 @@ def test_multislot_hist_kernel_interpret():
                                                **tol)
             np.testing.assert_array_equal(
                 out[k, :, :, 2], np.rint(out[k, :, :, 2]))  # counts exact
+
+
+@pytest.mark.parametrize("bins", [256, 64])
+@pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16, 12, 3])
+def test_multislot_radix_formulation_is_exact(n_slots, bins):
+    """The two-level formulation of the multi-slot pass (bin = 32 hi + lo,
+    slot = 4 row group + column slot: ``_radix_word_slots``) against a
+    bincount on weights that one bfloat16 term holds exactly, so that
+    interpret mode is exact too: every width an opening level uses (16 is
+    the widest of auto's five), one that is no power of two, one with no
+    split (3: the plain formulation),
+    rows outside [0, n_slots) contributing nowhere."""
+    from lightgbm_tpu.ops.hist_pallas import (_multislot_split,
+                                              build_histogram_multislot,
+                                              pack_bin_words)
+
+    assert (_multislot_split(n_slots) is None) == (n_slots == 3)
+    rng = np.random.RandomState(5 + n_slots)
+    n, f = 2048, 8
+    codes = rng.randint(0, bins - 1, (f, n)).astype(np.uint8)
+    bag = (rng.rand(n) < 0.7).astype(np.float32)
+    w = np.stack([rng.randint(-8, 9, n).astype(np.float32) * bag * 0.5,
+                  rng.randint(0, 9, n).astype(np.float32) * bag * 0.25, bag])
+    slot = rng.randint(-1, n_slots + 2, n).astype(np.int32)
+    out = np.asarray(build_histogram_multislot(
+        pack_bin_words(jnp.asarray(codes)), jnp.asarray(w),
+        jnp.asarray(slot), num_bins=bins, n_slots=n_slots, row_block=512,
+        nterms=3, interpret=True))
+    assert out.shape == (n_slots, f, bins, 3)
+    for k in range(n_slots):
+        m = (slot == k).astype(np.float64)
+        for fi in range(f):
+            for ch in range(3):
+                ref = np.bincount(codes[fi], weights=w[ch] * m,
+                                  minlength=bins)[:bins]
+                np.testing.assert_array_equal(out[k, fi, :, ch], ref)
+
+
+# the cells' precision (three bfloat16 terms: the RADIX formulation of the
+# multi-slot kernel) and full float32 (its PLAIN formulation).  A first
+# tree's gradients (+-0.5, 0.25, the bag's 0 / 1) fit one bfloat16 term, so
+# interpret mode, which keeps one term whatever ``nterms`` says, is exact
+_PRECISIONS = ["bf16x3", "highest"]
+
+
+def _spy_formulation(monkeypatch, seen):
+    """Record which formulation each multi-slot kernel body is traced in."""
+    from lightgbm_tpu.ops import hist_pallas
+    real = hist_pallas._hist_kernel_multislot
+
+    def kernel(*refs, **kw):
+        seen.add("radix" if kw["radix"] else "plain")
+        return real(*refs, **kw)
+
+    monkeypatch.setattr(hist_pallas, "_hist_kernel_multislot", kernel)
+
+
+@pytest.mark.parametrize("precision", _PRECISIONS)
+def test_opening_multislot_branch_matches_the_fallback(precision,
+                                                       monkeypatch):
+    """The branch of ``_opening_hists`` that the chip runs (rows routed to
+    member slots, ONE ``build_histogram_multislot`` pass a level), taken on
+    the CPU in interpret mode: on a first tree, whose float32 sums are exact
+    in any order, it builds the fallback's model bit for bit, with one pass
+    a level at the level's width, in the formulation the precision picks."""
+    from lightgbm_tpu.ops import hist_pallas
+
+    X, y = _make(n=8192)
+    _, pb = _pair(boost_from_average=False, tpu_hist_precision=precision,
+                  tpu_wave_open_levels=3)
+    a = _train(pb, X, y, rounds=1)
+    widths = []
+    real = hist_pallas.build_histogram_multislot
+
+    def interpreted(*args, **kw):
+        widths.append(kw["n_slots"])
+        return real(*args, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(hist_pallas, "build_histogram_multislot",
+                        interpreted)
+    monkeypatch.setattr(WaveTPUTreeLearner, "_multislot_opening",
+                        lambda self: True)
+    forms = set()
+    _spy_formulation(monkeypatch, forms)
+    b = _train(pb, X, y, rounds=1)
+    assert widths == [1, 2, 4]
+    assert forms == {"radix" if precision == "bf16x3" else "plain"}
+    assert a.model_to_string() == b.model_to_string()
+
+
+@pytest.mark.parametrize("precision", _PRECISIONS)
+def test_the_chips_histogram_branch_builds_the_fallbacks_first_tree(
+        precision, monkeypatch):
+    """The whole histogram path a TPU takes (packed root pass, five opened
+    levels through the multi-slot kernel with their keys left pending, the
+    segment kernel for the waves and the replay's corrections), its three
+    kernels in interpret mode, at the cells' precision (the radix
+    formulation, its slots put back in order inside ``_opening_hists``) and
+    at full float32: on a first tree, whose float32 sums are exact in any
+    order, the model of the CPU's path without the opening, bit for bit.
+    The segment kernel is built once a call site: an opened program keeps
+    no grid under 256 chunks, and both capacities are smaller here."""
+    from lightgbm_tpu import learner_compact
+    from lightgbm_tpu.ops import hist_pallas
+
+    seen = {"multislot": [], "segments": [], "packed": 0}
+
+    def interpreted(name):
+        real = getattr(hist_pallas, name)
+
+        def call(*args, **kw):
+            if name == "build_histogram_multislot":
+                seen["multislot"].append(kw["n_slots"])
+            elif name == "build_histogram_segments":
+                seen["segments"].append(args[3].shape[0])   # chunk capacity
+            else:
+                seen["packed"] += 1
+            return real(*args, **dict(kw, interpret=True))
+        return call
+
+    X, y = _make(n=8192)
+    _, pb = _pair("shipped", num_leaves=63, boost_from_average=False,
+                  tpu_hist_precision=precision, tpu_wave_sort_cutoff=256)
+    a = _train(dict(pb, tpu_wave_open_levels=0), X, y, rounds=1)
+    for name in ("build_histogram_packed", "build_histogram_segments",
+                 "build_histogram_multislot"):
+        monkeypatch.setattr(hist_pallas, name, interpreted(name))
+    monkeypatch.setattr(learner_compact, "build_histogram_packed",
+                        hist_pallas.build_histogram_packed)
+    params = dict(pb, tpu_wave_open_levels=5)
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    bst.gbdt.learner._use_pallas = True
+    assert bst.gbdt.learner._multislot_opening()
+    forms = set()
+    _spy_formulation(monkeypatch, forms)
+    bst.update()
+    assert seen["multislot"] == [1, 2, 4, 8, 16]
+    assert forms == {"radix" if precision == "bf16x3" else "plain"}
+    assert seen["packed"] >= 1 and len(seen["segments"]) >= 2
+    # (one grid size a call site at this size: the waves' and the
+    # corrections' whole capacities, both under 256 chunks)
+    assert len(set(seen["segments"])) == len(seen["segments"]) == 2
+    assert a.model_to_string() == bst.model_to_string()
 
 
 def test_wave_exact_counts():
@@ -454,7 +702,8 @@ _SORT_PATH_RUNS = {
     "serial": {},
     "data4": {"tree_learner": "data", "parallel_mesh": "4"},
     # the opening levels end in ``_materialize_sort``
-    "serial-opening": {"tpu_wave_open_levels": 2}}
+    "serial-opening": {"tpu_wave_open_levels": 2},
+    "serial-opening4": {"tpu_wave_open_levels": 4}}
 
 
 def _train_on_sort_path(run, rounds=3):
@@ -520,7 +769,9 @@ def test_row_ids_ascend_inside_every_materialised_window(run, monkeypatch):
         moved += not np.array_equal(rid, np.arange(rid.shape[0]))
         for s, c in spans:
             assert np.all(np.diff(rid[s:s + c]) > 0), (s, c)
-    assert moved > len(seen) // 2
+    # rows have moved in most snapshots; a 31-leaf tree opened four levels
+    # deep sorts once, late (its one materialisation sort a tree)
+    assert moved > (len(seen) // 2 if run != "serial-opening4" else 3)
 
 
 @pytest.mark.parametrize("run", list(_SORT_PATH_RUNS))
