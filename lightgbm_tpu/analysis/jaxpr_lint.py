@@ -269,6 +269,10 @@ def _trace_wave_sharded(kind: str, quant: bool = False, ndev: int = 2):
     mesh = make_mesh(ndev)
     cfg_params = dict(params, tree_learner={
         "data": "data", "voting": "voting", "feature": "feature"}[kind])
+    if kind == "data":
+        # two opened levels: an opening level's one exchange and count
+        # psum are budgeted too (the chip opens five from 2^22 rows a shard)
+        cfg_params["tpu_wave_open_levels"] = 2
     if quant:
         # 2048 global rows keep the int16 exchange tier active
         # (HMAX·N <= 32767, ops/quant.py)

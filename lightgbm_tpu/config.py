@@ -446,10 +446,12 @@ class Config:
     # (rows stay in root order; one multi-slot full-pass histogram kernel
     # serves each level) and leave their sort keys pending: the first
     # growth wave's sort compacts all windows at once.  -1 = auto: 5 levels
-    # where a level's histograms are that one kernel pass (the serial wave
-    # learner on the Pallas path) over at least 2^22 local rows, 0 anywhere
-    # else (CPU, f64, quantized gradients, the sharded learners: there a
-    # level is K full-span scans; under 2^22 rows a sort is cheap).  At
+    # where a level's histograms are that one kernel pass (the serial and
+    # the data-parallel wave learner on the Pallas path; the latter passes
+    # over a shard's rows and reduce-scatters the level's histograms once)
+    # over at least 2^22 local rows, 0 anywhere else (CPU, f64, quantized
+    # gradients, voting, the 2-D learner: there a level is K full-span
+    # scans; under 2^22 rows a sort is cheap).  At
     # 10.5M rows a full-array sort costs 128.3 ms and a pass over the rows
     # 21.6 (ledger, PRs 29 and 30); five opened levels leave a 255-leaf
     # tree two sorts where the sorted ramp pays four (my chip runs, PR 31:

@@ -135,7 +135,7 @@ def _resolve_open_levels(cfg: Config, local_rows: int,
     """``tpu_wave_open_levels`` with -1 = auto (see config.py): an explicit
     depth keeps its meaning; auto opens ``_AUTO_OPEN_LEVELS`` levels where
     each level's histograms are ONE multi-slot kernel pass (``multislot``:
-    the serial learner on the Pallas path) over at least
+    the serial or data-parallel learner on the Pallas path) over at least
     ``_AUTO_OPEN_MIN_ROWS`` local rows, and none anywhere else (the fallback
     pays K full-span scans a level; a sort over few rows is cheap)."""
     ol = int(cfg.tpu_wave_open_levels)
@@ -155,7 +155,7 @@ def _segment_grid_buckets(capacity: int, width: int, opened: bool) -> list:
     every bucket is one more trace and lowering of the kernel in the job's
     first iteration, and the opening's five bodies have to be paid for
     there (``setup_s``: PERF.md section 6, PR 31).  Every other program
-    (CPU, sharded, under 2^22 rows) keeps the whole ladder."""
+    (CPU, voting, 2-D, under 2^22 rows) keeps the whole ladder."""
     floor = 2 * width
     if opened:
         floor = min(max(floor, 256), capacity)
@@ -1012,15 +1012,9 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
         subclass overrides this to reduce-scatter the W local histograms
         over the feature axis before subtraction."""
         if self._use_pallas:
-            h_small = self._segment_hists(st, sm_slot, sm_start, sm_cnt,
-                                          valid)
-            h_par = st.hist_pool[ph]                   # (W, F, B, 3)
-            h_large = h_par - h_small
-            lsm = left_small[:, None, None, None]
-            hl = jnp.where(lsm, h_small, h_large)
-            hr = jnp.where(lsm, h_large, h_small)
-            pool = st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr)
-            return pool, hl, hr
+            return self._subtract_children(
+                st, self._segment_hists(st, sm_slot, sm_start, sm_cnt,
+                                        valid), ph, lh_w, rh_w, left_small)
 
         def hist_member(pool, xs):
             slot, start, cnt, phk, lhk, rhk, lsm, vk = xs
@@ -1050,45 +1044,57 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
              valid))
         return pool, hl, hr
 
+    @staticmethod
+    def _subtract_children(st: WaveState, h_small, ph, lh_w, rh_w,
+                           left_small):
+        """Sibling subtraction of the (K, F, B, 3) smaller-child histograms
+        against their parents' pool slots, and the pool writes: (pool, hl,
+        hr)."""
+        h_large = st.hist_pool[ph] - h_small
+        lsm = left_small[:, None, None, None]
+        hl = jnp.where(lsm, h_small, h_large)
+        hr = jnp.where(lsm, h_large, h_small)
+        pool = st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr)
+        return pool, hl, hr
+
     def _multislot_opening(self) -> bool:
         """Whether an opening level's histograms are one multi-slot kernel
         pass: the Pallas path and the serial member-histogram seam (the
-        sharded subclasses exchange through their own)."""
+        data-parallel learner answers for its own exchange seam)."""
         return self._use_pallas and type(self)._wave_member_hists is \
             WaveTPUTreeLearner._wave_member_hists
+
+    def _multislot_hists(self, st: WaveState, sm_slot, valid):
+        """The K smaller children's histograms of one opening level over
+        this learner's own rows, in root order: ONE multi-slot pass
+        (`ops/hist_pallas.py:build_histogram_multislot`), each row routed
+        to its member's slot, or to none."""
+        from .ops.hist_pallas import build_histogram_multislot
+        K = sm_slot.shape[0]
+        sl = jnp.where(valid, sm_slot, -1)
+        slot_r = jnp.full(st.lid_p.shape, K, jnp.int32)
+        for k in range(K):
+            slot_r = jnp.where(st.lid_p == sl[k], k, slot_r)
+        h_small = build_histogram_multislot(
+            st.bins_p, st.w_p, slot_r, num_bins=self._hist_nbins,
+            n_slots=K, row_block=self._seg_rb, nterms=self._hist_nterms,
+            quant=self._quant)[:, :self._hist_cols]
+        if self._quant:
+            h_small = h_small * jnp.stack(
+                [jnp.float32(1.0), jnp.float32(1.0), self._q_cnt])
+        return h_small
 
     def _opening_hists(self, st: WaveState, sm_slot, valid, ph, lh_w, rh_w,
                        left_small):
         """Smaller-child histograms for one OPENING level: rows are still
         in root order (no sort has run), so the segment kernel's chunk walk
-        cannot apply.  Serial TPU: ONE multi-slot full pass
-        (`ops/hist_pallas.py:build_histogram_multislot`), each row routed
-        to its member's slot, or to none.  Fallback (CPU /
-        f64 / sharded subclasses): per-member full-span lid-masked scans
-        through the regular member-hist seam, which keeps the sharded
-        psum_scatter exchange intact."""
+        cannot apply.  TPU: ONE multi-slot pass (``_multislot_hists``).
+        Fallback (CPU / f64): per-member full-span lid-masked scans through
+        the regular member-hist seam."""
         if self._multislot_opening():
-            from .ops.hist_pallas import build_histogram_multislot
-            K = sm_slot.shape[0]
-            sl = jnp.where(valid, sm_slot, -1)
-            slot_r = jnp.full(st.lid_p.shape, K, jnp.int32)
-            for k in range(K):
-                slot_r = jnp.where(st.lid_p == sl[k], k, slot_r)
-            h_small = build_histogram_multislot(
-                st.bins_p, st.w_p, slot_r, num_bins=self._hist_nbins,
-                n_slots=K, row_block=self._seg_rb,
-                nterms=self._hist_nterms,
-                quant=self._quant)[:, :self._hist_cols]
-            if self._quant:
-                h_small = h_small * jnp.stack(
-                    [jnp.float32(1.0), jnp.float32(1.0), self._q_cnt])
-            h_par = st.hist_pool[ph]
-            h_large = h_par - h_small
-            lsm = left_small[:, None, None, None]
-            hl = jnp.where(lsm, h_small, h_large)
-            hr = jnp.where(lsm, h_large, h_small)
-            pool = st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr)
-            return pool, hl, hr
+            return self._subtract_children(
+                st, self._multislot_hists(st, sm_slot, valid), ph, lh_w,
+                rh_w, left_small)
         n = self._rows_len()
         return self._wave_member_hists(
             st, sm_slot, jnp.zeros_like(sm_slot),
@@ -1808,6 +1814,12 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
             for S in self._win_sizes]
         with scope("root"):
             st = self._init_root_wave(bins_p, grad, hess, bag, feature_mask)
+        return self._emit_tree_wave(self._grow_tree(st, feature_mask),
+                                    feature_mask)
+
+    def _grow_tree(self, st: WaveState, feature_mask) -> WaveState:
+        """A rooted tree grown to its budget: the opening, then the growth
+        waves (the serial and the data-parallel learners' one loop)."""
         # level-wise opening: the first L levels grow unsorted (level d has
         # at most 2^d leaves to split) and leave their keys PENDING, as a
         # deferring wave does: the first growth wave's sort (``sort_now =
@@ -1843,7 +1855,7 @@ class WaveTPUTreeLearner(CompactTPUTreeLearner):
                 # this sort
                 st = lax.cond(st.pending, self._materialize_sort,
                               lambda s: s, st)
-        return self._emit_tree_wave(st, feature_mask)
+        return st
 
     def _emit_tree_wave(self, st: WaveState, feature_mask):
         """Exact greedy replay + host-record emission + speculative-leaf

@@ -595,6 +595,25 @@ def _multislot_split(n_slots: int):
     return (4, n_slots // 4) if n_slots % 4 == 0 else None
 
 
+# the largest output block (words x slots) that the compiler's default scoped
+# VMEM (16 MiB) has held on the chip: the Higgs cells' 8 words at 16 slots.
+# With its output in HBM, as inside a tree step, a pass asks about five times
+# its output block: 8.7 MiB at 8 x 16, 8.8 at 18 x 8, 16.5 at 18 x 16 (AOT,
+# PR 34), which a shard of the four-chip cell overran on the chip
+_MULTISLOT_DEFAULT_VMEM_BLOCK = 8 * 16
+
+
+def _multislot_vmem_limit(word_tile: int, n_slots: int,
+                          b_pad: int) -> Optional[int]:
+    """Scoped VMEM of a multi-slot pass: the compiler's default (None) up
+    to the output block the chip has compiled within it, above it six
+    output blocks and at least 32 MiB."""
+    if word_tile * n_slots <= _MULTISLOT_DEFAULT_VMEM_BLOCK:
+        return None
+    block = word_tile * n_slots * 3 * 4 * b_pad * 4
+    return min(max(32 << 20, 6 * block), 100 << 20)
+
+
 def _hist_kernel_multislot(bins_ref, w_ref, slot_ref, out_ref, *,
                            num_bins_padded: int, word_tile: int, nterms: int,
                            n_slots: int, radix: bool = False,
@@ -718,7 +737,9 @@ def build_histogram_multislot(bins_words: jax.Array, w: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_multislot_vmem_limit(word_tile, n_slots,
+                                                   b_pad)),
         interpret=interpret,
         name="build_histogram_multislot",
     )(bins_words, w, slot)

@@ -10,6 +10,9 @@ the serial learner):
 
   * every device runs the wave partition over its LOCAL rows (the one
     stable sort per wave sorts the local shard);
+  * the opening's levels (``tpu_wave_open_levels``) run unsorted as the
+    serial learner's do: one multi-slot pass over a shard's own rows a
+    level, then the same one reduce-scatter as a wave's;
   * the W smaller-child histograms of a wave are ``psum_scatter``-ed over
     the feature axis in ONE batched collective per wave — W× fewer
     exchanges than the sequential sharded learner
@@ -58,19 +61,16 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
     def __init__(self, cfg: Config, data: _ConstructedDataset, mesh: Mesh,
                  hist_backend: str = "auto"):
         ShardedCompactLearner.__init__(self, cfg, data, mesh, hist_backend)
-        # wave bookkeeping over the PADDED feature axis (no EFB bundles in
-        # the sharded path; metadata was padded by the sharded __init__)
-        self._init_wave_dims(cfg)
         # the kernels per shard, where the serial learner would run them
         # over these rows.  A shard's histogram keeps the padded feature
         # axis (the exchange scatters it; the voting learner elects from it)
         self._use_pallas = self._kernels_fit(hist_backend, self.n_local)
         self._hist_cols = self.f_pad
         self._seg_rb = _segment_row_block(self.n_local)
-        # the sharded program keeps the round-4 per-wave flow (one
-        # collective per wave); the serial opening's multi-slot kernel has
-        # no exchange seam yet — growth starts at wave 1 as before
-        self.open_levels = 0
+        # wave bookkeeping over the PADDED feature axis (no EFB bundles in
+        # the sharded path; metadata was padded by the sharded __init__),
+        # the opening's depth by the serial rule on a shard's rows
+        self._init_wave_dims(cfg)
         self.fw_col = jnp.arange(self.f_pad, dtype=jnp.int32)
         self.fw_goff = jnp.zeros(self.f_pad, jnp.int32)
         self.fw_bnd = jnp.zeros(self.f_pad, jnp.int32)
@@ -131,18 +131,30 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
         return self._scattered_children(st, h_local, ph, lh_w, rh_w,
                                         left_small)
 
+    def _multislot_opening(self) -> bool:
+        # the opening's pass feeds this learner's exchange seam; a
+        # subclass with its own member histograms (voting's local pool)
+        # has no such seam
+        return self._use_pallas and type(self)._wave_member_hists is \
+            ShardedWaveLearner._wave_member_hists
+
+    def _opening_hists(self, st: WaveState, sm_slot, valid, ph, lh_w, rh_w,
+                       left_small):
+        """One opening level: the shard's multi-slot pass over its own
+        rows, then the wave's ONE reduce-scatter and the subtraction."""
+        if self._multislot_opening():
+            return self._scattered_children(
+                st, self._multislot_hists(st, sm_slot, valid), ph, lh_w,
+                rh_w, left_small)
+        return super()._opening_hists(st, sm_slot, valid, ph, lh_w, rh_w,
+                                      left_small)
+
     def _scattered_children(self, st: WaveState, h_local, ph, lh_w, rh_w,
                             left_small):
         # (W, f_pad, B, 3) -> (W, fs, B, 3): one collective per wave,
         # int16-packed in quantized mode (_exchange)
-        h_small = self._exchange(h_local, 1)
-        h_par = st.hist_pool[ph]                       # (W, fs, B, 3)
-        h_large = h_par - h_small
-        lsm = left_small[:, None, None, None]
-        hl = jnp.where(lsm, h_small, h_large)
-        hr = jnp.where(lsm, h_large, h_small)
-        pool = st.hist_pool.at[lh_w].set(hl).at[rh_w].set(hr)
-        return pool, hl, hr
+        return self._subtract_children(st, self._exchange(h_local, 1), ph,
+                                       lh_w, rh_w, left_small)
 
     def _make_hist_branch_shard(self, S: int):
         # with ``_hist_cols`` = f_pad the serial branch IS the shard's
@@ -166,21 +178,8 @@ class ShardedWaveLearner(ShardedCompactLearner, WaveTPUTreeLearner):
             for S in self._win_sizes]
         with scope("root"):
             st = self._init_root_wave(bins_p, grad, hess, bag, fmask_pad)
-
-        def gcond(s):
-            return (s.num_splits < self.grow_budget) & \
-                (jnp.max(self._pool_gains(s)) > 0.0)
-
-        with scope("grow"):
-            st = lax.while_loop(
-                gcond, lambda s: self._wave_step(s, fmask_pad), st)
-            if self._defer_sorts and self._stall_batch == 1:
-                # batched (K>1) replay corrections mask through phys_i
-                # spans and skip the pre-replay materialization (see
-                # learner_wave)
-                st = lax.cond(st.pending, self._materialize_sort,
-                              lambda s: s, st)
-        return self._emit_tree_wave(st, fmask_pad)
+        return self._emit_tree_wave(self._grow_tree(st, fmask_pad),
+                                    fmask_pad)
 
     def train_async(self, grad: jax.Array, hess: jax.Array, bag: jax.Array,
                     feature_mask: Optional[jax.Array] = None):
@@ -245,6 +244,10 @@ class ShardedVotingWaveLearner(ShardedWaveLearner):
         super().__init__(cfg, data, mesh, hist_backend)
         from .compact_sharded import ShardedVotingLearner
         ShardedVotingLearner._init_voting_sizing(self, cfg)
+        # no opening, whatever is asked: its levels would scan full spans
+        # member by member into a pool that stays local, and the segment
+        # kernel's chunk capacity does not hold K full spans
+        self.open_levels = 0
 
     def _reduce_hist(self, local_hist):
         # the pool stays LOCAL; reduction happens per elected feature set

@@ -214,7 +214,7 @@ def test_open_levels_auto_rule(params, rows, multislot, want):
 ])
 def test_segment_grid_buckets(capacity, width, opened, want):
     """The segment kernel's grid sizes at a call site: the parent's ladder
-    down to 2W wherever the ramp is not opened (every sharded learner, the
+    down to 2W wherever the ramp is not opened (voting, the 2-D learner, the
     CPU, small inputs), none under 256 chunks where it is."""
     from lightgbm_tpu.learner_wave import _segment_grid_buckets
     assert _segment_grid_buckets(capacity, width, opened) == want
@@ -222,35 +222,42 @@ def test_segment_grid_buckets(capacity, width, opened, want):
 
 @pytest.mark.parametrize("learner,opens", [
     ("serial-cpu", False), ("serial-tpu", True), ("serial-tpu-quant", False),
-    ("serial-tpu-f64", False), ("data4", False)])
+    ("serial-tpu-f64", False), ("data4", True), ("data4-cpu", False),
+    ("voting4", False)])
 def test_open_levels_auto_by_learner(learner, opens, monkeypatch):
     """Auto opens the ramp only where ``_opening_hists`` takes the
-    multi-slot kernel: the serial learner steered onto its TPU branch, not
-    on the CPU, not with quantized gradients (no chip reading), not with
-    float64 histograms, and not in a sharded learner, steered or not."""
+    multi-slot kernel: the serial and the data-parallel learner steered
+    onto their TPU branch (the latter on a shard's rows), not on the CPU,
+    not with quantized gradients (no chip reading), not with float64
+    histograms, and not in the voting learner, whose pool stays local."""
     from lightgbm_tpu import learner_compact, learner_wave
     from lightgbm_tpu.ops import histogram
-    if learner == "data4" and len(jax.devices()) < 4:
+    mesh = learner in ("data4", "data4-cpu", "voting4")
+    if mesh and len(jax.devices()) < 4:
         pytest.skip("needs four devices")
-    if learner != "serial-cpu":
+    if learner not in ("serial-cpu", "data4-cpu"):
         for mod in (histogram, learner_compact, learner_wave):
             monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    data4 = {"tree_learner": "data", "parallel_mesh": "4"}
     extra = {"serial-tpu-quant": {"tpu_quantized_grad": "on"},
              "serial-tpu-f64": {"gpu_use_dp": True},
-             "data4": {"tree_learner": "data", "parallel_mesh": "4"}}
-    # the rule's row floor, lowered to the test's size
-    monkeypatch.setattr(learner_wave, "_AUTO_OPEN_MIN_ROWS", 4096)
+             "data4": data4, "data4-cpu": data4,
+             "voting4": {"tree_learner": "voting", "parallel_mesh": "4"}}
+    # the rule's row floor, lowered to the test's size: a shard's rows
+    monkeypatch.setattr(learner_wave, "_AUTO_OPEN_MIN_ROWS", 1024)
     X, y = _make(n=4096)
     params = dict(_pair("shipped", num_leaves=255)[1],
                   **extra.get(learner, {}))
-    if learner == "data4":
+    if mesh:
         del params["tpu_learner"]       # the factory's choice under a mesh
     g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).gbdt
     assert isinstance(g.learner, WaveTPUTreeLearner)
+    if mesh:
+        assert g.learner.n_local == 1024
     assert g.learner.open_levels == (
         learner_wave._AUTO_OPEN_LEVELS if opens else 0)
     assert g.learner._multislot_opening() == (
-        learner in ("serial-tpu", "serial-tpu-quant"))
+        learner in ("serial-tpu", "serial-tpu-quant", "data4"))
 
 
 # the depth auto resolves to on the chip, and one less
