@@ -370,7 +370,8 @@ def _hlo_gbdt_fused() -> str:
     fn = g._fused_iter_fn()
     return fn.lower(
         g.train_score.score, g.learner.bins_packed(), g._bag_mask,
-        g._feature_sample(), jnp.float32(0.1)).compile().as_text()
+        g._feature_sample(), jnp.float32(0.1),
+        g.objective.device_arrays()).compile().as_text()
 
 
 _HLO_BUILDERS = {

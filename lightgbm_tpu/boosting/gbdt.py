@@ -554,46 +554,31 @@ class GBDT:
 
     # -- gradients -----------------------------------------------------------
 
-    #: objective attributes that hold row-aligned device arrays — the same
-    #: list `parallel/learners.py` shards over the mesh
-    _OBJ_ARRAYS = ("label", "weights", "trans_label", "label_sign",
-                   "label_w", "label_weight", "label_onehot")
-
     def _compute_gradients(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(K, N_pad) gradients/hessians from the objective (`gbdt.cpp:149`),
-        as ONE jitted dispatch.  The objective's row-aligned arrays enter as
-        jit ARGUMENTS, not closure constants: under a multi-process mesh
-        (`parallel/multihost.py`) they span non-addressable devices, and
-        closing over such an array is an error — passing them as args is
-        equivalent (they are fixed for the life of the booster) and legal
+        as ONE jitted dispatch.  The objective's device arrays
+        (``device_arrays``) enter as jit ARGUMENTS, not closure constants:
+        under a multi-process mesh (`parallel/multihost.py`) the row-aligned
+        ones span non-addressable devices, and closing over such an array is
+        an error — passing them as args is equivalent and legal
         everywhere."""
         if self._jit_grad_fn is None:
             obj = self.objective
             K = self.num_tree_per_iteration
 
             def grad_all(score, arrs):
-                saved = {n: getattr(obj, n) for n in arrs}
-                for n, v in arrs.items():
-                    setattr(obj, n, v)
-                try:
-                    with scope("gradients"):
-                        if obj.name == "multiclass":
-                            return obj.get_gradients_all(score)
-                        gs, hs = [], []
-                        for k in range(K):
-                            g, h = obj.get_gradients(score[k], k)
-                            gs.append(g)
-                            hs.append(h)
-                        return jnp.stack(gs), jnp.stack(hs)
-                finally:
-                    for n, v in saved.items():
-                        setattr(obj, n, v)
+                with obj.bind(arrs), scope("gradients"):
+                    if obj.name == "multiclass":
+                        return obj.get_gradients_all(score)
+                    gs, hs = [], []
+                    for k in range(K):
+                        g, h = obj.get_gradients(score[k], k)
+                        gs.append(g)
+                        hs.append(h)
+                    return jnp.stack(gs), jnp.stack(hs)
 
             self._jit_grad_fn = jax.jit(grad_all)
-        obj = self.objective
-        arrs = {n: getattr(obj, n) for n in self._OBJ_ARRAYS
-                if getattr(obj, n, None) is not None
-                and hasattr(getattr(obj, n), "shape")}
+        arrs = self.objective.device_arrays()
         t0 = time.perf_counter()
         with self.telemetry.phase("gradients", it=self.iter_):
             g, h = self._jit_grad_fn(self.train_score.score, arrs)
@@ -681,10 +666,13 @@ class GBDT:
                 if isinstance(learner, WaveTPUTreeLearner) \
                 else learner._train_tree_compact
 
-            def step(score, bins_p, bag, fmask, lr):
+            def step(score, bins_p, bag, fmask, lr, arrs):
                 # device phase scopes (observability/phases.py): names in
-                # every enclosed operation's op_name, no equation
-                with scope("gradients"):
+                # every enclosed operation's op_name, no equation.  The
+                # objective's arrays are arguments (``device_arrays``): a
+                # new label vector or query layout of the same shapes
+                # reuses the program
+                with obj.bind(arrs), scope("gradients"):
                     g, h = obj.get_gradients(score[0], 0)
                 out = tree_fn(bins_p, g, h, bag, fmask)
                 rec_f, rec_i, rec_cat, leaf_id, leaf_out = out[:5]
@@ -709,7 +697,8 @@ class GBDT:
                        queued=len(self._pending)):
             out = self._fused_iter_fn()(
                 self.train_score.score, self.learner.bins_packed(),
-                self._bag_mask, fmask, self._lr_dev)
+                self._bag_mask, fmask, self._lr_dev,
+                self.objective.device_arrays())
         # on sampled iterations the fused program IS the whole tree leg
         # (gradients -> tree -> score update in one dispatch)
         self._sync_sampler.leg("tree_build", _t0, out)

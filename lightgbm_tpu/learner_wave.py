@@ -227,6 +227,8 @@ def growth_sort(key_p, bins_p, w_p, rid_p, lid_p, num_slots: int):
     fw = bins_p.shape[0]
     assert num_slots <= 1 << _BAG_SHIFT, num_slots
     lid_bag = lid_p | ((w_p[2] > 0.5).astype(jnp.int32) << _BAG_SHIFT)
+    if growth_sort_operands(fw) > _SORT_MAX_OPERANDS:
+        return _growth_sort_chunked(key_p, bins_p, w_p, rid_p, lid_bag)
     ops = ([key_p, rid_p] + [bins_p[i] for i in range(fw)]
            + [w_p[0], w_p[1], lid_bag])
     assert len(ops) == growth_sort_operands(fw)
@@ -236,6 +238,47 @@ def growth_sort(key_p, bins_p, w_p, rid_p, lid_p, num_slots: int):
                      (lid_bag >> _BAG_SHIFT).astype(w_p.dtype)])
     return (sd[0], jnp.stack(sd[2:2 + fw]), w_p, sd[1],
             lid_bag & ((1 << _BAG_SHIFT) - 1))
+
+
+# The chip's compiler takes time that grows with the SQUARE of a sort's
+# operands (AOT for a v5e at 2^20 rows: 154 s at 12 operands, 534 s at 23),
+# so a wide table's growth sort would compile for most of an hour.  Past 23
+# operands (the widest table a cell runs in one sort: 18 words) the words go
+# through ONE compiled sort of a chunk of words at a time, in a loop.
+_SORT_MAX_OPERANDS = 23
+_SORT_CHUNK_WORDS = 8
+
+
+def _growth_sort_chunked(key_p, bins_p, w_p, rid_p, lid_bag):
+    """``growth_sort`` as several sorts on the same two keys: ``[key_p,
+    rid_p]`` is unique per row, so every sort on it is the same permutation.
+    The words go first, ``_SORT_CHUNK_WORDS`` at a time through one sort in
+    a ``fori_loop`` that writes each sorted chunk back over the one it read,
+    so the table is permuted in its own buffer and only one chunk's sort is
+    held beside it (``wave_transient_bytes``); the remainder, if any, takes
+    one more sort.  The keys, the weight lanes and the node slots go last,
+    since every chunk's sort reads the keys unsorted."""
+    fw = bins_p.shape[0]
+    c = _SORT_CHUNK_WORDS
+    full = fw // c * c
+
+    def sort_words(words):
+        sd = lax.sort([key_p, rid_p] + [words[i] for i in range(len(words))],
+                      num_keys=2, is_stable=False)
+        return jnp.stack(sd[2:])
+
+    def sort_chunk(i, bins):
+        chunk = lax.dynamic_slice_in_dim(bins, i * c, c)
+        return lax.dynamic_update_slice_in_dim(bins, sort_words(chunk),
+                                               i * c, 0)
+
+    bins_s = lax.fori_loop(0, fw // c, sort_chunk, bins_p)
+    if full < fw:
+        bins_s = bins_s.at[full:].set(sort_words(bins_s[full:]))
+    key_s, rid_s, w0, w1, lid_bag = lax.sort(
+        [key_p, rid_p, w_p[0], w_p[1], lid_bag], num_keys=2, is_stable=False)
+    w_p = jnp.stack([w0, w1, (lid_bag >> _BAG_SHIFT).astype(w_p.dtype)])
+    return (key_s, bins_s, w_p, rid_s, lid_bag & ((1 << _BAG_SHIFT) - 1))
 
 
 def _segment_row_block(rows: int) -> int:
@@ -2034,8 +2077,17 @@ def wave_transient_bytes(cfg: Config, n_pad: int, f_pad: int, b: int
     m_pad = ((M + 127) // 128) * 128
     mask_bytes = min(n_pad, 1 << 20) * W * 4 + n_pad * 12
     lookup_bytes = min(n_pad, 1 << 17) * m_pad * 4
-    # the growth sort's operands, in and out
-    sort_bytes = 2 * growth_sort_operands(f_pad // 4) * n_pad * 4
+    # the growth sort's operands, in and out; sorted in chunks (wide
+    # tables), the operands permuted in their own buffers and one chunk's
+    # sort, keys included, in and out beside them (AOT for a v5e at 56
+    # words x 7,326,720 rows: 79 row-sized operands with the state's
+    # buffers donated, 143 with the state kept)
+    ops = growth_sort_operands(f_pad // 4)
+    if ops > _SORT_MAX_OPERANDS:
+        ops += 2 * (2 + _SORT_CHUNK_WORDS)
+    else:
+        ops *= 2
+    sort_bytes = ops * n_pad * 4
     # batched replay correction: the vectorized partition stacks the K-1
     # extras' (fw, S) bin-word + (3, S) weight + (S,) lid slices, S up to
     # the vec cap — on wide datasets (fw in the hundreds) this per-event

@@ -16,6 +16,7 @@ multiclass keeps the reference's (K, N) score layout — K trees per iteration.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Optional, Tuple
 
@@ -39,6 +40,13 @@ class ObjectiveFunction:
     is_constant_hessian = False
     num_model_per_iteration = 1
     need_group = False
+    #: attributes that may hold row-aligned device arrays: what
+    #: `parallel/learners.py` shards over the mesh
+    ROW_ARRAYS = ("label", "weights", "trans_label", "label_sign",
+                  "label_w", "label_weight", "label_onehot")
+    #: further device arrays the gradients read that are not row-aligned
+    #: (an objective's own tables): never sharded by rows
+    TABLE_ARRAYS: Tuple[str, ...] = ()
 
     def __init__(self, cfg: Config):
         self.cfg = cfg
@@ -58,6 +66,27 @@ class ObjectiveFunction:
         self._np_label = metadata.label
         self._np_weights = metadata.weights
         self.metadata = metadata
+
+    def device_arrays(self) -> dict:
+        """Every device array the gradients read, by attribute name: what a
+        jitted caller passes as ARGUMENTS (``bind``), so that none is
+        compiled into its program as a constant."""
+        return {n: getattr(self, n) for n in self.ROW_ARRAYS
+                + self.TABLE_ARRAYS
+                if hasattr(getattr(self, n, None), "shape")}
+
+    @contextlib.contextmanager
+    def bind(self, arrs: dict):
+        """The objective with ``arrs`` (traced arguments) in place of its
+        own arrays, for the duration of a trace."""
+        saved = {n: getattr(self, n) for n in arrs}
+        for n, v in arrs.items():
+            setattr(self, n, v)
+        try:
+            yield self
+        finally:
+            for n, v in saved.items():
+                setattr(self, n, v)
 
     # grad/hess for one class-tree; score shape (N_pad,)
     def get_gradients(self, score: jnp.ndarray, class_id: int = 0

@@ -31,6 +31,10 @@ DEVICE_SCOPES = DEVICE_PHASES + DEVICE_STAGES
 # (``benchmark/phases_mesh.json`` holds the same; the serial step, every
 # scope of which is in DEVICE_SCOPES, has none)
 MESH_STAGES = ("exchange",)
+# inside ``gradients``, the lambdarank objective's (``rank_objective.py``;
+# ``benchmark/phases_rank.json`` holds the same): scores into the pair
+# layout and the per-query sorts, the pair pass, the gradients back to rows
+RANK_STAGES = ("rank_order", "rank_pairs", "rank_writeback")
 
 # host spans of the training window (recorded as ``lgbt.<name>``).  No site
 # records ``tree_dispatch`` since the pipelined path's span became
@@ -54,9 +58,10 @@ KERNEL_NAMES = ("build_histogram_pallas", "build_histogram_packed",
 
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for one of :data:`DEVICE_SCOPES` or
-    :data:`MESH_STAGES`."""
-    if name not in DEVICE_SCOPES + MESH_STAGES:
+    """``jax.named_scope(name)`` for one of :data:`DEVICE_SCOPES`,
+    :data:`MESH_STAGES` or :data:`RANK_STAGES`."""
+    known = DEVICE_SCOPES + MESH_STAGES + RANK_STAGES
+    if name not in known:
         raise ValueError(f"{name!r} is not one of the program's device "
-                         f"scopes {DEVICE_SCOPES + MESH_STAGES}")
+                         f"scopes {known}")
     return jax.named_scope(name)

@@ -221,8 +221,7 @@ def apply_parallel_sharding(gbdt, mesh: Mesh, mode: str) -> None:
     # objective label arrays follow the rows
     obj = gbdt.objective
     if obj is not None:
-        for name in ("label", "weights", "trans_label", "label_sign",
-                     "label_w", "label_weight", "label_onehot"):
+        for name in obj.ROW_ARRAYS:
             arr = getattr(obj, name, None)
             if arr is not None and hasattr(arr, "shape") and arr.ndim >= 1:
                 spec = row_spec if arr.ndim == 1 else P(None, ax_r) \
@@ -247,8 +246,7 @@ def _place_row_arrays(gbdt, mesh: Mesh, mode: str) -> None:
     gbdt.train_score.score = rules.place("score", gbdt.train_score.score)
     obj = gbdt.objective
     if obj is not None:
-        for name in ("label", "weights", "trans_label", "label_sign",
-                     "label_w", "label_weight", "label_onehot"):
+        for name in obj.ROW_ARRAYS:
             arr = getattr(obj, name, None)
             if arr is not None and hasattr(arr, "shape") and arr.ndim >= 1:
                 try:

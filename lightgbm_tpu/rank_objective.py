@@ -1,33 +1,60 @@
-"""Lambdarank NDCG objective — padded-query vectorization.
+"""Lambdarank NDCG objective — queries bucketed by their own length.
 
 TPU-native re-design of ``LambdarankNDCG``
 (`src/objective/rank_objective.hpp:19-228`).  The reference runs a per-query
-O(n²) pairwise scalar loop under OpenMP; here queries are padded to a common
-length and the pairwise lambda matrix is computed densely per query and
-reduced — vmapped over query batches so the work is (batch, Q, Q) element-wise
-ops, which the VPU eats.  The sigmoid lookup table
-(`rank_objective.hpp:180-193`) is replaced by the exact expression
-``2 / (1 + exp(2·σ·Δ))`` — same function the table approximates.
+O(n²) pairwise scalar loop under OpenMP.  Here every query is padded to its
+LENGTH CLASS, the power of two at or above its length (at least 8); the
+queries of one class are batched as ``(qb, Q, Q)`` element-wise passes, which
+the VPU eats, with ``qb`` set by a fixed element budget; and each class is
+its own pass.  A query of 20 documents costs 32² pair slots, not the longest
+query's square.
 
-Semantics preserved: rank discounts 1/log2(2+pos) over a stable sort by score
-(`rank_objective.hpp:100-104`), per-pair ΔNDCG with the max-DCG@k
-normalization (``CalMaxDCGAtK``, `src/metric/dcg_calculator.cpp`), the
-``(0.01+|Δscore|)`` regularization when scores are not all equal, and the
-``p_hessian = p_lambda·(2-p_lambda)`` curvature.
+**Layout** (built once in ``init``, on the host).  Every document has one
+SLOT in a flat array ordered by class, batch, query and position in the
+query; a class holds ``nb * qb * Q`` slots, its queries padded with empty
+ones to a multiple of ``qb``.  An iteration gathers the scores into slot
+order (``rank_order``), sorts each query by score and computes its pairs
+(``rank_order``, ``rank_pairs``), and gathers every row's lambda and hessian
+back from its slot (``rank_writeback``): the layout's inverse permutation,
+once an iteration.  The tables are device arrays the objective declares
+(``TABLE_ARRAYS``), so a jitted caller takes them as ARGUMENTS: a second
+label vector or query layout of the same class shapes reuses its program.
+
+Semantics preserved: rank discounts 1/log2(2+pos) over a stable sort by
+descending score (`rank_objective.hpp:100-104`), per-pair ΔNDCG with the
+max-DCG@k normalization (``CalMaxDCGAtK``, `src/metric/dcg_calculator.cpp`,
+k = ``max_position``), ``label_gain``, the ``(0.01+|Δscore|)``
+regularization when a query's scores are not all equal, the
+``p_hessian = p_lambda·(2-p_lambda)`` curvature and per-document weights.
+
+Departures, each deliberate:
+
+* the sigmoid lookup table (`rank_objective.hpp:180-193`) is replaced by the
+  exact expression ``2 / (1 + exp(2·σ·Δ))``, the function the table samples;
+* a document's lambdas and hessians are summed in float32 over its row of
+  the query's pair matrix (the reference accumulates the high document's
+  sums in double, the low document's in float32, in rank order);
+* each unordered pair is evaluated twice, once from either document's row,
+  with the sign of the label difference choosing the orientation: the pair
+  pass reduces along one axis only.
 """
 
 from __future__ import annotations
 
-import functools
-import math
-from typing import List, Optional
+import numpy as np
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax import lax
 
 from .config import Config
 from .objectives import ObjectiveFunction
+from .observability.phases import scope
+
+# element budget of one batch's (qb, Q, Q) pair pass (256 MiB of f32 if it
+# were written out; the pass reduces it inside one fusion)
+PAIR_BUDGET = 1 << 26
+MIN_CLASS = 8
 
 
 def default_label_gain(max_label: int = 31) -> np.ndarray:
@@ -35,16 +62,46 @@ def default_label_gain(max_label: int = 31) -> np.ndarray:
     return (2.0 ** np.arange(max_label + 1)) - 1.0
 
 
-def max_dcg_at_k(k: int, labels: np.ndarray, label_gain: np.ndarray) -> float:
-    """``DCGCalculator::CalMaxDCGAtK`` (`src/metric/dcg_calculator.cpp`)."""
-    srt = np.sort(labels)[::-1][:k]
-    disc = 1.0 / np.log2(np.arange(len(srt)) + 2.0)
-    return float((label_gain[srt.astype(np.int64)] * disc).sum())
+def length_class(sizes: np.ndarray) -> np.ndarray:
+    """The padded length of each query: the power of two at or above its
+    length, at least ``MIN_CLASS``."""
+    sizes = np.maximum(np.asarray(sizes, np.int64), 1)
+    return np.maximum(MIN_CLASS, 2 ** np.ceil(np.log2(sizes)).astype(np.int64))
+
+
+def pair_layout(sizes: np.ndarray, budget: int = PAIR_BUDGET):
+    """Classes of the layout, smallest first: ``(Q, qb, nb, slot_offset,
+    query_offset, queries)`` with ``queries`` the query numbers of the class
+    in their order; and the layout's counters."""
+    sizes = np.asarray(sizes, np.int64)
+    cls = length_class(sizes)
+    classes, slot_off, query_off = [], 0, 0
+    for Q in np.unique(cls):
+        Q = int(Q)
+        queries = np.flatnonzero(cls == Q)
+        qb = max(1, min(len(queries), budget // (Q * Q)))
+        nb = -(-len(queries) // qb)
+        qb = -(-len(queries) // nb)          # the fewest empty queries
+        classes.append((Q, qb, nb, slot_off, query_off, queries))
+        slot_off += nb * qb * Q
+        query_off += nb * qb
+    counters = {
+        "real_pairs": int(np.sum(sizes * sizes)),
+        "computed_pairs": int(sum(nb * qb * Q * Q
+                                  for Q, qb, nb, *_ in classes)),
+        "classes": len(classes),
+        "longest_query": int(sizes.max()) if sizes.size else 0,
+        "slots": int(slot_off),
+    }
+    return classes, counters
 
 
 class LambdarankNDCG(ObjectiveFunction):
     name = "lambdarank"
     need_group = True
+    # the layout's device tables: not row-aligned (``doc_slot`` has a row
+    # axis but indexes slots), never sharded by rows
+    TABLE_ARRAYS = ("slot_doc", "slot_label", "query_inv", "doc_slot")
 
     def __init__(self, cfg: Config):
         super().__init__(cfg)
@@ -61,30 +118,39 @@ class LambdarankNDCG(ObjectiveFunction):
         qb = metadata.query_boundaries
         if qb is None:
             raise ValueError("Lambdarank tasks require query information")
-        self.query_boundaries = qb
-        sizes = np.diff(qb)
-        self.num_queries = len(sizes)
-        qmax = int(sizes.max())
-        # pad to the next power of two for shape reuse across datasets
-        self.q_pad = max(8, 1 << (qmax - 1).bit_length())
-        nq = self.num_queries
+        sizes = np.diff(qb).astype(np.int64)
+        nq = len(sizes)
         n = num_data
-        qid = np.repeat(np.arange(nq, dtype=np.int64), sizes)
-        within = np.arange(n, dtype=np.int64) - qb[qid]
-        # (nq, Q) doc index matrix into the padded row axis (-1 = padding)
-        doc_idx = np.full((nq, self.q_pad), -1, dtype=np.int32)
-        doc_idx[qid, within] = np.arange(n, dtype=np.int32)
-        self.doc_idx = jnp.asarray(doc_idx)
-        self.doc_valid = jnp.asarray(doc_idx >= 0)
-        labels = np.where(doc_idx >= 0, self._pad_gather(metadata.label, doc_idx), -1)
-        self.q_labels = jnp.asarray(labels.astype(np.int32))
-        # max DCG@k per query, vectorized: one stable (qid, -label) sort
-        lab_int = metadata.label.astype(np.int64)
+        lab_int = np.asarray(metadata.label).astype(np.int64)
         if lab_int.size and int(lab_int.max()) >= len(self.label_gain):
             raise ValueError(
                 f"Label {int(lab_int.max())} exceeds label_gain size "
                 f"{len(self.label_gain)}; set label_gain explicitly")
         lab_int = np.clip(lab_int, 0, None)
+        qid = np.repeat(np.arange(nq, dtype=np.int64), sizes)
+        within = np.arange(n, dtype=np.int64) - np.asarray(qb)[qid]
+
+        classes, self.pair_layout = pair_layout(sizes)
+        S = self.pair_layout["slots"]
+        n_query_slots = sum(nb * q_b for _, q_b, nb, *_ in classes)
+        # each query's first slot, and its place in the padded query list
+        q_slot0 = np.zeros(nq, np.int64)
+        q_place = np.zeros(nq, np.int64)
+        for Q, _, _, soff, qoff, queries in classes:
+            j = np.arange(len(queries), dtype=np.int64)
+            q_slot0[queries] = soff + j * Q
+            q_place[queries] = qoff + j
+        slot = q_slot0[qid] + within                      # (n,) doc -> slot
+        self._classes = tuple((Q, q_b, nb, soff, qoff)
+                              for Q, q_b, nb, soff, qoff, _ in classes)
+        slot_doc = np.zeros(S, np.int32)
+        slot_doc[slot] = np.arange(n, dtype=np.int32)
+        slot_label = np.full(S, -1, np.int32)
+        slot_label[slot] = lab_int.astype(np.int32)
+        # padded rows point at the zero slot appended after the last class
+        doc_slot = np.full(num_data_padded, S, np.int32)
+        doc_slot[:n] = slot
+        # max DCG@k per query, vectorized: one stable (qid, -label) sort
         ideal = np.lexsort((-lab_int, qid))
         disc = 1.0 / np.log2(within + 2.0)
         k = self.optimize_pos_at
@@ -92,80 +158,75 @@ class LambdarankNDCG(ObjectiveFunction):
         maxdcg = np.bincount(qid, weights=gains, minlength=nq)
         inv = np.where(maxdcg > 0, 1.0 / np.where(maxdcg > 0, maxdcg, 1.0),
                        0.0)
-        self.inverse_max_dcgs = jnp.asarray(inv.astype(np.float32))
-        self.gains_lut = jnp.asarray(self.label_gain.astype(np.float32))
-        # batch queries so the (qb, Q, Q) intermediate stays bounded (~256MB f32)
-        self.q_batch = max(1, min(nq, int(2 ** 26 // max(self.q_pad ** 2, 1)) or 1))
-        self._jit_grads = jax.jit(self._grads_impl)
-
-    @staticmethod
-    def _pad_gather(arr, idx):
-        safe = np.clip(idx, 0, len(arr) - 1)
-        return np.asarray(arr)[safe]
+        query_inv = np.zeros(n_query_slots, np.float32)
+        query_inv[q_place] = inv
+        self.slot_doc = jnp.asarray(slot_doc)
+        self.slot_label = jnp.asarray(slot_label)
+        self.query_inv = jnp.asarray(query_inv)
+        self.doc_slot = jnp.asarray(doc_slot)
 
     # -- device computation --------------------------------------------------
 
-    def _one_query(self, scores_q, labels_q, valid_q, inv_max_dcg):
-        """Pairwise lambdas for one padded query
-        (`rank_objective.hpp:79-164` GetGradientsForOneQuery)."""
-        Q = scores_q.shape[0]
+    def _batch(self, s, lab, inv):
+        """Lambdas and hessians of ``qb`` queries padded to one class
+        (`rank_objective.hpp:79-164` GetGradientsForOneQuery): ``s``, ``lab``
+        (qb, Q) with label -1 on empty slots, ``inv`` (qb,) 1/maxDCG@k."""
+        valid = lab >= 0
         neg_inf = jnp.float32(-np.inf)
-        s = jnp.where(valid_q, scores_q, neg_inf)
-        # rank position of each doc (stable sort by descending score)
-        order = jnp.argsort(-s, stable=True)                  # pos -> doc
-        pos = jnp.argsort(order, stable=True)                 # doc -> pos
-        discount = 1.0 / jnp.log2(pos.astype(jnp.float32) + 2.0)
-        gains = self.gains_lut[jnp.clip(labels_q, 0, len(self.label_gain) - 1)]
-        valid_f = valid_q.astype(jnp.float32)
-        best = jnp.max(jnp.where(valid_q, s, neg_inf))
-        worst = jnp.min(jnp.where(valid_q, s, jnp.inf))
-        norm = best != worst
+        s = jnp.where(valid, s, neg_inf)
+        with scope("rank_order"):
+            # rank position of each doc (stable sort by descending score)
+            order = jnp.argsort(-s, axis=-1, stable=True)        # pos -> doc
+            pos = jnp.argsort(order, axis=-1)                    # doc -> pos
+        with scope("rank_pairs"):
+            discount = 1.0 / jnp.log2(pos.astype(jnp.float32) + 2.0)
+            gain_lut = jnp.asarray(self.label_gain.astype(np.float32))
+            gains = gain_lut[jnp.clip(lab, 0, len(self.label_gain) - 1)]
+            best = jnp.max(s, axis=-1)
+            worst = jnp.min(jnp.where(valid, s, jnp.inf), axis=-1)
+            norm = (best != worst)[:, None, None]
+            # sign of the label difference orients each ordered pair: +1
+            # where the row's document is the pair's high one
+            sign = jnp.sign(lab[:, :, None] - lab[:, None, :]).astype(
+                jnp.float32)
+            pair = (sign != 0) & valid[:, :, None] & valid[:, None, :]
+            ds = s[:, :, None] - s[:, None, :]
+            dgap = sign * (gains[:, :, None] - gains[:, None, :])
+            pdisc = jnp.abs(discount[:, :, None] - discount[:, None, :])
+            delta = dgap * pdisc * inv[:, None, None]
+            delta = jnp.where(norm, delta / (0.01 + jnp.abs(ds)), delta)
+            # sigmoid of the high document's score minus the low one's
+            sig = 2.0 / (1.0 + jnp.exp(2.0 * self.sigmoid * sign * ds))
+            lam = jnp.sum(jnp.where(pair, -sign * delta * sig, 0.0), axis=-1)
+            hes = jnp.sum(jnp.where(pair, 2.0 * delta * sig * (2.0 - sig),
+                                    0.0), axis=-1)
+        return lam, hes
 
-        ds = s[:, None] - s[None, :]                          # Δscore high-low
-        dgap = gains[:, None] - gains[None, :]
-        pdisc = jnp.abs(discount[:, None] - discount[None, :])
-        delta = dgap * pdisc * inv_max_dcg
-        delta = jnp.where(norm, delta / (0.01 + jnp.abs(ds)), delta)
-        pair = (labels_q[:, None] > labels_q[None, :]) & \
-               valid_q[:, None] & valid_q[None, :]
-        pf = pair.astype(jnp.float32)
-        sig = 2.0 / (1.0 + jnp.exp(2.0 * self.sigmoid * ds))
-        p_lambda = -delta * sig * pf
-        p_hessian = sig * (2.0 - sig) * 2.0 * delta * pf
-        lam = p_lambda.sum(axis=1) - p_lambda.sum(axis=0)
-        hes = p_hessian.sum(axis=1) + p_hessian.sum(axis=0)
-        return lam * valid_f, hes * valid_f
+    def _class_pass(self, s_all, Q, qb, nb, soff, qoff):
+        n_slots = nb * qb * Q
+        s = lax.slice_in_dim(s_all, soff, soff + n_slots).reshape(nb, qb, Q)
+        lab = lax.slice_in_dim(self.slot_label, soff, soff + n_slots) \
+            .reshape(nb, qb, Q)
+        inv = lax.slice_in_dim(self.query_inv, qoff, qoff + nb * qb) \
+            .reshape(nb, qb)
+        if nb == 1:
+            lam, hes = self._batch(s[0], lab[0], inv[0])
+        else:
+            lam, hes = lax.map(lambda a: self._batch(*a), (s, lab, inv))
+        return lam.reshape(-1), hes.reshape(-1)
 
-    def _grads_impl(self, score):
-        n_pad = score.shape[0]
-
-        def batch(carry, args):
-            g, h = carry
-            didx, lab, val, inv = args
-            safe = jnp.clip(didx, 0, n_pad - 1)
-            s = score[safe]
-            lam, hes = jax.vmap(self._one_query)(s, lab, val, inv)
-            didx_flat = jnp.where(val, didx, n_pad).reshape(-1)
-            g = g.at[didx_flat].add(lam.reshape(-1), mode="drop")
-            h = h.at[didx_flat].add(hes.reshape(-1), mode="drop")
-            return (g, h), None
-
-        nq = self.num_queries
-        qb = self.q_batch
-        nb = (nq + qb - 1) // qb
-        pad_q = nb * qb
-        pad = lambda a, fill: jnp.concatenate(
-            [a, jnp.full((pad_q - nq,) + a.shape[1:], fill, a.dtype)]) \
-            if pad_q > nq else a
-        didx = pad(self.doc_idx, -1).reshape(nb, qb, -1)
-        lab = pad(self.q_labels, -1).reshape(nb, qb, -1)
-        val = pad(self.doc_valid, False).reshape(nb, qb, -1)
-        inv = pad(self.inverse_max_dcgs, 0.0).reshape(nb, qb)
-        init = (jnp.zeros(n_pad, jnp.float32), jnp.zeros(n_pad, jnp.float32))
-        (g, h), _ = jax.lax.scan(batch, init, (didx, lab, val, inv))
+    def get_gradients(self, score, class_id=0):
+        with scope("rank_order"):
+            s_all = score[self.slot_doc]          # scores in slot order
+        lams, hess = [], []
+        for Q, qb, nb, soff, qoff in self._classes:
+            lam, hes = self._class_pass(s_all, Q, qb, nb, soff, qoff)
+            lams.append(lam)
+            hess.append(hes)
+        zero = [jnp.zeros(1, jnp.float32)]
+        with scope("rank_writeback"):
+            g = jnp.concatenate(lams + zero)[self.doc_slot]
+            h = jnp.concatenate(hess + zero)[self.doc_slot]
         if self.weights is not None:
             g, h = g * self.weights, h * self.weights
         return g, h
-
-    def get_gradients(self, score, class_id=0):
-        return self._jit_grads(score)

@@ -40,6 +40,10 @@ HIGGS_ROWS = 10_500_096  # the benchmark's 10,500,000 rows padded: 2^11 x 5,127
 # rows padded to 53,125,120 over four, 67 columns padded to 72 = 18 words
 SHARD_ROWS = 13_281_280
 SHARD_FW = 18
+# the ranking cell (istella-letor): 7,325,625 rows padded to 7,326,720,
+# 220 columns padded to 224 = 56 words
+RANK_ROWS = 7_326_720
+RANK_FW = 56
 _GATHER = re.compile(r"[ )]gather\(")
 
 
@@ -103,14 +107,16 @@ def test_segment_histogram_compiles(one_chip):
 
 
 @pytest.mark.parametrize("words,rows", [(FW, ROWS), (FW, HIGGS_ROWS),
-                                        (SHARD_FW, SHARD_ROWS), (64, ROWS)])
+                                        (SHARD_FW, SHARD_ROWS),
+                                        (RANK_FW, RANK_ROWS), (64, ROWS)])
 @pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16])
 def test_multislot_histogram_compiles(one_chip, n_slots, words, rows):
     """The opening's pass (`learner_wave._multislot_hists`) at every width
     the auto depth of five levels uses, at 2^20 rows, at the Higgs cells'
     own 10,500,096 (a size only the cells reach hid PR 30's SMEM fault), at
-    a shard of the four-chip cell (18 words x 13,281,280 rows) and at the
-    widest table the wave learner takes (256 columns), with its output in
+    a shard of the four-chip cell (18 words x 13,281,280 rows), at the
+    ranking cell (56 words x 7,326,720 rows) and at the widest table the
+    wave learner takes (256 columns), with its output in
     HBM as inside a tree step: kept in VMEM, as the compiler keeps the
     output of a kernel alone, the 18-word pass of 16 slots compiled here
     and overran its scoped VMEM on the chip (16.42 MiB of 16: PR 34)."""
@@ -156,15 +162,20 @@ def test_batched_split_scan_compiles(one_chip, k):
              meta, ((F,), jnp.bool_))
 
 
-def test_fused_child_scan_compiles(one_chip):
+@pytest.mark.parametrize("f", [F, 4 * RANK_FW])
+def test_fused_child_scan_compiles(one_chip, f):
     """`fused_child_scans` for one full wave (K=W members, 2W children) —
-    the quantized wave step's chain (tpu_quantized_grad=on)."""
-    from lightgbm_tpu.ops.scan_pallas import fused_child_scans
-    hist = ((W, F, B, 3), jnp.float32)
+    the quantized wave step's chain (tpu_quantized_grad=on) — at Higgs
+    width and at the ranking cell's 224 padded columns, where its block
+    takes 98.4 % of what `fused_scan_ineligible_reason` allows."""
+    from lightgbm_tpu.ops.scan_pallas import (fused_child_scans,
+                                              fused_scan_ineligible_reason)
+    assert fused_scan_ineligible_reason(f, B) is None
+    hist = ((W, f, B, 3), jnp.float32)
     child = ((2 * W,), jnp.float32)
-    meta = ((F,), jnp.int32)
+    meta = ((f,), jnp.int32)
     _compile(fused_child_scans, one_chip, hist, hist, ((W,), jnp.bool_),
-             child, child, child, meta, meta, meta, ((F,), jnp.bool_))
+             child, child, child, meta, meta, meta, ((f,), jnp.bool_))
 
 
 def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
@@ -198,9 +209,11 @@ def test_fused_step_compiles_with_named_kernels_under_phases(one_chip,
         assert learner._use_pallas and learner._use_scan
         assert learner.open_levels == 3
         args = (g.train_score.score, learner.bins_packed(), g._bag_mask,
-                g._feature_sample(), jnp.float32(0.1))
-        shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-                  for a in args]
+                g._feature_sample(), jnp.float32(0.1),
+                g.objective.device_arrays())
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
         text = g._fused_iter_fn().lower(*shapes).compile().as_text()
     named = [(line, re.search(r'op_name="([^"]*)"', line).group(1))
              for line in text.split("\n")
@@ -310,6 +323,58 @@ def test_partition_sort_compiles_at_a_shard_of_the_criteo_share(one_chip):
         == 23
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2 * growth_sort_operands(SHARD_FW) * SHARD_ROWS * 4
+
+
+def test_histograms_compile_at_the_ranking_cell(one_chip):
+    """The root and window pass and the wave members' pass at the ranking
+    cell's 56 words x 7,326,720 rows, W = 64."""
+    from lightgbm_tpu.ops.hist_pallas import (build_histogram_packed,
+                                              build_histogram_segments)
+    bins = ((RANK_FW, RANK_ROWS), jnp.int32)
+    w3 = ((3, RANK_ROWS), jnp.float32)
+    _compile(lambda b, w: build_histogram_packed(b, w, num_bins=B, nterms=3),
+             one_chip, bins, w3)
+    rb = 1024           # the largest power of two up to 2048 that divides
+    t = RANK_ROWS // rb + W + W * (8192 // rb + 2) + 1
+    chunk = ((t,), jnp.int32)
+    _compile(lambda b, w, lid, cs, cb, cl: build_histogram_segments(
+        b, w, lid, cs, cb, cl, num_bins=B, n_slots=W, row_block=rb,
+        nterms=3), one_chip, bins, w3, ((RANK_ROWS,), jnp.int32), chunk,
+        chunk, chunk)
+
+
+@pytest.mark.slow
+def test_growth_sort_compiles_at_the_ranking_cell(one_chip):
+    """`learner_wave.growth_sort` at 56 words x 7,326,720 rows (61 operands
+    in one sort would compile for about an hour: 154 s at 12 operands, 534
+    s at 23, by AOT here): one sort of the keys, the weight lanes and the
+    node slots (5 operands), and ONE sort of 8 words on the same keys (10
+    operands) inside a loop over the 7 chunks.  With the state's buffers
+    donated, as the tree step's loop leaves them dead, the table is
+    permuted in place: arguments, results and temporaries hold no more
+    row-sized operands than ``wave_transient_bytes`` reckons (79 of 81;
+    AOT, 143 with the state kept).  Slow: 120 to 160 s here."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner_wave import growth_sort, wave_transient_bytes
+    shapes = [((RANK_ROWS,), jnp.int32), ((RANK_FW, RANK_ROWS), jnp.int32),
+              ((3, RANK_ROWS), jnp.float32), ((RANK_ROWS,), jnp.int32),
+              ((RANK_ROWS,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda *a: growth_sort(*a, 1021),
+                           donate_argnums=(0, 1, 2, 3, 4)) \
+            .lower(*args).compile()
+    sorts = [line for line in compiled.as_text().split("\n")
+             if re.search(r"[ )]sort\(", line)]
+    widths = sorted(re.split(r"[ )]sort\(", line)[0].count(f"[{RANK_ROWS}]")
+                    for line in sorts)
+    assert widths == [5, 10], widths
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    cfg = Config.from_params({"num_leaves": 255, "max_bin": 255})
+    assert held <= wave_transient_bytes(
+        cfg, RANK_ROWS, 4 * RANK_FW, 256)["sort_buffer_bytes"]
 
 
 @pytest.mark.parametrize("learner_name", ["ShardedWaveLearner",

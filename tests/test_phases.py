@@ -41,7 +41,8 @@ def _booster(rng, **extra):
 
 def _step_args(g):
     return (g.train_score.score, g.learner.bins_packed(), g._bag_mask,
-            g._feature_sample(), jnp.float32(0.1))
+            g._feature_sample(), jnp.float32(0.1),
+            g.objective.device_arrays())
 
 
 def _iter_eqns(jaxpr):
@@ -288,3 +289,45 @@ def test_mesh_scope_file_equals_the_programs():
     assert not set(phases.MESH_STAGES) & set(phases.DEVICE_SCOPES)
     with phases.scope("exchange"):
         pass
+
+
+# -- the lambdarank objective's stages ------------------------------------------
+
+def test_rank_scope_file_equals_the_programs():
+    """``benchmark/phases_rank.json`` holds ``phases.RANK_STAGES``: scopes
+    ``scope()`` lets through, none of the accepted phases or stages, so a
+    trace books them under ``gradients``."""
+    with open(os.path.join(ROOT, "benchmark", "phases_rank.json")) as fh:
+        data = json.load(fh)
+    assert tuple(data["rank_stages"]) == phases.RANK_STAGES
+    assert not set(phases.RANK_STAGES) & set(phases.DEVICE_SCOPES
+                                             + phases.MESH_STAGES)
+    for name in phases.RANK_STAGES:
+        with phases.scope(name):
+            pass
+
+
+def test_rank_stages_sit_inside_gradients_of_the_fused_step():
+    """Every stage of the lambdarank objective occurs in the compiled fused
+    step, inside ``gradients``: the scores into the layout and the
+    per-query sorts, the pair pass, the gradients back to rows."""
+    rng = np.random.RandomState(3)
+    sizes = np.array([3, 12, 40, 90, 7, 33] * 4)
+    n = int(sizes.sum())
+    X = rng.randn(n, 4)
+    y = rng.randint(0, 4, n).astype(float)
+    params = {"objective": "lambdarank", "num_leaves": 7, "verbosity": -1,
+              "min_data_in_leaf": 5, "min_sum_hessian_in_leaf": 1e-3}
+    with jax.enable_x64(False):
+        g = lgb.Booster(params, lgb.Dataset(X, label=y, group=sizes,
+                                            params=params)).gbdt
+        text = g._fused_iter_fn().lower(*_step_args(g)).compile().as_text()
+    seen = {}
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        parts = op_name.split("/")
+        for stage in set(parts) & set(phases.RANK_STAGES):
+            assert "gradients" in parts, op_name
+            seen[stage] = seen.get(stage, 0) + 1
+    assert set(seen) == set(phases.RANK_STAGES)
+    assert any(re.search(r"[ )]sort\(", line) and "/rank_order/" in line
+               for line in text.split("\n"))
