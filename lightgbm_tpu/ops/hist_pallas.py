@@ -526,7 +526,8 @@ def build_histogram_segments(bins_words: jax.Array, w: jax.Array,
 # slot index as ``_radix_word`` splits the bin, low part on the column side
 # with the 32-wide lo one-hot, high part on the weight side with the hi
 # one-hot: 27.6 / 32.7 / 31.1 / 52.6 / 102.5 / 202.1 ms at the same K with
-# the words unrolled (call 26), 36 / 42 / 40 / 63 / 116 to K = 16 looped.
+# the words unrolled, 36 / 42 / 40 / 63 / 116 to K = 16 looped a word at a
+# time, 32 / 37 / 36 / 58 / 110 looped two words a body (this kernel).
 # ---------------------------------------------------------------------------
 
 
@@ -597,9 +598,11 @@ def _multislot_split(n_slots: int):
 
 # the largest output block (words x slots) that the compiler's default scoped
 # VMEM (16 MiB) has held on the chip: the Higgs cells' 8 words at 16 slots.
-# With its output in HBM, as inside a tree step, a pass asks about five times
-# its output block: 8.7 MiB at 8 x 16, 8.8 at 18 x 8, 16.5 at 18 x 16 (AOT,
-# PR 34), which a shard of the four-chip cell overran on the chip
+# With its output in HBM, as inside a tree step, a pass asks 8.75 MiB at
+# 8 x 16, 8.75 at 18 x 8, 16.5 at 18 x 16, 14.75 at 56 and 64 words x 16 in
+# tiles of 8 (13.5 at the ranking cell's row block of 1024), two words a loop
+# body or one (compiled ahead of time for a v5e); a shard of the four-chip
+# cell overran the default on the chip at 18 x 16
 _MULTISLOT_DEFAULT_VMEM_BLOCK = 8 * 16
 
 
@@ -639,18 +642,27 @@ def _hist_kernel_multislot(bins_ref, w_ref, slot_ref, out_ref, *,
         cs = slot_blk & (n_col - 1)
         hi_n = bp // 32
 
-        # a loop, not an unrolled body: the words' code is the same, and a
-        # kernel of 8 unrolled words costs the host 8 times the lowering in
-        # every job's first iteration, compile cache or not
-        def one_word(wd, carry):
+        def one_word(wd):
             for c, s, acc in _radix_word_slots(
                     wt_g, bins_ref[wd, :], cs, rb, bp, nterms, n_col,
                     quant=quant):
                 out_ref[wd, c * n_grp:(c + 1) * n_grp, :,
                         s * hi_n:(s + 1) * hi_n, :] += acc
+
+        # a loop, not an unrolled body: a kernel of 8 unrolled words costs
+        # the host 8 times the lowering in every job's first iteration,
+        # compile cache or not.  Two words a body: the scheduler does not
+        # reach across the loop's boundary, so a body's first dot waits
+        # with the MXU idle while its operands are built, and the second
+        # word's first operands are built under the first word's dots
+        def two_words(i, carry):
+            one_word(2 * i)
+            one_word(2 * i + 1)
             return carry
 
-        jax.lax.fori_loop(0, word_tile, one_word, 0)
+        jax.lax.fori_loop(0, word_tile // 2, two_words, 0)
+        if word_tile % 2:
+            one_word(word_tile - 1)
         return
     iota_s = jax.lax.broadcasted_iota(jnp.int32, (n_slots, rb), 0)
     soh = slot_blk[None, :] == iota_s                      # (K, Rb) bool

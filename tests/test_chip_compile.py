@@ -108,7 +108,10 @@ def test_segment_histogram_compiles(one_chip):
 
 @pytest.mark.parametrize("words,rows", [(FW, ROWS), (FW, HIGGS_ROWS),
                                         (SHARD_FW, SHARD_ROWS),
-                                        (RANK_FW, RANK_ROWS), (64, ROWS)])
+                                        (RANK_FW, RANK_ROWS), (64, ROWS),
+                                        (FW, RANK_ROWS),
+                                        (SHARD_FW, RANK_ROWS),
+                                        (RANK_FW, ROWS)])
 @pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16])
 def test_multislot_histogram_compiles(one_chip, n_slots, words, rows):
     """The opening's pass (`learner_wave._multislot_hists`) at every width
@@ -119,7 +122,10 @@ def test_multislot_histogram_compiles(one_chip, n_slots, words, rows):
     wave learner takes (256 columns), with its output in
     HBM as inside a tree step: kept in VMEM, as the compiler keeps the
     output of a kernel alone, the 18-word pass of 16 slots compiled here
-    and overran its scoped VMEM on the chip (16.42 MiB of 16: PR 34)."""
+    and overran its scoped VMEM on the chip (16.42 MiB of 16).
+    Each of 8, 18 and 56 words runs at both row blocks a cell takes: 2048,
+    and 1024 where the rows are the ranking cell's (7,326,720 is no
+    multiple of 2048), so that two words a loop body are read at both."""
     from lightgbm_tpu.ops.hist_pallas import (_multislot_vmem_limit,
                                               build_histogram_multislot)
     text = _compile(lambda b, w, s: build_histogram_multislot(

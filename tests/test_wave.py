@@ -433,23 +433,15 @@ def test_multislot_hist_kernel_interpret():
                 out[k, :, :, 2], np.rint(out[k, :, :, 2]))  # counts exact
 
 
-@pytest.mark.parametrize("bins", [256, 64])
-@pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16, 12, 3])
-def test_multislot_radix_formulation_is_exact(n_slots, bins):
-    """The two-level formulation of the multi-slot pass (bin = 32 hi + lo,
-    slot = 4 row group + column slot: ``_radix_word_slots``) against a
-    bincount on weights that one bfloat16 term holds exactly, so that
-    interpret mode is exact too: every width an opening level uses (16 is
-    the widest of auto's five), one that is no power of two, one with no
-    split (3: the plain formulation),
-    rows outside [0, n_slots) contributing nowhere."""
-    from lightgbm_tpu.ops.hist_pallas import (_multislot_split,
-                                              build_histogram_multislot,
+def _check_multislot_exact(n_slots, bins, words, quant=False, seed=5):
+    """One multi-slot pass against a bincount, bit for bit: weights that
+    one bfloat16 term holds exactly (interpret mode keeps one term whatever
+    ``nterms`` says), rows outside [0, n_slots) contributing nowhere."""
+    from lightgbm_tpu.ops.hist_pallas import (build_histogram_multislot,
                                               pack_bin_words)
 
-    assert (_multislot_split(n_slots) is None) == (n_slots == 3)
-    rng = np.random.RandomState(5 + n_slots)
-    n, f = 2048, 8
+    rng = np.random.RandomState(seed)
+    n, f = 2048, 4 * words
     codes = rng.randint(0, bins - 1, (f, n)).astype(np.uint8)
     bag = (rng.rand(n) < 0.7).astype(np.float32)
     w = np.stack([rng.randint(-8, 9, n).astype(np.float32) * bag * 0.5,
@@ -458,15 +450,41 @@ def test_multislot_radix_formulation_is_exact(n_slots, bins):
     out = np.asarray(build_histogram_multislot(
         pack_bin_words(jnp.asarray(codes)), jnp.asarray(w),
         jnp.asarray(slot), num_bins=bins, n_slots=n_slots, row_block=512,
-        nterms=3, interpret=True))
+        nterms=3, quant=quant, interpret=True))
     assert out.shape == (n_slots, f, bins, 3)
+    # quantized, the count channel carries the hessian lane
+    lanes = (0, 1, 1) if quant else (0, 1, 2)
     for k in range(n_slots):
         m = (slot == k).astype(np.float64)
         for fi in range(f):
             for ch in range(3):
-                ref = np.bincount(codes[fi], weights=w[ch] * m,
+                ref = np.bincount(codes[fi], weights=w[lanes[ch]] * m,
                                   minlength=bins)[:bins]
                 np.testing.assert_array_equal(out[k, fi, :, ch], ref)
+
+
+@pytest.mark.parametrize("words", [2, 8, 18, 3])
+@pytest.mark.parametrize("bins", [256, 64])
+@pytest.mark.parametrize("n_slots", [1, 2, 4, 8, 16, 12, 3])
+def test_multislot_radix_formulation_is_exact(n_slots, bins, words):
+    """The two-level formulation of the multi-slot pass (bin = 32 hi + lo,
+    slot = 4 row group + column slot: ``_radix_word_slots``) against a
+    bincount: every width an opening level uses (16 is the widest of
+    auto's five), one that is no power of two, one with no split (3: the
+    plain formulation), at 2 words (the loop of two words a body runs
+    once), 8 (the Higgs cells' word tile), 18 (the four-chip cell's: a word
+    tile of 18, not 8) and 3 (an odd tile: its last word outside the
+    loop), so that each word of a body lands in its own block."""
+    from lightgbm_tpu.ops.hist_pallas import _multislot_split
+
+    assert (_multislot_split(n_slots) is None) == (n_slots == 3)
+    _check_multislot_exact(n_slots, bins, words, seed=5 + n_slots)
+
+
+def test_multislot_radix_formulation_is_exact_quantized():
+    """``tpu_quantized_grad=on`` runs the same word loop with one term a
+    lane and no count row."""
+    _check_multislot_exact(16, 256, 8, quant=True, seed=7)
 
 
 # the cells' precision (three bfloat16 terms: the RADIX formulation of the
